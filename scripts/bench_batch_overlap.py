@@ -26,17 +26,12 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 import time
 from concurrent.futures import ThreadPoolExecutor
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_compile_cache")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
 
 
 def main() -> int:
@@ -52,13 +47,13 @@ def main() -> int:
     )
     args = ap.parse_args()
 
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     import numpy as np
 
     from skyplane_tpu.ops.batch_runner import DeviceBatchRunner
     from skyplane_tpu.ops.cdc import CDCParams
+    from skyplane_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
 
     chunk_bytes = args.chunk_mb << 20
     runner = DeviceBatchRunner(cdc_params=CDCParams(), max_batch=args.batch)

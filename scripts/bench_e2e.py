@@ -37,7 +37,6 @@ from pathlib import Path
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 class LinkPacer:
@@ -167,17 +166,9 @@ class WanProxy:
 
 def make_corpus_file(path: Path, snapshots: int, snap_chunks: int, chunk_mb: int) -> int:
     """The bench.py snapshot-chain corpus, concatenated to one file."""
-    os.environ["SKYPLANE_BENCH_SNAPSHOTS"] = str(snapshots)
-    os.environ["SKYPLANE_BENCH_SNAP_CHUNKS"] = str(snap_chunks)
-    os.environ["SKYPLANE_BENCH_CHUNK_MB"] = str(chunk_mb)
-    import importlib.util
+    import bench
 
-    spec = importlib.util.spec_from_file_location(
-        "bench_corpus", Path(__file__).resolve().parent.parent / "bench.py"
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    chunks = mod.make_corpus()
+    chunks = bench.make_corpus(chunk_mb=chunk_mb, n_snapshots=snapshots, chunks_per_snapshot=snap_chunks)
     with open(path, "wb") as f:
         for c in chunks:
             f.write(c)
@@ -269,9 +260,6 @@ def main() -> int:
     ap.add_argument("--out", default=None, help="append the JSON summary to this file")
     args = ap.parse_args()
 
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
     if args.timeline_only:
         summary = {"metric": "timeline_overhead", "unit": "seconds"}

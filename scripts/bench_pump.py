@@ -72,9 +72,6 @@ def main() -> None:
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args()
 
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
     results = []
     for w in [int(x) for x in args.workers.split(",")]:

@@ -54,7 +54,7 @@ REQUIRED_TOP = (
     "unit",
     "vs_baseline",
     "platform",
-    "device",
+    "device_kind",
     "datapath_counters",
     "decode_gbps",
     "decode_counters",
@@ -1510,7 +1510,7 @@ def main(argv) -> int:
     print(
         f"bench-smoke OK: {result['value']} {result['unit']} encode, "
         f"{result['decode_gbps']} {result['unit']} decode on {result['platform']} "
-        f"(device {result['device']}); wire: {wire['frames_pipelined']} frames pipelined, "
+        f"({result['device_kind']}); wire: {wire['frames_pipelined']} frames pipelined, "
         f"stall {wire['wire_stall_ns_per_window']}ns/window vs serial drain {wire['serial_drain_ns_per_window']}ns/window; "
         f"trace overhead {overhead}%; cpu profile: {cpu['profile_samples']} samples, "
         f"{cores} cores effective, GIL wait {round(100.0 * gil, 1)}%, sampler overhead {p_overhead}%; "

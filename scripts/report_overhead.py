@@ -24,7 +24,6 @@ from pathlib import Path
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def one_timeline_run(tmp: Path, size_bytes: int, chunk_bytes: int) -> dict:

@@ -39,9 +39,6 @@ def main() -> None:
     ap.add_argument("--chunk-mb", type=int, default=4)
     args = ap.parse_args()
 
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     import hashlib
 
     import numpy as np

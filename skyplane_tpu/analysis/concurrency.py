@@ -723,9 +723,8 @@ _DEADLINEISH_FRAGMENTS = ("deadline", "timeout", "budget", "expires", "expiry")
 
 class UnboundedWaitInProvisionerChecker(Checker):
     """unbounded-wait-in-provisioner: a ``while`` poll loop (one that sleeps)
-    under ``compute/`` with no deadline bound — the bug class behind the r05
-    rc=124 artifact loss (an unbounded tunnel-lock wait spun until the outer
-    timeout killed the whole run). A cloud API that never converges
+    under ``compute/`` with no deadline bound — an unbounded wait spins until
+    an outer timeout kills the whole run and its artifact. A cloud API that never converges
     (operation stuck, instance wedged in PENDING, SSH never up) must surface
     as a TimeoutError with context, not hang the fleet bring-up forever.
 

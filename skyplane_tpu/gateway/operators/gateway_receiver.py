@@ -740,6 +740,7 @@ class GatewayReceiver:
                 {
                     "port": state.port,
                     "chunk_id": header.chunk_id,
+                    "codec": int(header.codec),  # what the sender actually ran, per frame
                     "raw_bytes": header.raw_data_len,
                     "wire_bytes": header.data_len,
                     "decode_s": round(task.decode_ns / 1e9, 6),

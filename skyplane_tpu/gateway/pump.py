@@ -1235,9 +1235,12 @@ def is_pump_sender(op) -> bool:
 def _pump_worker_main(cfg: dict, ctrl_sock: socket.socket) -> None:
     """Spawn-child entry point. Pins the jax platform BEFORE any data-path
     import (pump workers run host/CPU kernels — on accelerator gateways the
-    device belongs to the parent's batch runner and the single-client tunnel
-    discipline forbids a second jax client), then arms the inherited
-    observability surface and dispatches on role."""
+    chip belongs to the parent's batch runner, one process at a time), then
+    arms the inherited observability surface and dispatches on role. The pin
+    holds only while nothing imported before this function runs has imported
+    jax (jax reads JAX_PLATFORMS at import): this module must not, and a
+    spawning ``__main__`` must keep its jax imports inside functions
+    (tests/unit/test_pump.py pins the former)."""
     platform = os.environ.get("SKYPLANE_TPU_PUMP_CHILD_PLATFORM", "cpu")
     if platform:
         os.environ["JAX_PLATFORMS"] = platform

@@ -26,6 +26,68 @@ def _build_dir() -> Path:
     return Path(override) if override else _SRC_DIR
 
 
+_SOURCES = ("skylz.cpp", "datapath.cpp")
+_NATIVE_FLAGS = ("-O3", "-march=native", "-shared", "-fPIC")
+_PORTABLE_FLAGS = ("-O3", "-shared", "-fPIC")
+_build_info: dict = {}
+
+
+def _cpu_features() -> str:
+    """The CPU's instruction-set flags as the kernel reports them: what a
+    ``-march=native`` build actually depends on (an AVX-512 build SIGILLs on
+    a CPU without it, whatever the host is called)."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return " ".join(sorted(line.split(":", 1)[1].split()))
+    except OSError:
+        pass
+    import platform
+
+    return f"{platform.machine()} {platform.processor()}"
+
+
+def build_stamp() -> str:
+    """Digest of everything the compiled library depends on: the two sources,
+    the compiler flags and this CPU's feature flags. A library whose sidecar
+    stamp differs (stale sources, built on another CPU, planted) is rebuilt."""
+    import hashlib
+
+    h = hashlib.sha256()
+    for name in _SOURCES:
+        h.update((_SRC_DIR / name).read_bytes())
+    h.update(" ".join(_NATIVE_FLAGS).encode())
+    h.update(_cpu_features().encode())
+    return h.hexdigest()
+
+
+def build_info() -> dict:
+    """{"path", "stamp", "built"} of the loaded library ("built": compiled by
+    this process rather than found with a matching stamp); empty before load."""
+    return dict(_build_info)
+
+
+def _compile(out: Path) -> None:
+    src_args = [str(_SRC_DIR / name) for name in _SOURCES]
+    # build beside the target and rename: a concurrent loader (pump workers
+    # start together) never maps a half-written library
+    tmp = out.with_name(f"{out.name}.{os.getpid()}.tmp")
+    try:
+        # -march=native can fail in emulated environments; retry portable
+        for flags in (_NATIVE_FLAGS, _PORTABLE_FLAGS):
+            try:
+                proc = subprocess.run(["g++", *flags, *src_args, "-o", str(tmp)], capture_output=True, text=True, timeout=120)
+            except FileNotFoundError as e:
+                raise MissingDependencyException("native codec requires g++ in PATH") from e
+            if proc.returncode == 0:
+                os.replace(tmp, out)
+                return
+        raise MissingDependencyException(f"native codec build failed: {proc.stderr[-2000:]}")
+    finally:
+        tmp.unlink(missing_ok=True)
+
+
 def load_library() -> ctypes.CDLL:
     """Build (if needed) and load libskydp."""
     global _lib
@@ -34,33 +96,14 @@ def load_library() -> ctypes.CDLL:
     with _BUILD_LOCK:
         if _lib is not None:
             return _lib
-        sources = [_SRC_DIR / "skylz.cpp", _SRC_DIR / "datapath.cpp"]
         out = _build_dir() / "libskydp.so"
-        # the library is built with -march=native and MUST NOT travel between
-        # hosts (an AVX-512 build SIGILLs elsewhere): a host-tag sidecar forces
-        # a rebuild whenever the .so was produced on a different machine
-        import platform
-
-        host_tag = f"{platform.machine()}-{platform.node()}"
-        tag_file = _build_dir() / "libskydp.hosttag"
-        stale_host = not tag_file.exists() or tag_file.read_text() != host_tag
-        if not out.exists() or stale_host or any(out.stat().st_mtime < s.stat().st_mtime for s in sources):
+        stamp_file = _build_dir() / "libskydp.stamp"
+        stamp = build_stamp()
+        built = not out.exists() or not stamp_file.exists() or stamp_file.read_text() != stamp
+        if built:
             out.parent.mkdir(parents=True, exist_ok=True)
-            src_args = [str(s) for s in sources]
-            cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC", *src_args, "-o", str(out)]
-            try:
-                # sklint: disable=blocking-under-lock -- _BUILD_LOCK exists to serialize this build-once compile; waiters need the .so
-                proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
-            except FileNotFoundError as e:
-                raise MissingDependencyException("native codec requires g++ in PATH") from e
-            if proc.returncode != 0:
-                # -march=native can fail in emulated environments; retry portable
-                cmd = ["g++", "-O3", "-shared", "-fPIC", *src_args, "-o", str(out)]
-                # sklint: disable=blocking-under-lock -- same build-once contract as above; bounded by timeout=120
-                proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
-                if proc.returncode != 0:
-                    raise MissingDependencyException(f"native codec build failed: {proc.stderr[-2000:]}")
-            tag_file.write_text(host_tag)
+            _compile(out)
+            stamp_file.write_text(stamp)
         lib = ctypes.CDLL(str(out))
         u8p = ctypes.POINTER(ctypes.c_uint8)
         u32p = ctypes.POINTER(ctypes.c_uint32)
@@ -84,5 +127,6 @@ def load_library() -> ctypes.CDLL:
             fn = getattr(lib, name)
             fn.restype = restype
             fn.argtypes = argtypes
+        _build_info.update(path=str(out), stamp=stamp, built=built)
         _lib = lib
         return _lib

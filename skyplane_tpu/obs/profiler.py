@@ -8,7 +8,7 @@ lock-bound, or genuinely parallel? This module is that instrument:
   * **Sampling profiler** (:class:`StackProfiler`): a dedicated daemon thread
     walks ``sys._current_frames()`` at ``SKYPLANE_TPU_PROFILE_HZ`` and folds
     each thread's stack into bounded per-thread tables. Every sample is
-    classified into the existing stage taxonomy (frame / send_stall /
+    classified into the existing stage classes (frame / send_stall /
     ack_lag / decode / store / device_wait, plus codec / crypto / framing
     sub-buckets) by the innermost recognizable frame; per-thread CPU-clock
     deltas (``/proc/self/task`` via

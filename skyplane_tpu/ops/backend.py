@@ -17,10 +17,11 @@ def on_accelerator() -> bool:
             # (batch runner, device CDC/fingerprints) on a CPU backend
             _is_accelerator = True
             return True
-        try:
-            import jax
+        # no try/except: with no accelerator jax answers with its CPU backend
+        # without raising, so an error here is a chip that is held by another
+        # process or a broken runtime — the daemon must not carry on as a
+        # host-path gateway as if nothing happened
+        import jax
 
-            _is_accelerator = jax.devices()[0].platform not in ("cpu",)
-        except Exception:  # noqa: BLE001 - no usable jax backend => host paths
-            _is_accelerator = False
+        _is_accelerator = jax.devices()[0].platform != "cpu"
     return _is_accelerator

@@ -8,11 +8,10 @@ concurrent same-size submissions into one [B, N] batch (SURVEY §7 hard part
 #2: batching with BOUNDED latency — small transfers must not wait for a
 full batch).
 
-The batched work itself is the fused single-dispatch kernel
-(ops/fused_cdc.py): gear hash, boundary selection, and segment fingerprints
-run as ONE compiled program per batch with one small packed readback —
-critical when the accelerator sits behind a narrow readback link (tunnel /
-PCIe), and strictly fewer HBM round trips even with fast interconnect.
+The batched work itself is ops/fused_cdc.py: gear hash, boundary selection
+and segment fingerprints run as two compiled programs per batch with two
+small packed readbacks, so what crosses the host link per window is the
+chunk bytes once and a few hundred KiB of metadata.
 
 Leader-based protocol (no dedicated thread): the first worker to open a
 batch window waits ``max_wait_ms`` for peers, then executes the batched
@@ -126,9 +125,7 @@ class DeviceBatchRunner:
         self.cdc_params = cdc_params
         self.max_batch = max_batch
         if max_wait_ms is None:
-            # window-formation wait. 3 ms suits a locally attached chip;
-            # behind a high-latency dispatch link (tunnel) a longer wait fills
-            # windows better than it delays them — tune without code changes
+            # window-formation wait: how long a lone chunk waits for peers
             try:
                 max_wait_ms = float(os.environ.get("SKYPLANE_TPU_BATCH_WAIT_MS", "3"))
             except ValueError:
@@ -136,8 +133,7 @@ class DeviceBatchRunner:
         # NaN / inf / negative would stall or kill the window leader
         # (Condition.wait raises on NaN), whether it came from the env var or
         # a caller's computed value; a wait beyond a few seconds is never
-        # useful (dispatch RTTs are ~100 ms even through a tunnel), so
-        # clamp rather than obey a typo
+        # useful, so clamp rather than obey a typo
         import math
 
         if not math.isfinite(max_wait_ms) or max_wait_ms < 0:
@@ -392,14 +388,17 @@ class DeviceBatchRunner:
             # pad rows carry n=0 and are dropped before unpacking
             rows = [e.arr for e in entries]
             lens = [e.n for e in entries]
-            # batch-dim buckets {1, max_batch}: a LONE flush (start-of-stream,
+            # batch-dim buckets {1, group}: a LONE flush (start-of-stream,
             # tail, trickle traffic) runs the ~B-times-cheaper B=1 program
-            # instead of a fully padded window; all other sizes pad to
-            # max_batch so XLA still compiles at most two programs per bucket.
-            # Sharded runners always pad: a batch of 1 cannot split across
-            # the mesh's batch axis.
+            # instead of a fully padded window; all other sizes pad to a
+            # multiple of the rows one dispatch carries (the whole window,
+            # unless its rows are too large for one program's HBM) so XLA
+            # still compiles at most two batch shapes per bucket. Sharded
+            # runners always pad: a batch of 1 cannot split across the
+            # mesh's batch axis.
             pad_batch = not (len(rows) == 1 and self.mesh is None)
-            n_pad_rows = self.max_batch - len(rows) if pad_batch else 0
+            group = min(self.max_batch, self._fused.rows_per_dispatch(bucket))
+            n_pad_rows = -len(rows) % group if pad_batch else 0
             if self.mesh is not None:
                 # sharded path: one host stack; the mesh kernels distribute it
                 if n_pad_rows > 0:
@@ -422,6 +421,8 @@ class DeviceBatchRunner:
                     lens = lens + [0] * n_pad_rows
                     dev_rows = dev_rows + [self._dev_zero_row(bucket, dev_rows[0])] * n_pad_rows
                 pending = self._fused.dispatch(rows, lens, dev_rows=dev_rows)
+                for e in entries:
+                    e.dev = None  # restacked on device: let the staged row's HBM go
             # phase 1: boundary selection is final; the fingerprint kernel is
             # merely ENQUEUED. Wake every waiter so workers overlap recipe
             # span assembly with the in-flight fingerprint compute+readback.

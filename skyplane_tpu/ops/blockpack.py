@@ -121,9 +121,9 @@ def encode_container(data: bytes, block_bytes: int = DEFAULT_BLOCK_BYTES) -> byt
     if native_dp.available():
         # the native single-pass kernel runs at memcpy speed; the device
         # kernel would have to pull the (data-sized) literal stream back over
-        # the host link, which costs more than the whole host pass even on
-        # PCIe — and catastrophically more over a tunnel. The device kernel
-        # stays the path for device-resident consumers (datapath_step).
+        # the host link, which costs more than the whole host pass. The
+        # device kernel stays the path for device-resident consumers
+        # (datapath_step).
         tags_np, lit_np, n_lit = native_dp.blockpack_encode(arr, block_bytes)
     else:
         from skyplane_tpu.ops.backend import on_accelerator

@@ -210,6 +210,29 @@ def segment_fingerprint_host(seg: bytes) -> bytes:
     return bytes.fromhex(finalize_fingerprint(lanes, L))
 
 
+def segment_fp_lanes_numpy(arr: np.ndarray, ends: np.ndarray) -> np.ndarray:
+    """[n_segments, 8] uint32 lane values in vectorized numpy: the path hosts
+    without the native library run, and the plain reference chip_smoke.py
+    holds the device to at real chunk sizes."""
+    ends = np.asarray(ends, np.int64)
+    starts = np.concatenate([[0], ends[:-1]])
+    tables64 = _power_tables().astype(np.uint64)  # [LANES, MAX]
+    lanes = np.empty((len(ends), N_LANES), np.uint32)
+    m31 = np.uint64(M31)
+    # per-segment processing keeps the working set (<= 256 KiB slices) in
+    # cache — full-array passes are DRAM-bound and measure ~6x slower
+    for si, (s, e) in enumerate(zip(starts, ends)):
+        d = arr[s:e].astype(np.uint64)
+        length = int(e - s)
+        for li in range(N_LANES):
+            powers = tables64[li, :length][::-1]
+            t = d * powers  # < 2^39
+            t = (t >> np.uint64(31)) + (t & m31)  # < 2^31 + 2^8
+            total = int(t.sum())  # <= 2^18 * 2^32 < 2^50, python int exact
+            lanes[si, li] = total % M31
+    return lanes
+
+
 def segment_fingerprints_host_batch(arr: np.ndarray, ends: np.ndarray) -> list:
     """All segment fingerprints of one chunk. Uses the native single-pass
     Horner kernel when available (~10x the numpy path), else vectorized
@@ -221,24 +244,10 @@ def segment_fingerprints_host_batch(arr: np.ndarray, ends: np.ndarray) -> list:
         return []
     from skyplane_tpu.native import datapath as native_dp
 
-    starts = np.concatenate([[0], ends[:-1]])
     if native_dp.available():
         lanes = native_dp.segment_fp_lanes(arr, ends)
     else:
-        tables64 = _power_tables().astype(np.uint64)  # [LANES, MAX]
-        lanes = np.empty((len(ends), N_LANES), np.uint32)
-        m31 = np.uint64(M31)
-        # per-segment processing keeps the working set (<= 256 KiB slices) in
-        # cache — full-array passes are DRAM-bound and measure ~6x slower
-        for si, (s, e) in enumerate(zip(starts, ends)):
-            d = arr[s:e].astype(np.uint64)
-            length = int(e - s)
-            for li in range(N_LANES):
-                powers = tables64[li, :length][::-1]
-                t = d * powers  # < 2^39
-                t = (t >> np.uint64(31)) + (t & m31)  # < 2^31 + 2^8
-                total = int(t.sum())  # <= 2^18 * 2^32 < 2^50, python int exact
-                lanes[si, li] = total % M31
+        lanes = segment_fp_lanes_numpy(arr, ends)
     return digests_from_lanes(lanes, ends)
 
 

@@ -98,9 +98,10 @@ def estimate_corpus(
 
 
 # rough per-gateway codec throughputs in Gbps of LOGICAL (pre-compression)
-# data. CPU figures from docs/benchmark.md microbenchmarks; TPU figures are
-# the device-path targets (validated on hardware by bench.py). Used only for
-# the enable/disable decision, so order-of-magnitude accuracy suffices.
+# data. CPU figures from docs/benchmark.md microbenchmarks (builder-measured,
+# CPU); the "tpu" and "tpu_zstd" figures are assumed, not measured — no run
+# on a chip has produced them (PERF.md). Used only for the enable/disable
+# decision, so order-of-magnitude accuracy suffices.
 # Gateways without an accelerator substitute zstd for a planned tpu_zstd at
 # operator construction (ops/pipeline.effective_codec_name, logged and
 # visible in the wire headers) — so on all-CPU deployments the tpu_zstd row

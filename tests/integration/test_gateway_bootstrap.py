@@ -52,7 +52,6 @@ class FakeVM(SSHServer):
         self._env["PATH"] = f"{bin_dir}:{self._env['PATH']}"
         # the "VM" must run jax on CPU and not inherit the client's repo path
         self._env["JAX_PLATFORMS"] = "cpu"
-        self._env["SKYPLANE_GATEWAY_JAX_PLATFORM"] = "cpu"
         # stand-in for a TPU VM's preinstalled jax/numpy: the client env's
         # site-packages (which does NOT contain skyplane_tpu — verified by
         # the version probe returning empty before install)

@@ -95,6 +95,7 @@ def test_cross_bucket_traffic_does_not_starve_lone_flush():
 
     class SlowFused:
         mesh = None
+        rows_per_dispatch = real_fused.rows_per_dispatch
 
         def stage(self, arr):
             return real_fused.stage(arr)
@@ -131,13 +132,6 @@ def test_mesh_axis_selection_bounds_window_inflation():
     import jax
     import numpy as np
     from jax.sharding import Mesh
-
-    try:
-        from skyplane_tpu.parallel.datapath_spmd import shard_map_compat
-
-        shard_map_compat()
-    except ImportError:
-        pytest.skip("shard_map unavailable in this jax version (environment-caused)")
 
     devs = np.asarray(jax.devices()[:8])
     mesh = Mesh(devs.reshape(2, 4), axis_names=("data", "seq"))

@@ -65,6 +65,7 @@ def test_ends_ready_fires_before_fingerprint_readback():
 
     class SlowLanesFused:
         mesh = None
+        rows_per_dispatch = real_fused.rows_per_dispatch
 
         def stage(self, arr):
             return real_fused.stage(arr)

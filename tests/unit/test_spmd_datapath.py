@@ -9,20 +9,6 @@ import jax.numpy as jnp
 from skyplane_tpu.ops.pipeline import datapath_step
 from skyplane_tpu.parallel.datapath_spmd import default_mesh, make_spmd_datapath
 
-def _have_shard_map() -> bool:
-    try:
-        from skyplane_tpu.parallel.datapath_spmd import shard_map_compat
-
-        shard_map_compat()
-        return True
-    except ImportError:
-        return False
-
-
-requires_shard_map = pytest.mark.skipif(
-    not _have_shard_map(), reason="shard_map unavailable in this jax version (environment-caused)"
-)
-
 rng = np.random.default_rng(11)
 
 CHUNK = 64 * 1024
@@ -56,7 +42,6 @@ def test_mesh_shape(mesh):
     assert mesh.shape["data"] * mesh.shape["seq"] == 8
 
 
-@requires_shard_map
 def test_spmd_matches_single_device(mesh):
     batch = _batch()
     step, in_sharding = make_spmd_datapath(mesh, CHUNK, BATCH, BLOCK, FP_SEG, MASK_BITS)
@@ -76,7 +61,6 @@ def test_spmd_matches_single_device(mesh):
     np.testing.assert_array_equal(n_lit_spmd, np.asarray(ref["n_lit"]))
 
 
-@requires_shard_map
 def test_spmd_literals_reconstruct(mesh):
     """Per-shard literal buffers + tags fully reconstruct each chunk."""
     from skyplane_tpu.ops.blockpack import decode_device
@@ -96,7 +80,6 @@ def test_spmd_literals_reconstruct(mesh):
         np.testing.assert_array_equal(np.concatenate(rebuilt), batch[b])
 
 
-@requires_shard_map
 def test_meshed_batch_runner_matches_host_path(mesh):
     """The PRODUCTION batch runner (what gateway sender workers call) sharded
     over the mesh must produce bit-identical CDC boundaries and fingerprints
@@ -120,7 +103,6 @@ def test_meshed_batch_runner_matches_host_path(mesh):
         assert fps == want_fps
 
 
-@requires_shard_map
 @pytest.mark.parametrize("n_devices,data_parallel", [(2, 1), (4, 2), (8, 2)], ids=["1x2", "2x2", "2x4"])
 def test_meshed_runner_bit_identity_across_meshes(n_devices, data_parallel, monkeypatch):
     """ISSUE 18: the mesh-backed runner must be bit-identical to the host
@@ -176,26 +158,40 @@ def test_spmd_mode_parsing(monkeypatch):
         assert spmd_mode() == want, raw
 
 
-def test_maybe_default_mesh_off_and_memoized_warning(monkeypatch):
-    """SKYPLANE_TPU_SPMD=off always yields None; a broken backend warns ONCE
-    per process (the warning is memoized), then stays silent."""
+def _broken_backend():
+    raise RuntimeError("Unable to initialize backend 'tpu': the chip is held by another process")
+
+
+def test_maybe_default_mesh_off_and_backend_error_propagates(monkeypatch):
+    """SKYPLANE_TPU_SPMD=off always yields None; a backend that fails to
+    initialize RAISES — with no accelerator jax answers with its CPU backend
+    without raising, so an error is a broken chip, not a reason to carry on
+    single-device."""
     from skyplane_tpu.parallel import datapath_spmd
 
     monkeypatch.setenv("SKYPLANE_TPU_SPMD", "off")
     assert datapath_spmd.maybe_default_mesh() is None
     monkeypatch.delenv("SKYPLANE_TPU_SPMD", raising=False)
 
-    warnings = []
-    monkeypatch.setattr(datapath_spmd, "_warned_mesh_unavailable", False)
-    monkeypatch.setattr(
-        datapath_spmd.jax, "devices", lambda: (_ for _ in ()).throw(RuntimeError("no backend"))
-    )
-    from skyplane_tpu.utils.logger import logger
+    monkeypatch.setattr(datapath_spmd.jax, "devices", _broken_backend)
+    with pytest.raises(RuntimeError, match="held by another process"):
+        datapath_spmd.maybe_default_mesh()
+    # an odd device count is not an error: no mesh, single-device
+    monkeypatch.setattr(datapath_spmd.jax, "devices", lambda: [object()] * 3)
+    assert datapath_spmd.maybe_default_mesh() is None
 
-    monkeypatch.setattr(logger.fs, "warning", lambda msg, *a, **k: warnings.append(msg))
-    assert datapath_spmd.maybe_default_mesh() is None
-    assert datapath_spmd.maybe_default_mesh() is None
-    assert len(warnings) == 1, f"mesh-unavailable warning must be memoized per process, got {warnings}"
+
+def test_on_accelerator_backend_error_propagates(monkeypatch):
+    """ops/backend.on_accelerator must not turn a failed backend into a quiet
+    host-path gateway (and must not cache an answer it never got)."""
+    from skyplane_tpu.ops import backend
+
+    monkeypatch.delenv("SKYPLANE_TPU_FORCE_ACCEL_PATH", raising=False)
+    monkeypatch.setattr(backend, "_is_accelerator", None)
+    monkeypatch.setattr(jax, "devices", _broken_backend)
+    with pytest.raises(RuntimeError, match="held by another process"):
+        backend.on_accelerator()
+    assert backend._is_accelerator is None
 
 
 def test_force_host_devices_env(monkeypatch):
@@ -218,7 +214,6 @@ def test_force_host_devices_env(monkeypatch):
     assert env3["XLA_FLAGS"].count("xla_force_host_platform_device_count") == 1
 
 
-@requires_shard_map
 def test_meshed_batch_runner_concurrent_submissions(mesh):
     """Multiple worker threads share the meshed runner: the micro-batching
     window must batch them through the sharded kernels correctly."""
