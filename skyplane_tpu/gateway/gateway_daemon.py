@@ -681,7 +681,7 @@ class GatewayDaemon:
     def _compression_stats(self) -> dict:
         from skyplane_tpu.ops.pipeline import DataPathStats
 
-        agg = {"chunks": 0, "raw_bytes": 0, "wire_bytes": 0, "segments": 0, "ref_segments": 0, "device_wait_ns": 0}
+        agg = {k: 0 for k in DataPathStats._KEYS}  # the per-chunk counters, one list for both schemas
         hot_path = dict(DataPathStats.EXTERNAL_ZERO)  # pool / batch / donation counters
         for op in self.operators:
             if isinstance(op, GatewaySenderOperator):
@@ -692,7 +692,10 @@ class GatewayDaemon:
                     # per-processor pools: summing is correct (nothing shared);
                     # derived ratios are recomputed from the summed counts below
                     for k in hot_path:
-                        if k not in ("pool_hit_rate", "batch_occupancy"):
+                        if k in ("xla_compiles", "xla_compile_ns"):
+                            # the process's, the same from every operator in it
+                            hot_path[k] = max(hot_path[k], d.get(k, 0))
+                        elif k not in ("pool_hit_rate", "batch_occupancy"):
                             hot_path[k] = hot_path.get(k, 0) + d.get(k, 0)
         if self.batch_runner is None:
             lookups = hot_path["pool_hits"] + hot_path["pool_misses"]

@@ -1163,7 +1163,9 @@ class GatewaySenderOperator(GatewayOperator):
             view.pending.update(fp for fp, _ in payload.new_fingerprints)
         wire = payload.wire_bytes
         if self.cipher is not None:
+            t_seal = time.perf_counter_ns()
             wire = self.cipher.seal(wire)
+            self.processor.stats.observe_seal(time.perf_counter_ns() - t_seal)
         chunk.fingerprint = payload.fingerprint
         header = chunk.to_wire_header(
             n_chunks_left_on_socket=n_left,

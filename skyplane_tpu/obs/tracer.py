@@ -20,6 +20,11 @@ Design constraints (the hot paths this instruments move GB/s):
     flag for sampled chunks; receivers pass ``force=True`` so their spans
     for that chunk record regardless of the local rate. Exported events
     carry the chunk id in ``args`` — the correlation key across pids.
+  * **The device trace sits on these spans.** An enabled tracer also enters
+    ``jax.profiler.TraceAnnotation("host:<name>")`` for the duration of every
+    ``cat="device"`` span, so a ``jax.profiler`` trace taken of a running
+    gateway holds the host steps of the device path on the profile's own
+    clock, beside the device operations they enqueue (:class:`_DeviceSpan`).
 
 Export is Chrome trace-event JSON (the ``traceEvents`` array form): complete
 ``"X"`` events for context-managed spans (they nest by containment on one
@@ -115,6 +120,48 @@ class _Span:
         return False
 
 
+PROFILE_CAT = "device"  # spans of this category also go into a jax.profiler trace
+PROFILE_PREFIX = "host:"
+_annotation_cls = None  # jax.profiler.TraceAnnotation, or False where jax cannot be imported
+
+
+def _profile_annotation(name: str):
+    """``TraceAnnotation("host:<name>")``, or None in a process without jax.
+    jax is imported here, on the first device-category span of an enabled
+    tracer, never at module import: obs/ stays importable without it."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        try:
+            from jax.profiler import TraceAnnotation as cls
+        except ImportError:
+            cls = False
+        _annotation_cls = cls
+    return cls(PROFILE_PREFIX + name) if cls else None
+
+
+class _DeviceSpan(_Span):
+    """A ``cat="device"`` span: the ring record of :class:`_Span` and, around
+    it, a profiler annotation. With no profile session open the annotation is
+    one flag check inside the profiler; with one open, the span lands in the
+    trace on the clock the device operations are on."""
+
+    __slots__ = ("_annotation",)
+
+    def __init__(self, ring: _Ring, name: str, cat: str, trace_id, args, annotation):
+        super().__init__(ring, name, cat, trace_id, args)
+        self._annotation = annotation
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        return super().__enter__()
+
+    def __exit__(self, exc_type, exc, tb):
+        super().__exit__(exc_type, exc, tb)
+        self._annotation.__exit__(exc_type, exc, tb)
+        return False
+
+
 class Tracer:
     #: dead-thread rings retained for export (recently-finished workers'
     #: spans stay visible); beyond this, the OLDEST dead rings retire and
@@ -173,11 +220,17 @@ class Tracer:
         """A context-managed span. ``trace_id`` (the chunk id) keys sampling
         AND correlation; ``trace_id=None`` spans (device batches, spill I/O)
         record whenever tracing is enabled. ``force=True`` bypasses the local
-        sample decision — the receiver path for wire-flagged chunks."""
+        sample decision — the receiver path for wire-flagged chunks. A
+        ``cat="device"`` span is also written into any ``jax.profiler`` trace
+        in progress, as ``host:<name>``."""
         if not self.enabled:
             return NOOP_SPAN
         if trace_id is not None and not force and not self.sampled(trace_id):
             return NOOP_SPAN
+        if cat == PROFILE_CAT:
+            annotation = _profile_annotation(name)
+            if annotation is not None:
+                return _DeviceSpan(self._ring(), name, cat, trace_id, args, annotation)
         return _Span(self._ring(), name, cat, trace_id, args)
 
     def record_span(

@@ -77,7 +77,13 @@ class BatchHandle:
     selection lands (fingerprints may still be in flight); ``fps()`` then
     finalizes this row's digests in the CALLING worker's thread. ``wait_ns``
     accumulates the time this handle actually spent blocked on the device —
-    the hot-path stall the overlap scheduling is there to hide."""
+    the hot-path stall the overlap scheduling is there to hide. It leaves out
+    whatever ``submit`` did before it returned the handle: the pad copy and
+    staging, the leader's window wait and, for a window's leader, the whole
+    batch it ran there (a leader never waits on its own handle, so a window
+    of one row reads 0). All the time a chunk's worker spends on the device
+    path, those included, is the ``device_path_ns`` counter of
+    ``DataPathStats`` (ops/pipeline.py)."""
 
     def __init__(self, entry: _Entry):
         self._entry = entry
@@ -257,25 +263,27 @@ class DeviceBatchRunner:
         runner pads ``arr`` into a pooled buffer and recycles it itself;
         caller-provided padded buffers are left alone (legacy path)."""
         pooled = padded is None
-        if pooled:
-            n = len(arr)
-            padded = self.pool.acquire(bucket_size(n))
-            padded[:n] = arr
-            padded[n:] = 0
-        entry = _Entry(arr=padded, n=len(arr), pooled=pooled)
-        # double-buffered H2D (single-device runners): upload NOW (async) so
-        # the transfer overlaps the in-flight window's compute and this
-        # worker's own socket pump; the flush then stacks device-resident
-        # buffers. Sharded runners skip staging — device_put would pin every
-        # row on chip 0 and the mesh kernels would reshard at flush, paying
-        # the transfer on the critical path anyway. Staging failure is not
-        # fatal — the flush falls back to a host upload for that row.
-        if self.mesh is None:
-            try:
-                entry.dev = self._fused.stage(padded)
-            except Exception as err:  # noqa: BLE001
-                entry.dev = None
-                self._note_stage_failure(len(padded), err)
+        # on the worker's own thread, overlapped with the row ahead on the device
+        with get_tracer().span("batch.stage", cat="device", args={"bytes": len(arr)}):
+            if pooled:
+                n = len(arr)
+                padded = self.pool.acquire(bucket_size(n))
+                padded[:n] = arr
+                padded[n:] = 0
+            entry = _Entry(arr=padded, n=len(arr), pooled=pooled)
+            # double-buffered H2D (single-device runners): upload NOW (async) so
+            # the transfer overlaps the in-flight window's compute and this
+            # worker's own socket pump; the flush then stacks device-resident
+            # buffers. Sharded runners skip staging — device_put would pin every
+            # row on chip 0 and the mesh kernels would reshard at flush, paying
+            # the transfer on the critical path anyway. Staging failure is not
+            # fatal — the flush falls back to a host upload for that row.
+            if self.mesh is None:
+                try:
+                    entry.dev = self._fused.stage(padded)
+                except Exception as err:  # noqa: BLE001
+                    entry.dev = None
+                    self._note_stage_failure(len(padded), err)
         bucket = len(padded)
         with self._lock:
             group = self._open.setdefault(bucket, [])

@@ -114,14 +114,15 @@ def segment_fingerprint_cumsum(
     b = data.astype(jnp.uint32)
     lanes = []
     for li in range(N_LANES):
-        powers = tables[li][rev_pos]  # [N] uint32
-        terms = mulmod31(b, powers)  # [N] < 2^31
-        acc = jnp.zeros((n_segments,), jnp.uint32)
-        for k in range(4):
-            limb = (terms >> np.uint32(8 * k)) & np.uint32(0xFF)
-            cs = jnp.concatenate([jnp.zeros((1,), jnp.uint32), jnp.cumsum(limb)])  # [N+1], wraps mod 2^32
-            s = cs[seg_ends] - cs[seg_starts]  # exact segment sums (< 2^26)
-            acc = addmod31(acc, mulmod31(fold31(s), jnp.uint32((1 << (8 * k)) % M31)))
+        with jax.named_scope(f"lane{li}"):  # each pass over the chunk under its own name in a device trace
+            powers = tables[li][rev_pos]  # [N] uint32
+            terms = mulmod31(b, powers)  # [N] < 2^31
+            acc = jnp.zeros((n_segments,), jnp.uint32)
+            for k in range(4):
+                limb = (terms >> np.uint32(8 * k)) & np.uint32(0xFF)
+                cs = jnp.concatenate([jnp.zeros((1,), jnp.uint32), jnp.cumsum(limb)])  # [N+1], wraps mod 2^32
+                s = cs[seg_ends] - cs[seg_starts]  # exact segment sums (< 2^26)
+                acc = addmod31(acc, mulmod31(fold31(s), jnp.uint32((1 << (8 * k)) % M31)))
         lanes.append(acc)
     return jnp.stack(lanes, axis=-1)  # [n_segments, LANES]
 

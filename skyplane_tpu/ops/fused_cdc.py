@@ -57,6 +57,7 @@ is the TPU-native data-path addition (BASELINE.json north star).
 from __future__ import annotations
 
 import threading
+import time
 from functools import partial
 from typing import List, Optional, Tuple
 
@@ -103,14 +104,18 @@ def _candidates_impl(batch: jax.Array, lens: jax.Array, *, mask_bits: int, cap: 
     (ascending, sentinel-padded) and the true candidate count."""
     bucket = batch.shape[-1]
 
-    def one(chunk, n):
+    def one(chunk, n):  # the named scopes are the stages' stable names in a device trace
         iota = jax.lax.iota(jnp.int32, bucket)
-        valid = boundary_candidate_mask(gear_hash(chunk, pallas=_pallas), mask_bits) & (iota < n)
-        n_cand = valid.sum(dtype=jnp.int32)
-        pos = jnp.cumsum(valid.astype(jnp.int32)) - 1
-        scatter_to = jnp.where(valid & (pos < cap), pos, cap)  # cap -> dropped
-        cand = jnp.full((cap,), bucket, jnp.int32).at[scatter_to].min(iota, mode="drop")
-        return jnp.concatenate([cand, n_cand[None]])
+        with jax.named_scope("cdc.gear_hash"):
+            h = gear_hash(chunk, pallas=_pallas)
+        with jax.named_scope("cdc.candidate_mask"):
+            valid = boundary_candidate_mask(h, mask_bits) & (iota < n)
+        with jax.named_scope("cdc.compaction"):
+            n_cand = valid.sum(dtype=jnp.int32)
+            pos = jnp.cumsum(valid.astype(jnp.int32)) - 1
+            scatter_to = jnp.where(valid & (pos < cap), pos, cap)  # cap -> dropped
+            cand = jnp.full((cap,), bucket, jnp.int32).at[scatter_to].min(iota, mode="drop")
+            return jnp.concatenate([cand, n_cand[None]])
 
     return jax.vmap(one)(batch, lens)
 
@@ -127,16 +132,19 @@ def _fp_body(batch: jax.Array, ends_slots: jax.Array, *, n_slots: int):
 
     def one(chunk, ends):
         iota = jax.lax.iota(jnp.int32, bucket)
-        # byte at an end offset belongs to the NEXT segment; ends == bucket
-        # (full-chunk final end, or sentinel padding) scatter out of range
-        marks = jnp.zeros((bucket,), jnp.int32).at[ends].add(1, mode="drop")
-        seg_ids = jnp.cumsum(marks)
-        seg_end = ends[jnp.minimum(seg_ids, n_slots - 1)]
-        rev_pos = jnp.clip(seg_end - 1 - iota, 0, MAX_SEGMENT_BYTES - 1)
+        with jax.named_scope("fp.segment_ids"):
+            # byte at an end offset belongs to the NEXT segment; ends == bucket
+            # (full-chunk final end, or sentinel padding) scatter out of range
+            marks = jnp.zeros((bucket,), jnp.int32).at[ends].add(1, mode="drop")
+            seg_ids = jnp.cumsum(marks)
+        with jax.named_scope("fp.reverse_positions"):
+            seg_end = ends[jnp.minimum(seg_ids, n_slots - 1)]
+            rev_pos = jnp.clip(seg_end - 1 - iota, 0, MAX_SEGMENT_BYTES - 1)
         starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
         c = jnp.clip(ends, 0, bucket)
         s = jnp.clip(starts, 0, bucket)
-        return segment_fingerprint_cumsum(chunk, rev_pos, jnp.minimum(s, c), c, n_segments=n_slots)
+        with jax.named_scope("fp.lane_passes"):  # one scope ``lane<i>`` per pass inside
+            return segment_fingerprint_cumsum(chunk, rev_pos, jnp.minimum(s, c), c, n_segments=n_slots)
 
     return jax.vmap(one)(batch, ends_slots)
 
@@ -145,6 +153,50 @@ def _fp_body(batch: jax.Array, ends_slots: jax.Array, *, n_slots: int):
 # argument (HBM reuse), the plain one leaves it valid for the caller
 _fp_impl = partial(jax.jit, static_argnames=("n_slots",))(_fp_body)
 _fp_impl_donated = partial(jax.jit, static_argnames=("n_slots",), donate_argnums=(0,))(_fp_body)
+
+
+# ---- backend compiles, counted for the whole process ----
+#
+# jax reports every backend compile request as a duration event. In this jax
+# the event also fires when the program came from the persistent cache; the
+# cache's own retrieval event comes first, on the same thread, so the listener
+# drops the compile event that follows one: a load is not a compile.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_compile_lock = threading.Lock()
+_compile_counts = {"xla_compiles": 0, "xla_compile_ns": 0}
+_compile_listening = False
+_cache_hit = threading.local()
+
+
+def _on_duration_event(event: str, duration_secs: float, **_kwargs) -> None:
+    if event == CACHE_HIT_EVENT:
+        _cache_hit.pending = True
+    elif event == COMPILE_EVENT:
+        if getattr(_cache_hit, "pending", False):
+            _cache_hit.pending = False
+            return
+        with _compile_lock:
+            _compile_counts["xla_compiles"] += 1
+            _compile_counts["xla_compile_ns"] += int(duration_secs * 1e9)
+
+
+def _listen_for_compiles() -> None:
+    """Register the one listener, once per process (first FusedCDCFP built)."""
+    global _compile_listening
+    with _compile_lock:
+        if _compile_listening:
+            return
+        _compile_listening = True
+    jax.monitoring.register_event_duration_secs_listener(_on_duration_event)
+
+
+def compile_counters() -> dict:
+    """Backend compiles of this PROCESS since the listener went in, whoever
+    asked for them: a shape that depends on content recompiles mid-transfer
+    and shows here, where nothing else would say so."""
+    with _compile_lock:
+        return dict(_compile_counts)
 
 
 def _host_exact(arr: np.ndarray, params: CDCParams) -> Tuple[np.ndarray, List[bytes]]:
@@ -263,7 +315,8 @@ class FusedCDCFP:
         self._shards = int(np.prod([mesh.shape[a] for a in self.shard_axes])) if mesh is not None else 1
         self._sharded = {}  # bucket -> (candidates_fn, fp_fn)
         self._stats_lock = threading.Lock()
-        self._donated_batches = 0
+        self._counters = {"donated_batches": 0, "fused_rows": 0, "fused_gap_ns": 0, "fused_gap_cpu_ns": 0}
+        _listen_for_compiles()
 
     def _kernels(self, bucket: int):
         cap = candidate_cap(bucket, self.params)
@@ -299,8 +352,18 @@ class FusedCDCFP:
             self.pool.release_scratch(arr)
 
     def counters(self) -> dict:
+        """``fused_rows``: real rows dispatched (pad rows are not counted).
+        ``fused_gap_ns``: the host's part of the gap between the two programs,
+        wall time from call A's candidates being on the host to call B
+        enqueued (the extent of ``fused.select`` + ``fused.enqueue_b``);
+        ``fused_gap_cpu_ns``: this thread's CPU time over the same interval —
+        the rest is time the leader did not run (interpreter lock, scheduler,
+        a blocked transfer). ``xla_compiles`` / ``xla_compile_ns`` are the
+        process's, see :func:`compile_counters`."""
         with self._stats_lock:
-            return {"donated_batches": self._donated_batches}
+            out = dict(self._counters)
+        out.update(compile_counters())
+        return out
 
     def dispatch(self, batch, lens, dev_rows: Optional[List[jax.Array]] = None) -> PendingBatch:
         """Run call A + host boundary selection and ENQUEUE call B.
@@ -337,42 +400,57 @@ class FusedCDCFP:
                 return jnp.asarray(np.stack(host_rows[g0:g1]))  # uploaded once, shared by both calls
             return jnp.asarray(batch[g0:g1])  # contiguous input passes straight through
 
-        dev_groups = [group_input(g0, g1) for g0, g1 in groups]
         lens_np = np.asarray(lens, np.int32)
+        # the host steps below are sibling spans that tile the leader's time
+        # from here to call B enqueued; get_tracer().span is looked up at
+        # each site, so a replacement installed later is the one called
+        with get_tracer().span("fused.stack", cat="device", args={"rows": b, "bucket": bucket}):
+            dev_groups = [group_input(g0, g1) for g0, g1 in groups]
         with get_tracer().span("fused.dispatch", cat="device", args={"rows": b, "bucket": bucket}):
             # every group's call A is enqueued before the first (small) fetch
             packed_dev = [cand_fn(d, jnp.asarray(lens_np[g0:g1])) for d, (g0, g1) in zip(dev_groups, groups)]
             packed = np.concatenate([np.asarray(p) for p in packed_dev])
+        gap_t0, gap_cpu_t0 = time.perf_counter_ns(), time.thread_time_ns()
         ends_rows: List[Optional[np.ndarray]] = []
         fallback: List[Optional[Tuple[np.ndarray, List[bytes]]]] = []
-        if self.pool is not None:
-            ends_scratch = self.pool.acquire_scratch((b, n_slots), np.int32)
-        else:
-            ends_scratch = None
+        ends_scratch = None
+        donated = False
         try:
-            if ends_scratch is not None:
-                ends_scratch.fill(bucket)
-            ends_slots = ends_scratch if ends_scratch is not None else np.full((b, n_slots), bucket, np.int32)
-            for i in range(b):
-                n = int(lens[i])
-                n_cand = int(packed[i, cap])
-                if n_cand > cap:  # overflow: device compaction truncated the list
-                    fallback.append(_host_exact(np.asarray(host_rows[i][:n]), self.params))
-                    ends_rows.append(None)
-                    continue
-                fallback.append(None)
-                cands = packed[i, :n_cand].astype(np.int64)
-                ends = select_boundaries(cands, n, self.params)
-                ends_rows.append(ends)
-                ends_slots[i, : len(ends)] = ends
-                if n < bucket:  # one garbage end covering the zero padding
-                    ends_slots[i, len(ends)] = bucket
-            if self.donate and owned and self.mesh is None:
-                fp_fn = partial(_fp_impl_donated, n_slots=n_slots)
-                with self._stats_lock:
-                    self._donated_batches += 1
-            # enqueued; readback deferred
-            lanes_dev = [fp_fn(d, jnp.asarray(ends_slots[g0:g1])) for d, (g0, g1) in zip(dev_groups, groups)]
+            with get_tracer().span("fused.select", cat="device", args={"rows": b}):
+                if self.pool is not None:
+                    ends_scratch = self.pool.acquire_scratch((b, n_slots), np.int32)
+                    ends_scratch.fill(bucket)
+                ends_slots = ends_scratch if ends_scratch is not None else np.full((b, n_slots), bucket, np.int32)
+                for i in range(b):
+                    n = int(lens[i])
+                    n_cand = int(packed[i, cap])
+                    if n_cand > cap:  # overflow: device compaction truncated the list
+                        fallback.append(_host_exact(np.asarray(host_rows[i][:n]), self.params))
+                        ends_rows.append(None)
+                        continue
+                    fallback.append(None)
+                    cands = packed[i, :n_cand].astype(np.int64)
+                    ends = select_boundaries(cands, n, self.params)
+                    ends_rows.append(ends)
+                    ends_slots[i, : len(ends)] = ends
+                    if n < bucket:  # one garbage end covering the zero padding
+                        ends_slots[i, len(ends)] = bucket
+            with get_tracer().span("fused.enqueue_b", cat="device", args={"rows": b}):
+                if self.donate and owned and self.mesh is None:
+                    fp_fn = partial(_fp_impl_donated, n_slots=n_slots)
+                    donated = True
+                # enqueued; readback deferred
+                lanes_dev = [fp_fn(d, jnp.asarray(ends_slots[g0:g1])) for d, (g0, g1) in zip(dev_groups, groups)]
+            # the CPU clock is read inside the wall clock's interval at both
+            # ends, so CPU time cannot come out above wall time
+            gap_cpu_ns = time.thread_time_ns() - gap_cpu_t0
+            gap_ns = time.perf_counter_ns() - gap_t0
+            with self._stats_lock:
+                c = self._counters
+                c["donated_batches"] += donated
+                c["fused_rows"] += int(np.count_nonzero(lens_np))
+                c["fused_gap_ns"] += gap_ns
+                c["fused_gap_cpu_ns"] += gap_cpu_ns
         except BaseException:
             if ends_scratch is not None:
                 # an overflow-row host recompute or a failed device dispatch

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import hashlib
 import threading
+import time
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Callable, List, Optional
@@ -123,7 +124,17 @@ class DataPathStats:
     (bench-smoke and dashboard queries rely on the keys always existing).
     """
 
-    _KEYS = ("chunks", "raw_bytes", "wire_bytes", "segments", "ref_segments", "device_wait_ns")
+    _KEYS = (
+        "chunks",
+        "raw_bytes",
+        "wire_bytes",
+        "segments",
+        "ref_segments",
+        "device_wait_ns",
+        "device_path_ns",
+        "recipe_ns",
+        "seal_ns",
+    )
     EXTERNAL_ZERO = {
         "pool_hits": 0,
         "pool_misses": 0,
@@ -139,6 +150,11 @@ class DataPathStats:
         "batch_occupancy": 0.0,
         "stage_failures": 0,
         "donated_batches": 0,
+        "fused_rows": 0,
+        "fused_gap_ns": 0,
+        "fused_gap_cpu_ns": 0,
+        "xla_compiles": 0,
+        "xla_compile_ns": 0,
     }
 
     def __init__(self):
@@ -156,19 +172,35 @@ class DataPathStats:
             self._tls.counters = d
         return d
 
-    def observe(self, p: ProcessedPayload) -> None:
+    def observe(self, p: ProcessedPayload, device_path_ns: int = 0, recipe_ns: int = 0) -> None:
+        """One chunk done. ``device_path_ns``: wall time its worker spent on
+        CDC + fingerprints, from submission to finalized digests — the pad
+        copy and staging, the window wait, a leader's whole batch, a
+        follower's waits, ``finalize_row`` (on a gateway with no accelerator,
+        the host kernels). ``recipe_ns``: ``build_recipe`` (dedup-index
+        lookups, literal join, codec). Added together with ``chunks``, their
+        denominator, so a scrape between chunks sees whole chunks."""
         d = self._shard()
         d["chunks"] += 1
         d["raw_bytes"] += p.raw_len
         d["wire_bytes"] += len(p.wire_bytes)
         d["segments"] += p.n_segments
         d["ref_segments"] += p.n_ref_segments
+        d["device_path_ns"] += device_path_ns
+        d["recipe_ns"] += recipe_ns
 
     def observe_device_wait(self, ns: int) -> None:
         """Time this worker spent BLOCKED on the device (phase waits in the
-        batch runner) — the stall the overlap scheduling exists to hide."""
+        batch runner) — the stall the overlap scheduling exists to hide.
+        Only a follower's waits on its handle: what ``submit`` itself took
+        (a leader's whole batch) is in ``device_path_ns``, not here."""
         if ns:
             self._shard()["device_wait_ns"] += int(ns)
+
+    def observe_seal(self, ns: int) -> None:
+        """Wall time of the E2EE seal of one chunk's wire payload (the sender
+        operator's, after ``process`` returned)."""
+        self._shard()["seal_ns"] += int(ns)
 
     def add_source(self, fn: Callable[[], dict]) -> None:
         """Register an external counter provider merged into as_dict()."""
@@ -368,9 +400,12 @@ class DataPathProcessor:
 
     def process(self, data: bytes, index: Optional[SenderDedupIndex] = None) -> ProcessedPayload:
         raw_len = len(data)
+        device_path_ns = recipe_ns = 0
         if self.dedup and index is not None and raw_len > 0:
             arr = np.frombuffer(data, np.uint8)
+            t = time.perf_counter_ns()
             phased = self._cdc_and_fps_phased(arr)
+            device_path_ns = time.perf_counter_ns() - t
             # boundary-dependent assembly runs BETWEEN the phases: spans are
             # final once ends land, so they're cut while the fingerprint
             # readback of this worker's batch is still in flight
@@ -383,10 +418,14 @@ class DataPathProcessor:
             for end in ends_l:
                 spans.append(mv[start:end])
                 start = end
+            t = time.perf_counter_ns()
             seg_fps = phased.fps()
+            device_path_ns += time.perf_counter_ns() - t
             self.stats.observe_device_wait(phased.wait_ns)
             segments = list(zip(seg_fps, spans))
+            t = time.perf_counter_ns()
             wire, n_ref, lit_bytes, new_fps, ref_fps = build_recipe(segments, index, self.codec.encode)
+            recipe_ns = time.perf_counter_ns() - t
             payload = ProcessedPayload(
                 wire_bytes=wire,
                 codec=self.codec.codec_id,
@@ -416,7 +455,7 @@ class DataPathProcessor:
                 raw_len=raw_len,
                 fingerprint=fp,
             )
-        self.stats.observe(payload)
+        self.stats.observe(payload, device_path_ns, recipe_ns)
         return payload
 
     # ---- decode ----
