@@ -10,11 +10,15 @@ below corruption rates of the underlying networks. The 8x-uint32 lane vector
 is mixed to the 128-bit wire fingerprint with blake2b on host (32 bytes per
 segment — negligible).
 
-Everything device-side is parallel: per-byte powers come from a precomputed
-table indexed by position-within-segment (reversed), per-byte terms are
-``mulmod31`` products, and per-segment sums use limb-split ``segment_sum``
-(4 x 8-bit limbs so uint32 accumulators cannot overflow for segments up to
-2^24 bytes).
+Everything device-side is parallel: per-byte terms are ``mulmod31`` products
+with a precomputed power and per-segment sums are limb-split (4 x 8-bit
+limbs so uint32 accumulators cannot overflow for segments up to 2^24 bytes).
+Two formulations, bit-identical: ``segment_fingerprint_device`` indexes the
+power table by each byte's reversed position within its segment and sums with
+``segment_sum`` (fixed-stride segments, ``fixed_stride_lanes``);
+``segment_fingerprint_cumsum`` (content-defined segments, call B of
+ops/fused_cdc.py) takes the power from the byte's position in the row alone
+and the sums from prefix-sum differences, so nothing is indexed per byte.
 """
 
 from __future__ import annotations
@@ -26,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from skyplane_tpu.ops.u32 import M31, addmod31, fold31, mulmod31, powmod31_table
+from skyplane_tpu.ops.u32 import M31, addmod31, fold31, mulmod31, powmod31_table, powmod31_table_device
 
 N_LANES = 8
 MAX_SEGMENT_BYTES = 1 << 18  # power table length; must cover cdc_max_bytes
@@ -85,46 +89,88 @@ def segment_fingerprint_device(data: jax.Array, seg_ids: jax.Array, rev_pos: jax
 
 
 @partial(jax.jit, static_argnames=("n_segments",))
-def segment_fingerprint_cumsum(
-    data: jax.Array, rev_pos: jax.Array, seg_starts: jax.Array, seg_ends: jax.Array, n_segments: int
-):
-    """Per-segment 8-lane polynomial hash for CONTIGUOUS segments, scatter-free.
+def segment_fingerprint_cumsum(data: jax.Array, seg_starts: jax.Array, seg_ends: jax.Array, n_segments: int):
+    """Per-segment 8-lane polynomial hash for CONTIGUOUS segments, with no
+    per-byte index: no scatter, and no gather over the row.
 
-    Because segments tile the byte range in order, per-segment sums are
-    differences of a running prefix sum — cumsum + two tiny gathers — instead
-    of ``segment_sum``'s scatter-add, which TPU compiles poorly (sort-based
-    expansion) at multi-MiB operand sizes. Bit-identical to
-    ``segment_fingerprint_device`` (tested).
+    The row is read as blocks of ``T = min(MAX_SEGMENT_BYTES, N)`` bytes. With
+    q = r^-1 in the field, a byte i of block k contributes
+
+        b_i * r^(e-1-i)  =  r^(e-1-kT) * b_i * q^(i - kT)
+
+    to the segment ending at e, so the per-byte factor q^(i mod T) depends on
+    the POSITION alone: one [T] table broadcast over the row viewed [N/T, T].
+    Per-segment sums are differences of the limb prefix sums of a block, taken
+    once per block the segment touches — a segment no longer than T touches at
+    most two, cut at the block edge — and each piece is scaled by r^(e-1-kT),
+    exponent in [0, 2T), looked up per slot: a few n_segments-sized look-ups
+    per lane and limb instead of one per byte. Exact in GF(2^31 - 1), so
+    bit-identical to ``segment_fingerprint_device`` and to the host kernels
+    (tested).
 
     Args:
-      data:       [N] uint8 chunk bytes.
-      rev_pos:    [N] int32 reversed position within segment (end-1-i).
+      data:       [N] uint8 chunk bytes, N a multiple of T (every bucket is a
+                  power of two, and so is MAX_SEGMENT_BYTES).
       seg_starts: [n_segments] int32 start offset per slot.
       seg_ends:   [n_segments] int32 end offset per slot (== start for empty
-                  pad slots; both clamped to [0, N]).
+                  pad slots; both clamped to [0, N]). A slot longer than T
+                  must hold zero bytes only (the garbage slot over the row's
+                  zero padding): every sum over it is 0 whatever its factors.
       n_segments: static slot count.
 
-    Exactness: limbs are 8-bit, so a segment's limb sum is < 2^18 * 255 <
-    2^26; prefix sums wrap mod 2^32 but differences of uint32 prefix values
-    recover the exact segment sum.
+    Exactness: limbs are 8-bit and a block's prefix sums restart at its first
+    byte, so every prefix sum and every piece's limb sum is < 2^18 * 255 <
+    2^26: uint32 holds them with no wrap.
 
     Returns [n_segments, N_LANES] uint32 lane values in canonical [0, M31).
     """
-    tables = jnp.asarray(_power_tables())  # [LANES, MAX] uint32
-    b = data.astype(jnp.uint32)
-    lanes = []
-    for li in range(N_LANES):
-        with jax.named_scope(f"lane{li}"):  # each pass over the chunk under its own name in a device trace
-            powers = tables[li][rev_pos]  # [N] uint32
-            terms = mulmod31(b, powers)  # [N] < 2^31
-            acc = jnp.zeros((n_segments,), jnp.uint32)
-            for k in range(4):
-                limb = (terms >> np.uint32(8 * k)) & np.uint32(0xFF)
-                cs = jnp.concatenate([jnp.zeros((1,), jnp.uint32), jnp.cumsum(limb)])  # [N+1], wraps mod 2^32
-                s = cs[seg_ends] - cs[seg_starts]  # exact segment sums (< 2^26)
-                acc = addmod31(acc, mulmod31(fold31(s), jnp.uint32((1 << (8 * k)) % M31)))
-        lanes.append(acc)
-    return jnp.stack(lanes, axis=-1)  # [n_segments, LANES]
+    n = data.shape[0]
+    period = min(MAX_SEGMENT_BYTES, n)
+    if n % period:
+        raise ValueError(f"row length {n} is not a multiple of the fingerprint period {period}")
+    bases = [int(b) for b in LANE_BASES]
+    with jax.named_scope("fp.power_tables"):
+        inv = powmod31_table_device([pow(b, -1, M31) for b in bases], period)  # q^j, the per-byte factor
+        fwd = powmod31_table_device(bases, 2 * period)  # r^x, the per-piece factor
+
+    with jax.named_scope("fp.piece_bounds"):
+        # piece 1 = [start, mid) in the block of the first byte, piece 2 =
+        # [block_hi, end) in the block of the last, when that is another one
+        block_lo = (seg_starts // period) * period
+        mid = jnp.minimum(seg_ends, block_lo + period)
+        block_hi = ((seg_ends - 1) // period) * period
+        exp1 = jnp.clip(seg_ends - 1 - block_lo, 0, 2 * period - 1)  # clip: empty and garbage slots
+        exp2 = jnp.clip(seg_ends - 1 - block_hi, 0, 2 * period - 1)
+        # the sum of a block's bytes before row offset idx is the block's
+        # inclusive prefix sum at idx - 1, and 0 at the block's start
+        bounds = jnp.stack([seg_starts, mid, seg_ends])  # [3, n_segments]
+        last = jnp.clip(bounds - 1, 0, n - 1)
+        block_row, block_col = last // period, last % period
+        live = jnp.stack([seg_starts > block_lo, mid > block_lo, block_hi > block_lo])
+
+    b = data.astype(jnp.uint32).reshape(n // period, period)
+    reads = []  # per lane and limb, the prefix sums at the three bounds: [3, n_segments]
+    with jax.named_scope("fp.lane_passes"):
+        for li in range(N_LANES):
+            with jax.named_scope(f"lane{li}"):  # each pass over the chunk under its own name in a device trace
+                terms = mulmod31(b, inv[li][None, :])  # [N/T, T] < 2^31
+                for k in range(4):
+                    limb = (terms >> np.uint32(8 * k)) & np.uint32(0xFF)
+                    cs = jnp.cumsum(limb, axis=1)  # per block, inclusive: < 2^26, no wrap
+                    reads.append(cs[block_row, block_col])
+    with jax.named_scope("fp.piece_factors"):
+        # everything per slot, all lanes and limbs at once: [LANES, 4, n_segments]
+        before = jnp.where(live, jnp.stack(reads).reshape(N_LANES, 4, 3, n_segments), np.uint32(0))
+        shift = jnp.asarray([(1 << (8 * k)) % M31 for k in range(4)], jnp.uint32)[None, :, None]
+
+        def recombine(limb_sums):  # exact limb sums (< 2^26) -> sum_k limb_k * 2^(8k) mod M31
+            w = mulmod31(fold31(limb_sums), shift)
+            return addmod31(addmod31(w[:, 0], w[:, 1]), addmod31(w[:, 2], w[:, 3]))
+
+        piece1 = recombine(before[:, :, 1] - before[:, :, 0])
+        piece2 = recombine(before[:, :, 2])
+        lanes = addmod31(mulmod31(piece1, fwd[:, exp1]), mulmod31(piece2, fwd[:, exp2]))
+    return lanes.T  # [n_segments, LANES]
 
 
 def fixed_stride_lanes(chunk, fp_seg_bytes: int, pallas=None):

@@ -15,10 +15,13 @@ every transfer tiny and every device op vectorized:
            -> packed [B, cap+1] int32 readback (~16 KiB per 64 MiB batch)
   host:    greedy min/max selection over the sparse candidate indices
            (microseconds; bit-identical to ops/cdc.py select_boundaries)
-  call B:  per-byte segment mapping from the uploaded [B, n_slots] end
-           offsets (scatter marks + cumsum + gather — no [B, N] uploads)
-           -> 8-lane fingerprints via cumsum differences (scatter-free,
-           ops/fingerprint.py segment_fingerprint_cumsum)
+  call B:  8-lane fingerprints of the slots the uploaded [B, n_slots] end
+           offsets delimit (no [B, N] uploads), with nothing mapped per
+           byte: per-byte powers depend on the position alone (one table
+           per lane, periodic over the row), segment sums are differences
+           of per-block prefix sums at the slot bounds, scaled per slot
+           (ops/fingerprint.py segment_fingerprint_cumsum: no scatter, no
+           gather over the row)
            -> [B, n_slots, 8] readback (~0.5 MiB per 64 MiB batch)
 
 The chunk batch is uploaded once and stays device-resident across both
@@ -67,12 +70,7 @@ import numpy as np
 
 from skyplane_tpu.obs import get_tracer
 from skyplane_tpu.ops.cdc import CDCParams, select_boundaries
-from skyplane_tpu.ops.fingerprint import (
-    MAX_SEGMENT_BYTES,
-    N_LANES,
-    finalize_fingerprint,
-    segment_fingerprint_cumsum,
-)
+from skyplane_tpu.ops.fingerprint import finalize_fingerprint, segment_fingerprint_cumsum
 from skyplane_tpu.ops.gear import boundary_candidate_mask, gear_hash
 
 
@@ -89,12 +87,13 @@ def slots_cap(bucket: int, params: CDCParams) -> int:
     return bucket // params.min_bytes + 2
 
 
-# Rows one dispatch may carry, in bytes per device. Both programs keep ~130
+# Rows one dispatch may carry, in bytes per device. The programs keep tens of
 # bytes of int32/uint32 temporaries per input byte (gear doubling passes,
-# cumsums, gathered powers, limb prefix sums): measured on a TPU v5e (16 GB),
-# call B over [1, 64 MiB] compiles to 8.6 GB of HLO temporaries and over
-# [8, 64 MiB] to 64 GB, which the compiler refuses. A window larger than this
-# is dispatched as several programs of this size, one after the other.
+# per-lane terms, limb prefix sums): compiled for a TPU v5e (16 GB), call B
+# over [1, 64 MiB] holds 2.96 GB of HLO temporaries (~44 bytes per input
+# byte; 8.6 GB while it gathered its powers per byte, when [8, 64 MiB] was
+# refused at 64 GB) and call A 1.34 GB. A window larger than this is
+# dispatched as several programs of this size, one after the other.
 ROW_GROUP_BYTES = 64 << 20
 
 
@@ -124,27 +123,18 @@ def _fp_body(batch: jax.Array, ends_slots: jax.Array, *, n_slots: int):
     """[B, bucket] u8 + [B, n_slots] i32 end offsets -> [B, n_slots, 8] u32.
 
     ends_slots rows: ascending real segment ends (last == chunk length),
-    then one `bucket` garbage end when the chunk is shorter than the bucket,
-    then `bucket` sentinels (scatter-dropped) up to n_slots. Mirrors the
-    host ``segment_ids_and_rev_pos`` semantics exactly.
+    then one `bucket` garbage end when the chunk is shorter than the bucket
+    (its bytes are the row's zero padding, so its lanes are 0 and never
+    read), then `bucket` sentinels up to n_slots (empty slots: start == end).
+    Slot j is [ends[j-1], ends[j]); nothing is mapped per byte.
     """
     bucket = batch.shape[-1]
 
     def one(chunk, ends):
-        iota = jax.lax.iota(jnp.int32, bucket)
-        with jax.named_scope("fp.segment_ids"):
-            # byte at an end offset belongs to the NEXT segment; ends == bucket
-            # (full-chunk final end, or sentinel padding) scatter out of range
-            marks = jnp.zeros((bucket,), jnp.int32).at[ends].add(1, mode="drop")
-            seg_ids = jnp.cumsum(marks)
-        with jax.named_scope("fp.reverse_positions"):
-            seg_end = ends[jnp.minimum(seg_ids, n_slots - 1)]
-            rev_pos = jnp.clip(seg_end - 1 - iota, 0, MAX_SEGMENT_BYTES - 1)
-        starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
-        c = jnp.clip(ends, 0, bucket)
-        s = jnp.clip(starts, 0, bucket)
-        with jax.named_scope("fp.lane_passes"):  # one scope ``lane<i>`` per pass inside
-            return segment_fingerprint_cumsum(chunk, rev_pos, jnp.minimum(s, c), c, n_segments=n_slots)
+        with jax.named_scope("fp.slot_bounds"):
+            c = jnp.clip(ends, 0, bucket)
+            s = jnp.concatenate([jnp.zeros((1,), jnp.int32), c[:-1]])
+        return segment_fingerprint_cumsum(chunk, s, c, n_segments=n_slots)
 
     return jax.vmap(one)(batch, ends_slots)
 

@@ -10,6 +10,7 @@ numpy mirror (``*_np``) is provided for property tests against Python ints.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -62,6 +63,21 @@ def powmod31_table(base: int, n: int) -> np.ndarray:
         out[m : m + take] = step[:take] % M31
         m *= 2
     return out[:n].astype(np.uint32)
+
+
+def powmod31_table_device(bases, n: int):
+    """[len(bases), n] table base^j mod M31 built INSIDE a traced program, on
+    the device, by the same size-doubling (a few dozen small ops a call). The
+    seed passes an optimization barrier: without it the compiler folds the
+    build into constants embedded in the program (40 MiB of them in the 64
+    MiB fingerprint program), which every load of the program then pays."""
+    out = jax.lax.optimization_barrier(jnp.ones((len(bases), 1), jnp.uint32))
+    m = 1
+    while m < n:
+        step = jnp.asarray([pow(int(b), m, M31) for b in bases], jnp.uint32)[:, None]  # base^m
+        out = jnp.concatenate([out, mulmod31(out, step)], axis=1)
+        m *= 2
+    return out[:, :n]
 
 
 # ---- numpy mirrors for property testing ----

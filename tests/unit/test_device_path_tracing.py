@@ -389,7 +389,10 @@ def test_merge_numeric_counters_sums_the_new_keys():
     "program, scopes",
     [
         ("call_a", ("cdc.gear_hash", "cdc.candidate_mask", "cdc.compaction")),
-        ("call_b", ("fp.segment_ids", "fp.reverse_positions", "fp.lane_passes", "lane0", "lane7")),
+        (
+            "call_b",
+            ("fp.slot_bounds", "fp.power_tables", "fp.piece_bounds", "fp.lane_passes", "lane0", "lane7", "fp.piece_factors"),
+        ),
     ],
 )
 def test_device_programs_carry_their_stage_scopes(program, scopes):

@@ -2,9 +2,15 @@
 for all inputs (including the bounded-candidate overflow fallback)."""
 
 import numpy as np
+import pytest
 
 from skyplane_tpu.ops.cdc import CDCParams, cdc_segment_ends
-from skyplane_tpu.ops.fingerprint import segment_fingerprints_host_batch
+from skyplane_tpu.ops.fingerprint import (
+    MAX_SEGMENT_BYTES,
+    segment_fingerprint_cumsum,
+    segment_fingerprint_np,
+    segment_fingerprints_host_batch,
+)
 from skyplane_tpu.ops.fused_cdc import FusedCDCFP, candidate_cap
 
 rng = np.random.default_rng(31)
@@ -162,3 +168,123 @@ def test_mixed_fallback_batch_releases_scratch_via_lanes(monkeypatch):
         np.testing.assert_array_equal(ends, want_ends)
         assert fps == want_fps
     assert pool.counters()["pool_outstanding"] == 0
+
+
+# ---- rows of several fingerprint blocks (bucket >= 4 x the period T) ----
+#
+# The buckets above (64-128 KiB) are all smaller than MAX_SEGMENT_BYTES, so
+# call B sees one block there. These rows are 1 MiB at the shipped CDC
+# parameters: T = 256 KiB, four blocks, and segments that meet the block
+# edges in every way a segment can.
+
+SHIPPED = CDCParams()  # 4 / 16 / 64 KiB
+BIG = 1 << 20
+EDGE = MAX_SEGMENT_BYTES  # the first block edge of a BIG row
+
+
+def _random(n, seed=77):
+    return np.random.default_rng(seed).integers(0, 256, n, dtype=np.uint8)
+
+
+def _candidate_positions(arr, params):
+    from skyplane_tpu.ops.host_fallback import boundary_candidates_host, gear_hash_host
+
+    return np.flatnonzero(boundary_candidates_host(gear_hash_host(arr), params.mask_bits))
+
+
+def _forced_cuts_only(n, params, seed, keep_first=False):
+    """Random bytes with every natural cut candidate removed (one byte at the
+    candidate bumped until the mask misses), so CDC cuts at max_bytes strides;
+    ``keep_first`` leaves the first candidate that can end a segment, which
+    shifts every later stride off the block edges."""
+    arr = _random(n, seed)
+    kept = None
+    for _ in range(64):
+        cands = _candidate_positions(arr, params)
+        if keep_first and kept is None:
+            kept = next(int(p) for p in cands if params.min_bytes <= p + 1 < params.max_bytes)
+        cands = cands[cands != kept] if kept is not None else cands
+        if len(cands) == 0:
+            break
+        arr[cands] += 1
+    else:
+        raise AssertionError("could not clear the cut candidates")
+    return arr
+
+
+MULTI_BLOCK_CASES = {
+    "random_full_row": lambda: [_random(BIG)],
+    "segment_ends_on_block_edge": lambda: [_forced_cuts_only(BIG, SHIPPED, seed=5)],
+    "segment_starts_on_block_edge": lambda: [_forced_cuts_only(2 * EDGE + 5000, SHIPPED, seed=6)],
+    "max_segment_straddles_block_edge": lambda: [_forced_cuts_only(BIG, SHIPPED, seed=7, keep_first=True)],
+    "garbage_slot_spans_blocks": lambda: [_random(100_000)],
+    "batch_with_zero_length_pad_row": lambda: [_random(EDGE + 70_000), np.zeros(0, np.uint8)],
+}
+
+
+@pytest.mark.parametrize("name", list(MULTI_BLOCK_CASES))
+def test_rows_of_several_blocks_match_host(name):
+    chunks = MULTI_BLOCK_CASES[name]()
+    ends = [cdc_segment_ends(c, SHIPPED) for c in chunks if len(c)]
+    starts = [np.concatenate([[0], e[:-1]]) for e in ends]
+    # each case is what its name says, by the host's own cuts
+    if name == "segment_ends_on_block_edge":
+        assert {EDGE, 2 * EDGE, 3 * EDGE} <= set(ends[0].tolist())
+    elif name == "segment_starts_on_block_edge":
+        assert starts[0][-1] == 2 * EDGE and ends[0][-1] == 2 * EDGE + 5000
+    elif name == "max_segment_straddles_block_edge":
+        across = (starts[0] < EDGE) & (ends[0] > EDGE)
+        assert across.any() and (ends[0] - starts[0])[across][0] == SHIPPED.max_bytes
+    elif name == "random_full_row":
+        assert ((starts[0] // EDGE) != ((ends[0] - 1) // EDGE)).any(), "no segment crosses a block edge"
+    fused = FusedCDCFP(SHIPPED, pallas=False)
+    batch = np.stack([_pad(c, BIG) for c in chunks])
+    results = fused(batch, [len(c) for c in chunks])
+    for c, (got_ends, got_fps) in zip(chunks, results):
+        if not len(c):
+            continue  # the pad row: nothing to compare, it must only not disturb its neighbour
+        want_ends, want_fps = _expected(c, SHIPPED)
+        np.testing.assert_array_equal(got_ends, want_ends)
+        assert got_fps == want_fps
+
+
+def test_segment_fingerprint_cumsum_hand_placed_ends():
+    """The device formulation alone, slots placed by hand around two block
+    edges of a three-block row, against the per-byte python reference."""
+    import jax.numpy as jnp
+
+    t = MAX_SEGMENT_BYTES
+    n = 3 * t
+    data = np.zeros(n, np.uint8)
+    r = np.random.default_rng(9)
+    ends = np.array(
+        [
+            t - 9000,  # zero bytes only, inside block 0
+            t - 3000,  # inside block 0
+            t,  # ends on the edge
+            t + 1,  # starts on the edge, one byte
+            2 * t - 700,  # zero tail but a live head: still inside block 1
+            2 * t - 700,  # an empty slot
+            2 * t + 800,  # crosses the second edge
+            n,  # the rest: zeros over a whole block and more
+            n,  # an empty slot at the row's end
+        ],
+        np.int64,
+    )
+    starts = np.concatenate([[0], ends[:-1]])
+    live = [(t - 9000, t + 1), (2 * t - 700, 2 * t + 800)]  # windows of slot-aligned random bytes
+    data[t + 1 : t + 2000] = r.integers(1, 256, 1999, dtype=np.uint8)  # the head of slot 4
+    for a, b in live:
+        data[a:b] = r.integers(0, 256, b - a, dtype=np.uint8)
+    got = np.asarray(
+        segment_fingerprint_cumsum(
+            jnp.asarray(data), jnp.asarray(starts, jnp.int32), jnp.asarray(ends, jnp.int32), n_segments=len(ends)
+        )
+    )
+    want = np.zeros((len(ends), 8), np.uint32)
+    for a, b in live:
+        inside = (starts >= a) & (ends <= b) & (ends > starts)
+        want[inside] = segment_fingerprint_np(data[a:b], ends[inside] - a)
+    want[4] = segment_fingerprint_np(data[t + 1 : 2 * t - 700], [t - 701])[0]
+    np.testing.assert_array_equal(got, want)
+    assert want[[1, 2, 3, 4, 6]].all() and not want[[0, 5, 7, 8]].any()
