@@ -347,9 +347,10 @@ def main(argv=None) -> int:
                 k: counters.get(k, 0)
                 for k in (
                     "batch_rows", "batch_windows", "batch_padded_rows", "spmd_batches", "stage_failures",
-                    "donated_batches", "segments", "ref_segments", "raw_bytes", "wire_bytes",
+                    "donated_batches", "segments", "ref_segments", "literal_bytes", "raw_bytes", "wire_bytes",
                 )
-            },
+            }
+            | {"ref_segments_resolved": decode["counters"].get("ref_segments_resolved", 0)},  # the sink's
             "wire_codecs": wire_codecs,
             "byte_identical": all(identical),
             "reference": reference,

@@ -1157,7 +1157,7 @@ class GatewaySenderOperator(GatewayOperator):
                 tenant_id=meta.get("tenant", DEFAULT_TENANT_ID),
             )
         data = fpath.read_bytes()
-        payload = self.processor.process(data, view if view is not None else self.dedup_index)
+        payload = self.processor.process(data, view if view is not None else self.dedup_index, trace_id=chunk.chunk_id)
         if view is not None:
             # later chunks in this window may REF these (in-order socket)
             view.pending.update(fp for fp, _ in payload.new_fingerprints)

@@ -72,6 +72,9 @@ DECODE_COUNTER_ZERO = {
     "decode_nacks": 0,
     "decode_queue_depth": 0,
     "decode_ns": 0,
+    "ref_resolve_ns": 0,
+    "ref_segments_resolved": 0,
+    "ref_bytes_resolved": 0,
     "store_mem_hits": 0,
     "store_spill_reads": 0,
     "store_promotions": 0,
@@ -331,6 +334,10 @@ class GatewayReceiver:
             "decode_wire_bytes": 0,
             "decode_busy": 0,
             "decode_ns": 0,
+            # parse_recipe's ref_stats, summed over the chunks decoded
+            "ref_resolve_ns": 0,
+            "ref_segments_resolved": 0,
+            "ref_bytes_resolved": 0,
         }
         self._decode_threads: List[threading.Thread] = []
         for i in range(decode_workers):
@@ -639,6 +646,7 @@ class GatewayReceiver:
             if tracer.enabled
             else NOOP_SPAN
         )
+        ref_stats: dict = {}
         t0 = time.perf_counter_ns()
         try:
           with span:
@@ -696,6 +704,7 @@ class GatewayReceiver:
                         store=self.segment_store,
                         ref_wait_timeout=self.ref_wait_timeout,
                         pooled=True,
+                        ref_stats=ref_stats,
                     )
                 except DedupIntegrityException as e:
                     # a REF pointed at a segment this receiver no longer
@@ -734,6 +743,8 @@ class GatewayReceiver:
                 self._decode_stats["decode_raw_bytes"] += header.raw_data_len
                 self._decode_stats["decode_wire_bytes"] += header.data_len
                 self._decode_stats["decode_ns"] += task.decode_ns
+                for k, v in ref_stats.items():
+                    self._decode_stats[k] += v
             self._decode_hist.observe(task.decode_ns / 1e9)
             if put_drop_oldest(
                 self.decode_profile_events,

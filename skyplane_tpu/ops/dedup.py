@@ -38,7 +38,7 @@ import numpy as np
 
 from skyplane_tpu.exceptions import CodecException, DedupIntegrityException
 from skyplane_tpu.faults import get_injector as _get_injector
-from skyplane_tpu.obs.tracer import get_tracer as _get_tracer
+from skyplane_tpu.obs.tracer import NOOP_SPAN, get_tracer as _get_tracer
 from skyplane_tpu.ops.bufpool import BufferPool, bucket_size
 from skyplane_tpu.ops.fingerprint import segment_fingerprint_host
 from skyplane_tpu.obs import lockwitness as lockcheck
@@ -781,6 +781,7 @@ def build_recipe(
     segments: List[Tuple[bytes, bytes]],  # [(fp16, seg_bytes), ...] in order
     index: SenderDedupIndex,
     encode_blob,
+    timings: Optional[dict] = None,
 ) -> Tuple[bytes, int, int, List[bytes], List[bytes]]:
     """Assemble a recipe for one chunk.
 
@@ -794,6 +795,10 @@ def build_recipe(
     receiver nacks an unresolvable REF, so the retry resends literals.
     Repeats *within* this chunk are still deduped (they travel in the same
     frame, so in-order resolution is guaranteed).
+
+    ``timings``, where given, receives ``recipe_encode_ns``: the literal join
+    and ``encode_blob``. What the call takes beyond that is index lookups and
+    recipe assembly.
     """
     entries = bytearray()
     lit_parts: List[bytes] = []
@@ -809,7 +814,10 @@ def build_recipe(
             lit_parts.append(seg)
             emitted_here.add(fp)
             new_fps.append((fp, len(seg)))
+    t0 = time.perf_counter_ns()
     lit_blob = encode_blob(b"".join(lit_parts))
+    if timings is not None:
+        timings["recipe_encode_ns"] = time.perf_counter_ns() - t0
     head = MAGIC + struct.pack("<BI", VERSION, len(segments))
     return head + bytes(entries) + lit_blob, len(ref_fps), sum(len(p) for p in lit_parts), new_fps, ref_fps
 
@@ -850,6 +858,8 @@ def parse_recipe(
     verify_literals: bool = False,
     out_pool: Optional[BufferPool] = None,
     expected_raw_len: Optional[int] = None,
+    ref_stats: Optional[dict] = None,
+    ref_span=NOOP_SPAN,
 ):
     """Receiver side: resolve a recipe back into raw chunk bytes.
 
@@ -858,16 +868,20 @@ def parse_recipe(
     work — a hostile entry list must not size an allocation, and the
     mismatch fails fast instead of after a full restore.
 
-    Every literal segment is inserted into ``store`` so later refs resolve.
+    Two passes over the entries. The first takes the literals: each is
+    inserted into ``store`` so later refs resolve, and placed in the output.
     With ``verify_literals``, each literal's fingerprint is recomputed before
     admission — a corrupted literal stored under a healthy fingerprint would
-    propagate to every future chunk that REFs it.
+    propagate to every future chunk that REFs it. The second resolves the
+    REFs (``store.get`` and the copy into the output), this chunk's own
+    repeats among them, under ``ref_span``; ``ref_stats``, where given,
+    receives what that pass did: ``ref_resolve_ns``, ``ref_segments_resolved``,
+    ``ref_bytes_resolved`` (nothing for a recipe with no REF).
 
     With ``out_pool``, segments are assembled directly into a pooled output
-    buffer (one copy per segment, no intermediate list + ``b"".join`` pass)
-    and a :class:`PooledChunk` is returned instead of ``bytes``; the caller
-    writes its ``view`` out and releases it. Without a pool the historical
-    ``bytes`` return is unchanged.
+    buffer (one copy per segment) and a :class:`PooledChunk` is returned
+    instead of ``bytes``; the caller writes its ``view`` out and releases it.
+    Without a pool the historical ``bytes`` return is unchanged.
     """
     head_len = 2 + struct.calcsize("<BI")
     if len(buf) < head_len or buf[:2] != MAGIC:
@@ -892,10 +906,13 @@ def parse_recipe(
     if expected_raw_len is not None and total != expected_raw_len:
         raise CodecException(f"recipe entries claim {total} raw bytes but the header declared {expected_raw_len}")
     lit_blob = decode_blob(buf[off:])
+    # the output: a pooled buffer (``arr``, released on every failing path) or a plain one;
+    # no second name for ``arr``: analysis/resources.py follows the pooled buffer by name
+    plain = np.empty(total, np.uint8) if out_pool is None or total == 0 else None
     arr: Optional[np.ndarray] = None
-    if out_pool is not None and total > 0:
+    if plain is None:
         arr = out_pool.acquire(bucket_size(total))
-    out: List[bytes] = []
+    refs = []  # (offset in the output, fp, seg_len)
     out_off = 0
     lit_off = 0
     try:
@@ -909,23 +926,30 @@ def parse_recipe(
                     if segment_fingerprint_host(seg) != fp:
                         raise DedupIntegrityException(f"literal segment fingerprint mismatch (claimed {fp.hex()})")
                 store.put(fp, seg)
+                (plain if arr is None else arr)[out_off : out_off + seg_len] = np.frombuffer(seg, np.uint8)
             elif kind == KIND_REF:
-                seg = store.get(fp, wait_timeout=ref_wait_timeout)
-                if len(seg) != seg_len:
-                    raise DedupIntegrityException(f"dedup ref {fp.hex()} length mismatch")
+                refs.append((out_off, fp, seg_len))
             else:
                 raise CodecException(f"bad recipe entry kind {kind}")
-            if arr is not None:
-                arr[out_off : out_off + seg_len] = np.frombuffer(seg, np.uint8)
-                out_off += seg_len
-            else:
-                out.append(seg)
+            out_off += seg_len
         if lit_off != len(lit_blob):
             raise DedupIntegrityException("literal blob longer than recipe entries")
+        if refs:
+            t0 = time.perf_counter_ns()
+            with ref_span:
+                for at, fp, seg_len in refs:
+                    seg = store.get(fp, wait_timeout=ref_wait_timeout)
+                    if len(seg) != seg_len:
+                        raise DedupIntegrityException(f"dedup ref {fp.hex()} length mismatch")
+                    (plain if arr is None else arr)[at : at + seg_len] = np.frombuffer(seg, np.uint8)
+            if ref_stats is not None:
+                ref_stats["ref_resolve_ns"] = time.perf_counter_ns() - t0
+                ref_stats["ref_segments_resolved"] = len(refs)
+                ref_stats["ref_bytes_resolved"] = sum(seg_len for _, _, seg_len in refs)
     except BaseException:
         if arr is not None:
             out_pool.release(arr)  # a failed decode must not leak the buffer
         raise
     if arr is not None:
         return PooledChunk(arr, out_pool, total)
-    return b"".join(out)
+    return plain.tobytes()

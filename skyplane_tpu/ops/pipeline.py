@@ -29,6 +29,7 @@ import numpy as np
 
 from skyplane_tpu.chunk import Codec, WireProtocolHeader
 from skyplane_tpu.exceptions import ChecksumMismatchException, CodecException
+from skyplane_tpu.obs import get_tracer
 from skyplane_tpu.ops import blockpack
 from skyplane_tpu.ops.bufpool import MIN_BUCKET, BufferPool, bucket_size
 from skyplane_tpu.ops.cdc import CDCParams, cdc_segment_ends
@@ -130,9 +131,11 @@ class DataPathStats:
         "wire_bytes",
         "segments",
         "ref_segments",
+        "literal_bytes",
         "device_wait_ns",
         "device_path_ns",
         "recipe_ns",
+        "recipe_encode_ns",
         "seal_ns",
     )
     EXTERNAL_ZERO = {
@@ -172,13 +175,18 @@ class DataPathStats:
             self._tls.counters = d
         return d
 
-    def observe(self, p: ProcessedPayload, device_path_ns: int = 0, recipe_ns: int = 0) -> None:
+    def observe(
+        self, p: ProcessedPayload, device_path_ns: int = 0, recipe_ns: int = 0, recipe_encode_ns: int = 0
+    ) -> None:
         """One chunk done. ``device_path_ns``: wall time its worker spent on
         CDC + fingerprints, from submission to finalized digests — the pad
         copy and staging, the window wait, a leader's whole batch, a
         follower's waits, ``finalize_row`` (on a gateway with no accelerator,
         the host kernels). ``recipe_ns``: ``build_recipe`` (dedup-index
-        lookups, literal join, codec). Added together with ``chunks``, their
+        lookups, literal join, codec); ``recipe_encode_ns`` is the join and
+        the codec inside it. ``literal_bytes``: raw bytes of the segments
+        that went as literals, so what dedup left (0 with dedup off: no
+        recipe, no literal). Added together with ``chunks``, their
         denominator, so a scrape between chunks sees whole chunks."""
         d = self._shard()
         d["chunks"] += 1
@@ -186,8 +194,10 @@ class DataPathStats:
         d["wire_bytes"] += len(p.wire_bytes)
         d["segments"] += p.n_segments
         d["ref_segments"] += p.n_ref_segments
+        d["literal_bytes"] += p.literal_bytes
         d["device_path_ns"] += device_path_ns
         d["recipe_ns"] += recipe_ns
+        d["recipe_encode_ns"] += recipe_encode_ns
 
     def observe_device_wait(self, ns: int) -> None:
         """Time this worker spent BLOCKED on the device (phase waits in the
@@ -398,9 +408,13 @@ class DataPathProcessor:
 
     # ---- encode ----
 
-    def process(self, data: bytes, index: Optional[SenderDedupIndex] = None) -> ProcessedPayload:
+    def process(
+        self, data: bytes, index: Optional[SenderDedupIndex] = None, trace_id: Optional[str] = None
+    ) -> ProcessedPayload:
+        """``trace_id`` (the chunk id) keys the sampling of the ``recipe.build``
+        span, as it does for the framer's ``wire.frame`` around this call."""
         raw_len = len(data)
-        device_path_ns = recipe_ns = 0
+        device_path_ns = recipe_ns = recipe_encode_ns = 0
         if self.dedup and index is not None and raw_len > 0:
             arr = np.frombuffer(data, np.uint8)
             t = time.perf_counter_ns()
@@ -423,9 +437,12 @@ class DataPathProcessor:
             device_path_ns += time.perf_counter_ns() - t
             self.stats.observe_device_wait(phased.wait_ns)
             segments = list(zip(seg_fps, spans))
+            timings: dict = {}
             t = time.perf_counter_ns()
-            wire, n_ref, lit_bytes, new_fps, ref_fps = build_recipe(segments, index, self.codec.encode)
+            with get_tracer().span("recipe.build", trace_id=trace_id, cat="sender"):
+                wire, n_ref, lit_bytes, new_fps, ref_fps = build_recipe(segments, index, self.codec.encode, timings)
             recipe_ns = time.perf_counter_ns() - t
+            recipe_encode_ns = timings["recipe_encode_ns"]
             payload = ProcessedPayload(
                 wire_bytes=wire,
                 codec=self.codec.codec_id,
@@ -455,7 +472,7 @@ class DataPathProcessor:
                 raw_len=raw_len,
                 fingerprint=fp,
             )
-        self.stats.observe(payload, device_path_ns, recipe_ns)
+        self.stats.observe(payload, device_path_ns, recipe_ns, recipe_encode_ns)
         return payload
 
     # ---- decode ----
@@ -471,13 +488,15 @@ class DataPathProcessor:
         store: Optional[SegmentStore] = None,
         ref_wait_timeout: float = 60.0,
         pooled: bool = False,
+        ref_stats: Optional[dict] = None,
     ):
         """Wire payload -> raw chunk bytes, driven by the wire header.
 
         With ``pooled`` (the gateway receiver's decode pool), recipe payloads
         assemble into a pooled buffer and a :class:`PooledChunk` is returned —
         the caller writes ``.view`` out and calls ``.release()``. Non-recipe
-        payloads (and ``pooled=False``) return plain ``bytes``.
+        payloads (and ``pooled=False``) return plain ``bytes``. ``ref_stats``
+        is ``parse_recipe``'s: what resolving this chunk's REFs took.
         """
         codec = get_codec_by_id(header.codec)
         if header.is_recipe:
@@ -491,6 +510,10 @@ class DataPathProcessor:
                 verify_literals=self.verify_checksums,
                 out_pool=self.bufpool if pooled else None,
                 expected_raw_len=header.raw_data_len,
+                ref_stats=ref_stats,
+                ref_span=get_tracer().span(
+                    "decode.ref_resolve", trace_id=header.chunk_id, cat="receiver", force=header.is_traced
+                ),
             )
         else:
             data = codec.decode(payload)

@@ -346,6 +346,56 @@ def test_seal_time_counted_only_with_a_cipher(with_cipher, tmp_path):
     assert (seal_ns > 0) if with_cipher else (seal_ns == 0)
 
 
+# ---- (g2) the REF path's two spans
+
+
+def _send_twice_and_restore(trace_id: str):
+    """One chunk sent twice (the second time all REFs) and restored; returns
+    the second payload and what resolving its REFs counted."""
+    from skyplane_tpu.chunk import ChunkFlags, WireProtocolHeader
+    from skyplane_tpu.ops.dedup import SegmentStore, SenderDedupIndex
+
+    data = _chunk(200_000).tobytes()
+    proc = DataPathProcessor(codec_name="none", dedup=True, cdc_params=PARAMS)
+    index, store = SenderDedupIndex(), SegmentStore()
+    ref_stats: dict = {}
+    for n in range(2):
+        p = proc.process(data, index, trace_id=trace_id)
+        for fp, size in p.new_fingerprints:
+            index.add(fp, size)
+        header = WireProtocolHeader(
+            chunk_id=trace_id, data_len=len(p.wire_bytes), raw_data_len=p.raw_len, codec=int(p.codec),
+            flags=int(ChunkFlags.RECIPE), fingerprint=p.fingerprint,
+        )
+        ref_stats = {}
+        assert proc.restore(p.wire_bytes, header, store=store, ref_stats=ref_stats) == data
+    return p, ref_stats
+
+
+@pytest.mark.parametrize("span_name, cat", [("recipe.build", "sender"), ("decode.ref_resolve", "receiver")])
+def test_ref_path_spans_record_when_enabled_and_carry_the_chunk_id(span_name, cat):
+    tracer = configure_tracer(sample=1.0)
+    p, ref_stats = _send_twice_and_restore("cd" * 16)
+    assert p.n_ref_segments == p.n_segments and p.literal_bytes == 0
+    assert ref_stats["ref_segments_resolved"] == p.n_segments and ref_stats["ref_bytes_resolved"] == p.raw_len
+    events = [e for e in tracer.export()["traceEvents"] if e.get("name") == span_name]
+    # recipe.build: once per process(); decode.ref_resolve: only the recipe that holds REFs
+    assert len(events) == (2 if span_name == "recipe.build" else 1)
+    assert all(e["cat"] == cat and e["args"]["chunk_id"] == "cd" * 16 for e in events)
+    if span_name == "decode.ref_resolve":
+        assert events[0]["dur"] * 1e3 <= ref_stats["ref_resolve_ns"]
+
+
+@pytest.mark.parametrize("span_name", ["recipe.build", "decode.ref_resolve"])
+def test_disabled_tracer_gives_the_ref_path_sites_the_noop_span(span_name):
+    tracer = configure_tracer(sample=0.0)
+    calls = _spy_on_span(tracer)
+    _send_twice_and_restore("ef" * 16)
+    got = [span for name, _cat, span, _tid in calls if name == span_name]
+    assert len(got) == 2 and all(span is NOOP_SPAN for span in got)
+    assert tracer.counters()["spans_recorded"] == 0
+
+
 # ---- (h) the schema is stable, and served
 
 
@@ -367,6 +417,48 @@ def test_loopback_pair_serves_the_new_keys_as_numbers(tmp_path):
         dst.stop()
     for key in NEW_KEYS:
         assert isinstance(served.get(key), (int, float)) and not isinstance(served[key], bool), (key, served.get(key))
+
+
+#: the REF path's counters (PR 27): where each is served
+REF_PATH_KEYS = (
+    ("profile/compression", "literal_bytes"),
+    ("profile/compression", "recipe_encode_ns"),
+    ("profile/decode", "ref_resolve_ns"),
+    ("profile/decode", "ref_segments_resolved"),
+    ("profile/decode", "ref_bytes_resolved"),
+)
+
+
+@pytest.fixture(scope="module")
+def served_with_dedup_off(tmp_path_factory):
+    """Both endpoints of a loopback pair that moved one chunk with dedup off:
+    no recipe was built and none was parsed."""
+    pytest.importorskip("zstandard")
+    from tests.integration.harness import dispatch_file, make_pair, wait_complete
+
+    tmp = tmp_path_factory.mktemp("dedup_off")
+    (tmp / "in.bin").write_bytes(_chunk(200_000).tobytes())
+    # a codec, so that the chunk goes through the processor and not the raw passthrough
+    src, dst = make_pair(tmp, compress="zstd", dedup=False, encrypt=False, use_tls=False, num_connections=1)
+    try:
+        wait_complete(dst, dispatch_file(src, tmp / "in.bin", tmp / "out.bin"))
+        compression = src.get("profile/compression", timeout=10).json()
+        decode = dst.get("profile/decode", timeout=10).json()["counters"]
+    finally:
+        src.stop()
+        dst.stop()
+    assert compression["chunks"] == 1 and decode["decode_chunks"] == 1
+    return {"profile/compression": compression, "profile/decode": decode}
+
+
+@pytest.mark.parametrize("route, key", REF_PATH_KEYS)
+def test_ref_path_keys_are_served_zero_filled_with_dedup_off(served_with_dedup_off, route, key):
+    from skyplane_tpu.gateway.operators.gateway_receiver import DECODE_COUNTER_ZERO
+
+    value = served_with_dedup_off[route].get(key)
+    assert value == 0 and isinstance(value, (int, float)) and not isinstance(value, bool), (route, key, value)
+    schema = DataPathStats._KEYS if route == "profile/compression" else DECODE_COUNTER_ZERO
+    assert key in schema
 
 
 # ---- (i) pump workers' snapshots sum
