@@ -48,27 +48,6 @@ def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
 
 
-def maybe_enable_pallas() -> dict:
-    """Compile each Pallas kernel on this backend and compare it with the
-    XLA path (ops/pallas_kernels.py ``validate_on_device``); a kernel that
-    compiled and matched bit for bit is enabled for the run. The verdicts,
-    with the compiler's message for a refusal, go into the result line.
-
-    Per-kernel: the gear and fingerprint kernels lower independently through
-    Mosaic, so one failing does not disable the other."""
-    from skyplane_tpu.ops.pallas_kernels import validate_on_device
-
-    verdicts = validate_on_device()
-    opted_out = os.environ.get("SKYPLANE_TPU_USE_PALLAS", "").strip().lower() in ("0", "false", "off")
-    # set BOTH per-kernel flags explicitly: a pre-exported master =1 must not
-    # silently run an unvalidated kernel while the result reports it off
-    for k, v in verdicts.items():
-        v["enabled"] = bool(v["compiled"] and v["identical"] and not opted_out)
-        os.environ[f"SKYPLANE_TPU_USE_PALLAS_{k.upper()}"] = "1" if v["enabled"] else "0"
-    log(f"pallas kernels on this backend: {verdicts}")
-    return verdicts
-
-
 WRITE_SITE_FRAC = 0.004  # clustered write sites between snapshots
 WRITE_RUN_BLOCKS = 8  # mean blocks touched per write site
 
@@ -1185,7 +1164,6 @@ def main() -> None:
     devices = jax.devices()
     dev_platform = devices[0].platform
     log(f"benchmarking on platform={dev_platform} device_kind={devices[0].device_kind} n_devices={len(devices)}")
-    pallas_on = maybe_enable_pallas()
 
     chunks = make_corpus()
     log("corpus ready")
@@ -1308,7 +1286,6 @@ def main() -> None:
         "mesh": _main_mesh_label(),
         "workers": deploy_workers,
         "gbps_by_workers": by_workers,
-        "pallas": pallas_on,  # per kernel: {"compiled", "identical", "error", "enabled"}
         "wire_reduction_ours": round(ours["raw_bytes"] / max(ours["wire_bytes"], 1), 2),
         "wire_reduction_baseline": round(base["raw_bytes"] / max(base["wire_bytes"], 1), 2),
         # egress $/TB of raw data actually moved (BASELINE metric's second

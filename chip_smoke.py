@@ -180,7 +180,6 @@ def main(argv=None) -> int:
     from skyplane_tpu.chunk import Codec
     from skyplane_tpu.native import datapath as native_dp
     from skyplane_tpu.ops.cdc import cdc_and_fps_host
-    from skyplane_tpu.ops.pallas_kernels import validate_on_device
     from tests.integration.harness import dispatch_file, make_pair, wait_complete
 
     phases = {}
@@ -302,11 +301,6 @@ def main(argv=None) -> int:
         check(native_ok, "native.datapath.available() is false: the numpy paths served")
         check(native_ok and (info["built"] or info["stamp"] == native.build_stamp()), "libskydp was neither built in this run nor matches this host's build stamp")
 
-        # ---- Pallas: facts, not gates (the default path is XLA)
-        t = time.monotonic()
-        pallas = validate_on_device()
-        phases["pallas_s"] = round(time.monotonic() - t, 2)
-
         mem = devices[0].memory_stats() or {}
         check(full_size, f"reduced below the contract's size ({chunk_mb} MiB chunks, {n_chunks} chunks): a rehearsal never passes")
         check(platform == "tpu", f"platform is {platform!r}, not 'tpu'")
@@ -361,7 +355,6 @@ def main(argv=None) -> int:
             "hbm": {"peak_bytes_in_use": mem.get("peak_bytes_in_use"), "bytes_limit": mem.get("bytes_limit")},
             "compile_cache": {"dir": cache_dir, "empty_at_start": cache_empty_at_start, "hits": cache_hits[0]},
             "compiles": compiles,
-            "pallas": pallas,
             "phases": phases,
             "failed": failed,
         }

@@ -1,9 +1,9 @@
 """skyplane_tpu: a TPU-native cloud bulk-data-transfer framework.
 
 Capability parity with skyplane-project/skyplane (reference survey in
-SURVEY.md), re-architected so the gateway data path — content-defined
-chunking, dedup fingerprinting, compression, and integrity checksums — runs
-as JAX/Pallas kernels over HBM-resident chunk batches.
+SURVEY.md), re-architected so the gateway data path's content-defined
+chunking and dedup fingerprinting run as JAX programs over HBM-resident
+chunk batches, beside host kernels for compression and integrity checksums.
 
 Public surface (reference: skyplane/__init__.py:1-28): ``SkyplaneClient``,
 ``Pipeline``, ``Dataplane``, ``TransferHook``, plus config dataclasses.

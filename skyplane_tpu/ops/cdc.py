@@ -119,18 +119,3 @@ def cdc_and_fps_host(arr: np.ndarray, params: CDCParams = CDCParams()) -> Tuple[
     from skyplane_tpu.ops.fingerprint import segment_fingerprints_host_batch
 
     return ends, segment_fingerprints_host_batch(arr, ends)
-
-
-def segment_ids_and_rev_pos(ends: np.ndarray, n: int) -> Tuple[np.ndarray, np.ndarray]:
-    """Per-byte (segment_id, reversed-position-in-segment) vectors for the
-    fingerprint kernel, computed vectorized on host."""
-    ends = np.asarray(ends, dtype=np.int64)
-    seg_ids = np.zeros(n, dtype=np.int32)
-    if len(ends) > 1:
-        seg_ids[ends[:-1]] = 1
-        seg_ids = np.cumsum(seg_ids, dtype=np.int32)
-    starts = np.concatenate([[0], ends[:-1]])
-    pos = np.arange(n, dtype=np.int32) - starts[seg_ids].astype(np.int32)
-    seg_len = (ends - starts).astype(np.int32)
-    rev_pos = seg_len[seg_ids] - 1 - pos
-    return seg_ids, rev_pos

@@ -12,13 +12,11 @@ segment — negligible).
 
 Everything device-side is parallel: per-byte terms are ``mulmod31`` products
 with a precomputed power and per-segment sums are limb-split (4 x 8-bit
-limbs so uint32 accumulators cannot overflow for segments up to 2^24 bytes).
-Two formulations, bit-identical: ``segment_fingerprint_device`` indexes the
-power table by each byte's reversed position within its segment and sums with
-``segment_sum`` (fixed-stride segments, ``fixed_stride_lanes``);
-``segment_fingerprint_cumsum`` (content-defined segments, call B of
-ops/fused_cdc.py) takes the power from the byte's position in the row alone
-and the sums from prefix-sum differences, so nothing is indexed per byte.
+limbs so uint32 accumulators cannot overflow). One device formulation:
+``segment_fingerprint_cumsum`` (call B of ops/fused_cdc.py) takes the power
+from the byte's position in the row alone and the sums from prefix-sum
+differences, so nothing is indexed per byte. The host forms below (native
+Horner kernel, numpy, python ints) are what it is held bit-identical to.
 """
 
 from __future__ import annotations
@@ -54,41 +52,6 @@ def _power_tables() -> np.ndarray:
 
 
 @partial(jax.jit, static_argnames=("n_segments",))
-def segment_fingerprint_device(data: jax.Array, seg_ids: jax.Array, rev_pos: jax.Array, n_segments: int):
-    """Per-segment 8-lane polynomial hash.
-
-    Args:
-      data:     [N] uint8 chunk bytes (padding bytes must carry seg_id == n_segments-1
-                slot reserved for garbage, or rev_pos 0 with byte 0).
-      seg_ids:  [N] int32 segment id per byte (0..n_segments-1).
-      rev_pos:  [N] int32 reversed position within segment (L-1-i), < MAX_SEGMENT_BYTES.
-      n_segments: static segment-slot count (pad segments are all-zero slots).
-
-    Returns [n_segments, N_LANES] uint32 lane values in canonical [0, M31).
-    """
-    tables = jnp.asarray(_power_tables())  # [LANES, MAX] uint32
-    b = data.astype(jnp.uint32)
-
-    # unrolled per-lane loop (NOT vmap over lanes): keeps every large
-    # intermediate 1-D [N], which TPU layouts tile without padding. A lane
-    # vmap tempts XLA into [N, LANES] intermediates whose minor dim pads
-    # 8 -> 128 — a 16x HBM inflation that OOMs real chips on big batches.
-    lanes = []
-    for li in range(N_LANES):
-        powers = tables[li][rev_pos]  # [N] uint32
-        terms = mulmod31(b, powers)  # [N] < 2^31
-        # limb-split segment sums: 4 x 8-bit limbs, uint32 accumulators
-        acc = jnp.zeros((n_segments,), jnp.uint32)
-        for k in range(4):
-            limb = (terms >> np.uint32(8 * k)) & np.uint32(0xFF)
-            s = jax.ops.segment_sum(limb, seg_ids, num_segments=n_segments)  # < 2^24 * 2^8 = 2^32
-            # s * 2^(8k) mod M31  (s < 2^32 -> fold first, then mulmod)
-            acc = addmod31(acc, mulmod31(fold31(s), jnp.uint32((1 << (8 * k)) % M31)))
-        lanes.append(acc)
-    return jnp.stack(lanes, axis=-1)  # [n_segments, LANES]
-
-
-@partial(jax.jit, static_argnames=("n_segments",))
 def segment_fingerprint_cumsum(data: jax.Array, seg_starts: jax.Array, seg_ends: jax.Array, n_segments: int):
     """Per-segment 8-lane polynomial hash for CONTIGUOUS segments, with no
     per-byte index: no scatter, and no gather over the row.
@@ -105,8 +68,7 @@ def segment_fingerprint_cumsum(data: jax.Array, seg_starts: jax.Array, seg_ends:
     most two, cut at the block edge — and each piece is scaled by r^(e-1-kT),
     exponent in [0, 2T), looked up per slot: a few n_segments-sized look-ups
     per lane and limb instead of one per byte. Exact in GF(2^31 - 1), so
-    bit-identical to ``segment_fingerprint_device`` and to the host kernels
-    (tested).
+    bit-identical to the host kernels (tested).
 
     Args:
       data:       [N] uint8 chunk bytes, N a multiple of T (every bucket is a
@@ -171,36 +133,6 @@ def segment_fingerprint_cumsum(data: jax.Array, seg_starts: jax.Array, seg_ends:
         piece2 = recombine(before[:, :, 2])
         lanes = addmod31(mulmod31(piece1, fwd[:, exp1]), mulmod31(piece2, fwd[:, exp2]))
     return lanes.T  # [n_segments, LANES]
-
-
-def fixed_stride_lanes(chunk, fp_seg_bytes: int, pallas=None):
-    """[N] uint8 -> [N/fp_seg_bytes, LANES] uint32 for FIXED-stride segments,
-    dispatching to the Pallas kernel when enabled (shared by datapath_step
-    and the SPMD datapath so the dispatch cannot drift between them).
-
-    ``pallas=None`` resolves the env flag + backend at trace time; callers
-    that jit should resolve it OUTSIDE the trace and pass the bool through a
-    static argument, or the flag gets frozen into the compiled program.
-    """
-    n = chunk.shape[0]
-    n_segments = n // fp_seg_bytes
-    if pallas is None:
-        from skyplane_tpu.ops.backend import on_accelerator
-        from skyplane_tpu.ops.pallas_kernels import use_pallas
-
-        pallas = use_pallas("fp") and on_accelerator()
-    if pallas:
-        from skyplane_tpu.ops.pallas_kernels import FP_MAX_TILE, FP_SUB_TILE, segment_fp_fixed_pallas
-
-        if fp_seg_bytes <= FP_MAX_TILE and (fp_seg_bytes <= FP_SUB_TILE or fp_seg_bytes % FP_SUB_TILE == 0):
-            # one VMEM pass per segment instead of per-lane HBM term arrays;
-            # sizes outside the kernel's column-tiled domain fall through to
-            # the XLA path below instead of erroring (graceful degradation)
-            return segment_fp_fixed_pallas(chunk, fp_seg_bytes)
-    pos = jax.lax.iota(jnp.int32, n)
-    seg_ids = pos // fp_seg_bytes
-    rev_pos = fp_seg_bytes - 1 - (pos % fp_seg_bytes)
-    return segment_fingerprint_device(chunk, seg_ids, rev_pos, n_segments=n_segments)
 
 
 def finalize_fingerprint(lanes: np.ndarray, length: int) -> str:

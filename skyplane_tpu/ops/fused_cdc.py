@@ -120,8 +120,8 @@ def _first_candidates(valid: jax.Array, cap: int) -> jax.Array:
     return jnp.concatenate([cand, n_cand[None]])
 
 
-@partial(jax.jit, static_argnames=("mask_bits", "cap", "_pallas"))
-def _candidates_impl(batch: jax.Array, lens: jax.Array, *, mask_bits: int, cap: int, _pallas: bool):
+@partial(jax.jit, static_argnames=("mask_bits", "cap"))
+def _candidates_impl(batch: jax.Array, lens: jax.Array, *, mask_bits: int, cap: int):
     """[B, bucket] u8 -> [B, cap+1] i32: first-`cap` candidate positions
     (ascending, sentinel-padded) and the true candidate count."""
     bucket = batch.shape[-1]
@@ -129,7 +129,7 @@ def _candidates_impl(batch: jax.Array, lens: jax.Array, *, mask_bits: int, cap: 
     def one(chunk, n):  # the named scopes are the stages' stable names in a device trace
         iota = jax.lax.iota(jnp.int32, bucket)
         with jax.named_scope("cdc.gear_hash"):
-            h = gear_hash(chunk, pallas=_pallas)
+            h = gear_hash(chunk)
         with jax.named_scope("cdc.candidate_mask"):
             valid = boundary_candidate_mask(h, mask_bits) & (iota < n)
         with jax.named_scope("cdc.compaction"):
@@ -289,37 +289,22 @@ class FusedCDCFP:
     def __init__(
         self,
         params: CDCParams,
-        pallas: Optional[bool] = None,
         mesh=None,
         shard_axes=None,
         pool=None,
         donate: Optional[bool] = None,
     ):
         self.params = params
-        if pallas is None:
-            from skyplane_tpu.ops.backend import on_accelerator
-            from skyplane_tpu.ops.pallas_kernels import use_pallas
-
-            pallas = bool(use_pallas("gear") and on_accelerator())
-        self.pallas = bool(pallas)
         self.mesh = mesh
         self.shard_axes = tuple(shard_axes) if shard_axes else (tuple(mesh.shape.keys()) if mesh is not None else None)
         self.pool = pool  # optional BufferPool for per-batch scratch reuse
         if donate is None:
-            import os
+            # donation reuses HBM on accelerators; XLA-CPU cannot alias the
+            # batch into the smaller fp output and would warn 'donated
+            # buffers were not usable' on every compile
+            from skyplane_tpu.ops.backend import on_accelerator
 
-            env = os.environ.get("SKYPLANE_TPU_DONATE", "auto").strip().lower()
-            if env in ("0", "false", "off"):
-                donate = False
-            elif env in ("1", "true", "on"):
-                donate = True
-            else:
-                # auto: donation reuses HBM on accelerators; XLA-CPU cannot
-                # alias the batch into the smaller fp output and would warn
-                # 'donated buffers were not usable' on every compile
-                from skyplane_tpu.ops.backend import on_accelerator
-
-                donate = on_accelerator()
+            donate = on_accelerator()
         self.donate = bool(donate)
         self._shards = int(np.prod([mesh.shape[a] for a in self.shard_axes])) if mesh is not None else 1
         self._sharded = {}  # bucket -> (candidates_fn, fp_fn)
@@ -331,14 +316,13 @@ class FusedCDCFP:
         cap = candidate_cap(bucket, self.params)
         n_slots = slots_cap(bucket, self.params)
         if self.mesh is None:
-            cand_fn = partial(_candidates_impl, mask_bits=self.params.mask_bits, cap=cap, _pallas=self.pallas)
+            cand_fn = partial(_candidates_impl, mask_bits=self.params.mask_bits, cap=cap)
             fp_fn = partial(_fp_impl, n_slots=n_slots)
             return cand_fn, fp_fn
         fns = self._sharded.get(bucket)
         if fns is None:
-            fns = self._sharded[bucket] = make_sharded_kernels(
-                self.mesh, self.params, bucket, pallas=self.pallas, shard_axes=self.shard_axes
-            )
+            fns = make_sharded_kernels(self.mesh, self.params, bucket, shard_axes=self.shard_axes)
+            self._sharded[bucket] = fns
         return fns
 
     def rows_per_dispatch(self, bucket: int) -> int:
@@ -476,7 +460,7 @@ class FusedCDCFP:
         return [pending.result_row(i) for i in range(pending.b)]
 
 
-def make_sharded_kernels(mesh, params: CDCParams, bucket: int, pallas: bool = False, shard_axes=None):
+def make_sharded_kernels(mesh, params: CDCParams, bucket: int, shard_axes=None):
     """The two batched kernels sharded chunk-parallel over ``shard_axes`` of
     the mesh (default: all axes, flattened): boundary selection is
     sequential per chunk, so the batch dimension is the parallel axis —
@@ -491,7 +475,7 @@ def make_sharded_kernels(mesh, params: CDCParams, bucket: int, pallas: bool = Fa
     axes = tuple(shard_axes) if shard_axes else tuple(mesh.shape.keys())
     cand = jax.jit(
         jax.shard_map(
-            lambda b, l: _candidates_impl(b, l, mask_bits=params.mask_bits, cap=cap, _pallas=pallas),
+            lambda b, l: _candidates_impl(b, l, mask_bits=params.mask_bits, cap=cap),
             mesh=mesh,
             in_specs=(P(axes, None), P(axes)),
             out_specs=P(axes, None),

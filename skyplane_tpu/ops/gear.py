@@ -78,32 +78,15 @@ def gear_values(data_u8: jax.Array) -> jax.Array:
     return jax.lax.optimization_barrier(level[0]).reshape(n)
 
 
-def gear_hash(data_u8: jax.Array, pallas: bool | None = None) -> jax.Array:
+def gear_hash(data_u8: jax.Array) -> jax.Array:
     """[N] uint8 -> [N] uint32 rolling gear hash, parallel windowed-sum form.
 
     Matches the sequential recurrence h_t = (h_{t-1} << 1) + G[b_t] for all t
     (the zero-filled prefix reproduces the h_0 = 0 start). Evaluated by
     log-doubling: with S_k(t) = sum_{i<2^k} g_{t-i} << i,
     S_{k+1}(t) = S_k(t) + (S_k(t - 2^k) << 2^k) — 5 shifted adds instead of 31.
-
-    ``pallas=None`` resolves the env flag + backend at trace time; callers
-    that jit (fused_cdc) resolve it outside the trace and pass the bool.
     """
-    g = gear_values(data_u8)  # [N] uint32
-    # opt-in Pallas path: one HBM read/write instead of one per doubling pass
-    # (SKYPLANE_TPU_USE_PALLAS=1; requires TILE-aligned inputs — the data path
-    # pads chunks to power-of-two buckets so this holds there)
-    from skyplane_tpu.ops.pallas_kernels import TILE, gear_windowed_sum_pallas, use_pallas
-
-    if pallas is None:
-        # the env flag can leak into CPU-pinned daemon subprocesses;
-        # pallas_call only lowers on real accelerators, so gate on backend
-        from skyplane_tpu.ops.backend import on_accelerator
-
-        pallas = use_pallas("gear") and on_accelerator()
-    if pallas and g.shape[0] % TILE == 0:
-        return gear_windowed_sum_pallas(g)
-    return _windowed_sum_doubling(g)
+    return _windowed_sum_doubling(gear_values(data_u8))
 
 
 def _windowed_sum_doubling(g: jax.Array) -> jax.Array:

@@ -1,8 +1,9 @@
-"""Vectorized numpy fallbacks for the data-path kernels.
+"""Vectorized numpy forms of the host data-path kernels.
 
-Gateways without an accelerator (or whose jax backend is CPU) run these —
-bit-identical to the device kernels (tested), avoiding XLA-on-CPU dispatch
-overhead. Selection happens in DataPathProcessor via ``_on_accelerator``.
+What a gateway runs where the native library (native/datapath.cpp) is not
+built, and the reference the native and device kernels are held to: gear
+hash and boundary candidates bit-identical to ops/gear.py, blockpack
+bit-identical to the native single pass (tested).
 """
 
 from __future__ import annotations
@@ -32,10 +33,9 @@ def boundary_candidates_host(h: np.ndarray, mask_bits: int) -> np.ndarray:
 
 
 def blockpack_encode_host(data: np.ndarray, block_bytes: int) -> Tuple[np.ndarray, np.ndarray, int]:
-    """Same contract as blockpack.encode_device, in numpy.
-
-    Returns (tags [NB] uint8, literals [n_lit] uint8 dense, n_lit).
-    """
+    """[N] uint8 (N divisible by block_bytes) -> (tags [NB] uint8, literals
+    [n_lit] uint8 dense in stream order, n_lit): ops/blockpack.py's block
+    classification and literal compaction."""
     from skyplane_tpu.ops.blockpack import TAG_CONST, TAG_LITERAL, TAG_ZERO
 
     n = len(data)
@@ -74,7 +74,7 @@ def blockpack_decode_host(tags: np.ndarray, literals: np.ndarray, block_bytes: i
     lens = np.where(tags == TAG_LITERAL, block_bytes, np.where(tags == TAG_CONST, 1, 0))
     if int(lens.sum()) > len(literals):
         # corrupted container: tags demand more literal bytes than shipped
-        # (device path clamps the gather; keep the error inside the codec contract)
+        # (keep the error inside the codec contract)
         raise CodecException("blockpack container corrupt: tag/literal length mismatch")
     offsets = np.cumsum(lens) - lens
     out = np.zeros(nb * block_bytes, np.uint8)

@@ -1,18 +1,11 @@
-"""The fused TPU data-path: one jittable step + the host-side processor.
-
-``datapath_step`` is the flagship device function (what ``__graft_entry__``
-exposes): for a batch of equal-length chunks it computes, in one compiled
-program —
-
-  * Gear rolling hashes + CDC boundary-candidate mask   (ops/gear.py)
-  * blockpack tags + compacted literals                 (ops/blockpack.py)
-  * fixed-stride 8-lane segment fingerprints            (ops/fingerprint.py)
+"""The host side of the data path: ``DataPathProcessor``.
 
 ``DataPathProcessor`` is the host orchestration the gateway operators call
-per chunk: content-defined chunking (device hash, host select), dedup recipe
-assembly, codec encode/decode, and end-to-end fingerprints. Input sizes are
-padded to power-of-two buckets so XLA compiles a handful of shapes, not one
-per chunk.
+per chunk: content-defined chunking and segment fingerprints (the two device
+programs of ops/fused_cdc.py through the shared ``DeviceBatchRunner`` on an
+accelerator, the host kernels otherwise), dedup recipe assembly, codec
+encode/decode, and end-to-end fingerprints. Input sizes are padded to
+power-of-two buckets so XLA compiles a handful of shapes, not one per chunk.
 """
 
 from __future__ import annotations
@@ -21,72 +14,21 @@ import hashlib
 import threading
 import time
 from dataclasses import dataclass, field
-from functools import partial
 from typing import Callable, List, Optional
 
-import jax
 import numpy as np
 
 from skyplane_tpu.chunk import Codec, WireProtocolHeader
 from skyplane_tpu.exceptions import ChecksumMismatchException, CodecException
 from skyplane_tpu.obs import get_tracer
-from skyplane_tpu.ops import blockpack
 from skyplane_tpu.ops.bufpool import MIN_BUCKET, BufferPool, bucket_size
 from skyplane_tpu.ops.cdc import CDCParams, cdc_segment_ends
 from skyplane_tpu.ops.codecs import CodecSpec, get_codec, get_codec_by_id
 from skyplane_tpu.ops.dedup import PooledChunk, SegmentStore, SenderDedupIndex, build_recipe, parse_recipe
-from skyplane_tpu.ops.fingerprint import fixed_stride_lanes
-from skyplane_tpu.ops.gear import boundary_candidate_mask, gear_hash
 
 # canonical home is ops/bufpool.py (the pool keys on it); kept under the old
 # name here because this is where every data-path caller historically looked
 _bucket_size = bucket_size
-
-
-@partial(jax.jit, static_argnames=("block_bytes", "fp_seg_bytes", "mask_bits", "_pallas_gear", "_pallas_fp"))
-def _datapath_step_impl(
-    batch: jax.Array, block_bytes: int, fp_seg_bytes: int, mask_bits: int, _pallas_gear: bool, _pallas_fp: bool
-):
-    n = batch.shape[-1]
-    if n % fp_seg_bytes or n % block_bytes:
-        raise ValueError(f"N={n} must be divisible by fp_seg_bytes and block_bytes")
-
-    def one(chunk):
-        h = gear_hash(chunk, pallas=_pallas_gear)
-        candidates = boundary_candidate_mask(h, mask_bits)
-        tags, literals, n_lit = blockpack.encode_device(chunk, block_bytes=block_bytes)
-        fp_lanes = fixed_stride_lanes(chunk, fp_seg_bytes, pallas=_pallas_fp)
-        return dict(candidates=candidates, tags=tags, literals=literals, n_lit=n_lit, fp_lanes=fp_lanes)
-
-    return jax.vmap(one)(batch)
-
-
-def datapath_step(batch: jax.Array, block_bytes: int = 512, fp_seg_bytes: int = 1 << 16, mask_bits: int = 16):
-    """Fused per-batch device step. batch: [B, N] uint8, N % fp_seg_bytes == 0.
-
-    Returns dict of device arrays:
-      candidates [B, N] bool — CDC boundary candidates
-      tags       [B, N/block_bytes] uint8 — blockpack block tags
-      literals   [B, N] uint8 — compacted literal bytes (dense prefix)
-      n_lit      [B] int32 — valid literal byte count
-      fp_lanes   [B, N/fp_seg_bytes, 8] uint32 — fixed-stride segment fingerprints
-
-    The Pallas flags are resolved HERE (per call, per kernel) and passed as
-    static args: resolving them inside the trace would freeze the env flags
-    into the first compiled program and silently ignore later flips.
-    """
-    from skyplane_tpu.ops.backend import on_accelerator
-    from skyplane_tpu.ops.pallas_kernels import use_pallas
-
-    acc = on_accelerator()
-    return _datapath_step_impl(
-        batch,
-        block_bytes=block_bytes,
-        fp_seg_bytes=fp_seg_bytes,
-        mask_bits=mask_bits,
-        _pallas_gear=bool(use_pallas("gear") and acc),
-        _pallas_fp=bool(use_pallas("fp") and acc),
-    )
 
 
 @dataclass

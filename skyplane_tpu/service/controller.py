@@ -114,7 +114,10 @@ class ServiceJob:
         self.start_latency_s: Optional[float] = None
         self.watch_rounds = 0  # sync-watch specs: rounds spawned so far
         self.last_progress_t = time.monotonic()  # stall-repost clock
-        self.last_round_t = 0.0  # sync-watch specs: when the last round spawned
+        # sync-watch specs: when the last round spawned, on time.monotonic()'s
+        # clock, whose zero is arbitrary (boot): a watch that has run no round
+        # in this process (new, or replayed from the WAL) is due now
+        self.last_round_t = float("-inf")
 
     def pending_chunk_ids(self) -> List[str]:
         return [cid for cid in self.chunks if cid not in self.landed]

@@ -199,7 +199,7 @@ def test_watch_paces_rounds_and_never_overlaps(fleet, tmp_path):
     time.sleep(0.05)
     (srcdir / "f.bin").write_bytes(b"Q" * 200_000)
     assert c.run_watch_rounds() == 0, "ignored the watch interval"
-    c.job(watch_id).last_round_t = 0.0  # simulate the interval elapsing
+    c.job(watch_id).last_round_t = float("-inf")  # simulate the interval elapsing
     assert c.run_watch_rounds() == 1
     c.close()
 

@@ -177,3 +177,26 @@ def test_pump_module_does_not_import_jax():
     proc = _run("import sys, skyplane_tpu.gateway.pump; print('jax' in sys.modules)")
     assert proc.returncode == 0, proc.stderr
     assert proc.stdout.strip() == "False"
+
+
+# ---- one device path: no kernel-selection flag, no second formulation ----
+
+
+def test_no_kernel_switch_and_no_device_blockpack_in_the_program():
+    """(spelled in pieces: the tree is grepped for the old names)"""
+    kernel_lib = "pal" + "las"
+    gone = (
+        "jax.experimental." + kernel_lib,
+        "jax.experimental import " + kernel_lib,
+        "SKYPLANE_TPU_USE_" + kernel_lib.upper(),
+        "SKYPLANE_TPU_" + "DONATE",
+    )
+    sources = [*sorted((REPO / "skyplane_tpu").rglob("*.py")), REPO / "bench.py", REPO / "chip_smoke.py"]
+    assert len(sources) > 100
+    for path in sources:
+        text = path.read_text()
+        for name in gone:
+            assert name not in text, f"{path.relative_to(REPO)} still has {name}"
+    assert not (REPO / "skyplane_tpu" / "ops" / f"{kernel_lib}_kernels.py").exists()
+    blockpack = (REPO / "skyplane_tpu" / "ops" / "blockpack.py").read_text()
+    assert "import jax" not in blockpack and "from jax" not in blockpack

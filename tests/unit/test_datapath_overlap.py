@@ -176,8 +176,8 @@ def test_donated_fp_call_bitexact_and_counted():
 
     chunk = rng.integers(0, 256, 70_000, dtype=np.uint8)
     padded = np.concatenate([chunk, np.zeros((1 << 17) - len(chunk), np.uint8)])
-    plain = FusedCDCFP(PARAMS, pallas=False, donate=False)
-    donating = FusedCDCFP(PARAMS, pallas=False, donate=True)
+    plain = FusedCDCFP(PARAMS, donate=False)
+    donating = FusedCDCFP(PARAMS, donate=True)
     want = plain(padded[None, :].copy(), [len(chunk)])  # 2D contiguous: never donated
     got = donating([padded, np.zeros_like(padded)], [len(chunk), 0])  # list form: donated
     np.testing.assert_array_equal(got[0][0], want[0][0])
@@ -195,10 +195,28 @@ def test_caller_provided_2d_batch_never_donated():
     chunk = rng.integers(0, 256, 1 << 16, dtype=np.uint8)
     batch = chunk[None, :].copy()
     before = batch.copy()
-    fused = FusedCDCFP(PARAMS, pallas=False, donate=True)
+    fused = FusedCDCFP(PARAMS, donate=True)
     fused(batch, [len(chunk)])
     np.testing.assert_array_equal(batch, before)
     assert fused.counters()["donated_batches"] == 0
+
+
+@pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
+@pytest.mark.parametrize("accel", [True, False], ids=["accelerator", "cpu"])
+def test_donation_follows_the_backend_and_reads_no_environment(accel, monkeypatch):
+    """``donate=None`` is ``on_accelerator()``: the old switch, set to the
+    opposite, moves nothing, and on an accelerator a batch the driver owns
+    goes through the donated variant of call B."""
+    import skyplane_tpu.ops.backend as backend
+    from skyplane_tpu.ops.fused_cdc import FusedCDCFP
+
+    monkeypatch.setenv("SKYPLANE_TPU_" + "DONATE", "0" if accel else "1")
+    monkeypatch.setattr(backend, "_is_accelerator", accel)
+    fused = FusedCDCFP(PARAMS)
+    assert fused.donate is accel
+    row = rng.integers(0, 256, 1 << 16, dtype=np.uint8)
+    fused([row], [len(row)])  # list form: the driver stacks it, so it owns the batch
+    assert fused.counters()["donated_batches"] == int(accel)
 
 
 # ---- sharded DataPathStats ----

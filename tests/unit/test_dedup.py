@@ -74,13 +74,11 @@ def test_segment_store_spill(tmp_path):
 def test_device_and_host_fingerprints_agree():
     import jax.numpy as jnp
 
-    from skyplane_tpu.ops.cdc import segment_ids_and_rev_pos
-    from skyplane_tpu.ops.fingerprint import finalize_fingerprint, segment_fingerprint_device
+    from skyplane_tpu.ops.fingerprint import finalize_fingerprint, segment_fingerprint_cumsum
 
     data = rng.integers(0, 256, 3000, dtype=np.uint8)
-    ends = np.array([1200, 3000])
-    seg_ids, rev_pos = segment_ids_and_rev_pos(ends, 3000)
-    lanes = np.asarray(segment_fingerprint_device(jnp.asarray(data), jnp.asarray(seg_ids), jnp.asarray(rev_pos), n_segments=2))
+    seg_starts, seg_ends = np.array([0, 1200], np.int32), np.array([1200, 3000], np.int32)
+    lanes = np.asarray(segment_fingerprint_cumsum(jnp.asarray(data), jnp.asarray(seg_starts), jnp.asarray(seg_ends), n_segments=2))
     host0 = segment_fingerprint_host(data[:1200].tobytes())
     host1 = segment_fingerprint_host(data[1200:].tobytes())
     assert bytes.fromhex(finalize_fingerprint(lanes[0], 1200)) == host0

@@ -108,7 +108,7 @@ def _device_spans(tracer) -> list:
 
 
 def test_one_dispatch_records_sibling_spans_that_tile_the_gap(monkeypatch):
-    fused = FusedCDCFP(PARAMS, pallas=False)
+    fused = FusedCDCFP(PARAMS)
     chunk = _chunk()
     batch = _padded(chunk)[None, :]
     fused(batch, [len(chunk)])  # compile outside what is timed
@@ -164,7 +164,7 @@ def _profile_event_names(log_dir: str) -> set:
 def test_device_spans_reach_a_jax_profile_only_when_the_tracer_is_on(sample, tmp_path):
     import jax
 
-    fused = FusedCDCFP(PARAMS, pallas=False)
+    fused = FusedCDCFP(PARAMS)
     chunk = _chunk()
     batch = _padded(chunk)[None, :]
     fused(batch, [len(chunk)])
@@ -216,7 +216,7 @@ def test_every_device_site_looks_span_up_at_call_time(site):
 
 
 def test_gap_and_row_counters_count_real_rows_and_only_rise():
-    fused = FusedCDCFP(PARAMS, pallas=False)
+    fused = FusedCDCFP(PARAMS)
     chunk = _chunk()
     rows = [_padded(chunk), np.zeros(BUCKET, np.uint8)]  # one real row, one pad row
     snaps = [fused.counters()]
@@ -249,7 +249,7 @@ def no_persistent_cache():
 
 def test_xla_compiles_rises_on_a_new_bucket_only(no_persistent_cache):
     params = CDCParams(min_bytes=512, avg_bytes=2048, max_bytes=8192)  # no other test's programs
-    fused = FusedCDCFP(params, pallas=False)
+    fused = FusedCDCFP(params)
     chunk = _chunk(20_000)
     small, large = _padded(chunk, 1 << 15)[None, :], _padded(chunk, 1 << 16)[None, :]
     c0 = fused.counters()
@@ -269,7 +269,7 @@ def test_a_load_from_the_persistent_cache_is_not_a_compile(tmp_path):
     import jax.numpy as jnp
     from jax.experimental.compilation_cache import compilation_cache
 
-    FusedCDCFP(PARAMS, pallas=False)  # the listener goes in with the first one built
+    FusedCDCFP(PARAMS)  # the listener goes in with the first one built
     old_dir = jax.config.jax_compilation_cache_dir
     old_min = jax.config.jax_persistent_cache_min_compile_time_secs
     jax.config.update("jax_compilation_cache_dir", str(tmp_path))
@@ -496,7 +496,7 @@ def test_device_programs_carry_their_stage_scopes(program, scopes):
     batch = jax.ShapeDtypeStruct((1, BUCKET), jnp.uint8)
     if program == "call_a":
         lowered = fused_mod._candidates_impl.lower(
-            batch, jax.ShapeDtypeStruct((1,), jnp.int32), mask_bits=PARAMS.mask_bits, cap=128, _pallas=False
+            batch, jax.ShapeDtypeStruct((1,), jnp.int32), mask_bits=PARAMS.mask_bits, cap=128
         )
     else:
         n_slots = fused_mod.slots_cap(BUCKET, PARAMS)
@@ -531,7 +531,7 @@ def test_call_a_gathers_nothing_per_byte():
     bucket = 1 << 20
     cap = fused_mod.candidate_cap(bucket, fused_mod.CDCParams())
     lowered = fused_mod._candidates_impl.lower(
-        jax.ShapeDtypeStruct((2, bucket), jnp.uint8), jax.ShapeDtypeStruct((2,), jnp.int32), mask_bits=14, cap=cap, _pallas=False
+        jax.ShapeDtypeStruct((2, bucket), jnp.uint8), jax.ShapeDtypeStruct((2,), jnp.int32), mask_bits=14, cap=cap
     )
     gathers = [o for o in _indexed_ops(lowered.compiler_ir("stablehlo")) if "gather" in o[0]]
     assert [o for o in gathers if o[1] >= bucket] == [], f"gathered over the row's bytes (cap {cap}, bucket {bucket})"

@@ -33,7 +33,7 @@ def _expected(arr, params=PARAMS):
 
 
 def _check(chunks, params=PARAMS):
-    fused = FusedCDCFP(params, pallas=False)
+    fused = FusedCDCFP(params)
     padded = [_pad(c) for c in chunks]
     bucket = max(len(p) for p in padded)
     batch = np.stack([_pad(p, bucket) for p in padded])
@@ -57,7 +57,7 @@ class TestFusedMatchesHost:
 
     def test_batch_with_zero_pad_rows(self):
         """Rows with n=0 (batch padding) must not crash or corrupt neighbors."""
-        fused = FusedCDCFP(PARAMS, pallas=False)
+        fused = FusedCDCFP(PARAMS)
         c = rng.integers(0, 256, 50_000, dtype=np.uint8)
         batch = np.stack([_pad(c), np.zeros(1 << 16, np.uint8)])
         results = fused(batch, [len(c), 0])
@@ -77,7 +77,7 @@ class TestFusedMatchesHost:
         chunk = rng.integers(0, 256, n, dtype=np.uint8)
         # ~n/256 = 256 expected candidates; cap of 16 guarantees overflow
         monkeypatch.setattr(fused_mod, "candidate_cap", lambda bucket, params=None: 16)
-        fused = fused_mod.FusedCDCFP(params, pallas=False)
+        fused = fused_mod.FusedCDCFP(params)
         called = {}
         real_fallback = fused_mod._host_exact
         monkeypatch.setattr(fused_mod, "_host_exact", lambda arr, p: called.setdefault("x", real_fallback(arr, p)))
@@ -99,7 +99,7 @@ def test_fuzz_params_and_lengths():
         CDCParams(min_bytes=2048, avg_bytes=8192, max_bytes=8192),  # avg == max
     ]
     for params in param_sets:
-        fused = FusedCDCFP(params, pallas=False)
+        fused = FusedCDCFP(params)
         lens = [int(x) for x in r.integers(1, 1 << 17, 4)] + [1 << 16, 5]
         chunks = []
         for i, n in enumerate(lens):
@@ -134,7 +134,7 @@ def test_all_fallback_batch_releases_pooled_scratch(monkeypatch):
     # ~n/256 = 256 expected candidates per row; cap of 16 guarantees overflow
     monkeypatch.setattr(fused_mod, "candidate_cap", lambda bucket, params=None: 16)
     pool = BufferPool()
-    fused = fused_mod.FusedCDCFP(params, pallas=False, pool=pool)
+    fused = fused_mod.FusedCDCFP(params, pool=pool)
     batch = rng.integers(0, 256, (2, n), dtype=np.uint8)  # pathological density corpus
     pending = fused.dispatch(batch, [n, n])
     assert all(f is not None for f in pending.fallback), "scenario must be all-fallback"
@@ -160,7 +160,7 @@ def test_mixed_fallback_batch_releases_scratch_via_lanes(monkeypatch):
     # (all zeros -> few/no gear candidates) stays on the device path
     monkeypatch.setattr(fused_mod, "candidate_cap", lambda bucket, params_=None: 16)
     pool = BufferPool()
-    fused = fused_mod.FusedCDCFP(params, pallas=False, pool=pool)
+    fused = fused_mod.FusedCDCFP(params, pool=pool)
     batch = np.stack([rng.integers(0, 256, n, dtype=np.uint8), np.zeros(n, np.uint8)])
     pending = fused.dispatch(batch, [n, n])
     assert pending.fallback[0] is not None and pending.fallback[1] is None, "scenario must be mixed"
@@ -237,7 +237,7 @@ def test_candidates_beyond_the_row_length_are_dropped(cut):
     n1 = {"before_the_first_candidate": int(positions[1][0]), "mid_row": int(positions[1][len(positions[1]) // 2]) + 1, "full_row": COMPACT_BUCKET}[cut]
     lens = np.array([COMPACT_BUCKET, n1], np.int32)
     cap = candidate_cap(COMPACT_BUCKET, PARAMS)
-    got = np.asarray(fused_mod._candidates_impl(rows, lens, mask_bits=PARAMS.mask_bits, cap=cap, _pallas=False))
+    got = np.asarray(fused_mod._candidates_impl(rows, lens, mask_bits=PARAMS.mask_bits, cap=cap))
     for row, pos, n in zip(got, positions, lens):
         mask = _mask_with(pos[pos < n])
         np.testing.assert_array_equal(row, _want_candidates(mask, cap))
@@ -312,7 +312,7 @@ def test_rows_of_several_blocks_match_host(name):
         assert across.any() and (ends[0] - starts[0])[across][0] == SHIPPED.max_bytes
     elif name == "random_full_row":
         assert ((starts[0] // EDGE) != ((ends[0] - 1) // EDGE)).any(), "no segment crosses a block edge"
-    fused = FusedCDCFP(SHIPPED, pallas=False)
+    fused = FusedCDCFP(SHIPPED)
     batch = np.stack([_pad(c, BIG) for c in chunks])
     results = fused(batch, [len(c) for c in chunks])
     for c, (got_ends, got_fps) in zip(chunks, results):
@@ -363,3 +363,34 @@ def test_segment_fingerprint_cumsum_hand_placed_ends():
     want[4] = segment_fingerprint_np(data[t + 1 : 2 * t - 700], [t - 701])[0]
     np.testing.assert_array_equal(got, want)
     assert want[[1, 2, 3, 4, 6]].all() and not want[[0, 5, 7, 8]].any()
+
+
+def test_graft_entry_is_the_two_live_programs_and_matches_the_host():
+    """``__graft_entry__.entry()`` jits, and what it returns is call A's
+    packed candidates and call B's lanes: boundaries selected from the one
+    and digests finalized from the other equal the host path on its batch."""
+    import importlib.util
+    from pathlib import Path
+
+    import jax
+
+    from skyplane_tpu.ops.cdc import select_boundaries
+    from skyplane_tpu.ops.fused_cdc import finalize_row
+
+    spec = importlib.util.spec_from_file_location("graft_entry", Path(__file__).resolve().parents[2] / "__graft_entry__.py")
+    graft = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(graft)
+    fn, args = graft.entry()
+    out = jax.jit(fn)(*args)
+    packed, lanes = np.asarray(out["candidates"]), np.asarray(out["fp_lanes"])
+    batch, lens, ends_slots = (np.asarray(a) for a in args)
+    params = CDCParams()
+    cap = candidate_cap(batch.shape[1], params)
+    assert packed.shape == (2, cap + 1) and lanes.shape == ends_slots.shape + (8,)
+    for i, n in enumerate(lens.tolist()):
+        want_ends, want_fps = _expected(batch[i, :n], params)
+        assert 0 < packed[i, cap] <= cap
+        ends = select_boundaries(packed[i, : packed[i, cap]].astype(np.int64), n, params)
+        np.testing.assert_array_equal(ends, want_ends)
+        np.testing.assert_array_equal(ends_slots[i, : len(ends)], want_ends)
+        assert finalize_row(lanes[i], ends) == want_fps

@@ -7,14 +7,24 @@ import jax.numpy as jnp
 from skyplane_tpu.ops import u32
 from skyplane_tpu.ops.gear import GEAR_TABLE, gear_hash, gear_hash_np, gear_values, boundary_candidate_mask
 from skyplane_tpu.ops import blockpack
-from skyplane_tpu.ops.cdc import CDCParams, cdc_segment_ends, segment_ids_and_rev_pos, select_boundaries
+from skyplane_tpu.ops.cdc import CDCParams, cdc_segment_ends, select_boundaries
 from skyplane_tpu.ops.fingerprint import (
-    segment_fingerprint_device,
+    segment_fingerprint_cumsum,
     segment_fingerprint_np,
     finalize_fingerprint,
 )
 
 rng = np.random.default_rng(42)
+
+
+def cumsum_lanes(data: np.ndarray, ends, n_segments: int) -> np.ndarray:
+    """Call B's formulation over one row: slot j is [ends[j-1], ends[j]), the
+    slots past the last end are empty (start == end == len(data))."""
+    seg_ends = np.full(n_segments, len(data), np.int32)
+    seg_ends[: len(ends)] = ends
+    seg_starts = np.concatenate([[0], seg_ends[:-1]]).astype(np.int32)
+    lanes = segment_fingerprint_cumsum(jnp.asarray(data), jnp.asarray(seg_starts), jnp.asarray(seg_ends), n_segments=n_segments)
+    return np.asarray(lanes)
 
 
 class TestU32:
@@ -156,21 +166,12 @@ class TestCDC:
     def test_empty_input(self):
         assert cdc_segment_ends(b"").tolist() == [0]
 
-    def test_segment_ids_and_rev_pos(self):
-        ends = np.array([3, 5, 9])
-        seg_ids, rev_pos = segment_ids_and_rev_pos(ends, 9)
-        np.testing.assert_array_equal(seg_ids, [0, 0, 0, 1, 1, 2, 2, 2, 2])
-        np.testing.assert_array_equal(rev_pos, [2, 1, 0, 1, 0, 3, 2, 1, 0])
-
 
 class TestFingerprint:
     def test_device_matches_numpy_reference(self):
         data = rng.integers(0, 256, size=2048, dtype=np.uint8)
         ends = np.array([100, 512, 1000, 2048])
-        seg_ids, rev_pos = segment_ids_and_rev_pos(ends, len(data))
-        got = np.asarray(
-            segment_fingerprint_device(jnp.asarray(data), jnp.asarray(seg_ids), jnp.asarray(rev_pos), n_segments=4)
-        )
+        got = cumsum_lanes(data, ends, 4)
         want = segment_fingerprint_np(data, ends)
         np.testing.assert_array_equal(got, want)
 
@@ -179,10 +180,7 @@ class TestFingerprint:
         seg_mut = ((seg.astype(np.int32) + 1) % 256).astype(np.uint8)
         data = np.concatenate([seg, seg, seg_mut])
         ends = np.array([500, 1000, 1500])
-        seg_ids, rev_pos = segment_ids_and_rev_pos(ends, len(data))
-        fps = np.asarray(
-            segment_fingerprint_device(jnp.asarray(data), jnp.asarray(seg_ids), jnp.asarray(rev_pos), n_segments=3)
-        )
+        fps = cumsum_lanes(data, ends, 3)
         assert (fps[0] == fps[1]).all()
         assert not (fps[0] == fps[2]).all()
         f0 = finalize_fingerprint(fps[0], 500)
@@ -193,7 +191,7 @@ class TestFingerprint:
     def test_padding_slots_do_not_affect_real_segments(self):
         data = rng.integers(0, 256, size=300, dtype=np.uint8)
         ends = np.array([300])
-        seg_ids, rev_pos = segment_ids_and_rev_pos(ends, 300)
-        a = np.asarray(segment_fingerprint_device(jnp.asarray(data), jnp.asarray(seg_ids), jnp.asarray(rev_pos), n_segments=1))
-        b = np.asarray(segment_fingerprint_device(jnp.asarray(data), jnp.asarray(seg_ids), jnp.asarray(rev_pos), n_segments=8))
+        a = cumsum_lanes(data, ends, 1)
+        b = cumsum_lanes(data, ends, 8)
         np.testing.assert_array_equal(a[0], b[0])
+        assert not b[1:].any(), "an empty slot has lanes 0"

@@ -1,4 +1,5 @@
-"""Multi-device SPMD data-path equivalence tests (8 virtual CPU devices)."""
+"""The data path sharded over a device mesh, held to the unsharded programs
+and to the host pipeline (8 virtual CPU devices)."""
 
 import numpy as np
 import pytest
@@ -6,16 +7,12 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from skyplane_tpu.ops.pipeline import datapath_step
-from skyplane_tpu.parallel.datapath_spmd import default_mesh, make_spmd_datapath
+from skyplane_tpu.parallel.datapath_spmd import default_mesh
 
 rng = np.random.default_rng(11)
 
 CHUNK = 64 * 1024
 BATCH = 4
-BLOCK = 512
-FP_SEG = 4096
-MASK_BITS = 10
 
 
 @pytest.fixture(scope="module")
@@ -42,42 +39,37 @@ def test_mesh_shape(mesh):
     assert mesh.shape["data"] * mesh.shape["seq"] == 8
 
 
-def test_spmd_matches_single_device(mesh):
+@pytest.mark.parametrize("shard_axes", [("data",), None], ids=["data-axis", "all-axes"])
+def test_sharded_kernels_equal_the_unsharded_programs(shard_axes):
+    """``make_sharded_kernels`` over a (data 2, seq 2) mesh, rows spread over
+    one axis or over all four devices: call A's packed candidates and call
+    B's lanes equal the single-device programs', array for array."""
+    from skyplane_tpu.ops.cdc import CDCParams, cdc_segment_ends
+    from skyplane_tpu.ops.fused_cdc import _candidates_impl, _fp_impl, candidate_cap, make_sharded_kernels, slots_cap
+
+    params = CDCParams(min_bytes=1024, avg_bytes=4096, max_bytes=16384)
+    cap, n_slots = candidate_cap(CHUNK, params), slots_cap(CHUNK, params)
     batch = _batch()
-    step, in_sharding = make_spmd_datapath(mesh, CHUNK, BATCH, BLOCK, FP_SEG, MASK_BITS)
-    sharded = jax.device_put(jnp.asarray(batch), in_sharding)
-    out = step(sharded)
-    ref = datapath_step(jnp.asarray(batch), block_bytes=BLOCK, fp_seg_bytes=FP_SEG, mask_bits=MASK_BITS)
+    lens = np.asarray([CHUNK, CHUNK, CHUNK, CHUNK * 5 // 8 + 321], np.int32)
+    batch[3, lens[3] :] = 0  # a tail row, zero-padded into the bucket
+    ends_slots = np.full((BATCH, n_slots), CHUNK, np.int32)
+    for i, n in enumerate(lens):
+        ends = cdc_segment_ends(batch[i, :n], params)
+        ends_slots[i, : len(ends)] = ends
 
-    # gear boundary candidates must match exactly, including across shard halos
-    np.testing.assert_array_equal(np.asarray(out["candidates"]), np.asarray(ref["candidates"]))
-    # blockpack tags are local per block -> identical
-    np.testing.assert_array_equal(np.asarray(out["tags"]), np.asarray(ref["tags"]))
-    # fixed-stride fingerprints are segment-aligned to shards -> identical
-    np.testing.assert_array_equal(np.asarray(out["fp_lanes"]), np.asarray(ref["fp_lanes"]))
-    # literal compaction is per-shard in SPMD: total literal bytes must agree
-    seq = mesh.shape["seq"]
-    n_lit_spmd = np.asarray(out["n_lit"]).reshape(BATCH, seq).sum(axis=1)
-    np.testing.assert_array_equal(n_lit_spmd, np.asarray(ref["n_lit"]))
+    mesh = default_mesh(jax.devices()[:4], data_parallel=2)
+    assert dict(mesh.shape) == {"data": 2, "seq": 2}
+    cand_fn, fp_fn = make_sharded_kernels(mesh, params, CHUNK, shard_axes=shard_axes)
+    packed = cand_fn(jnp.asarray(batch), jnp.asarray(lens))
+    lanes = fp_fn(jnp.asarray(batch), jnp.asarray(ends_slots))
+    n_shards = 2 if shard_axes else 4
+    assert len({s.index for s in packed.addressable_shards}) == n_shards  # the rows really are spread
 
-
-def test_spmd_literals_reconstruct(mesh):
-    """Per-shard literal buffers + tags fully reconstruct each chunk."""
-    from skyplane_tpu.ops.blockpack import decode_device
-
-    batch = _batch()
-    seq = mesh.shape["seq"]
-    n_local = CHUNK // seq
-    step, in_sharding = make_spmd_datapath(mesh, CHUNK, BATCH, BLOCK, FP_SEG, MASK_BITS)
-    out = step(jax.device_put(jnp.asarray(batch), in_sharding))
-    tags = np.asarray(out["tags"]).reshape(BATCH, seq, n_local // BLOCK)
-    literals = np.asarray(out["literals"]).reshape(BATCH, seq, n_local)
-    for b in range(BATCH):
-        rebuilt = []
-        for s in range(seq):
-            dec = decode_device(jnp.asarray(tags[b, s]), jnp.asarray(literals[b, s]), block_bytes=BLOCK)
-            rebuilt.append(np.asarray(dec))
-        np.testing.assert_array_equal(np.concatenate(rebuilt), batch[b])
+    want_packed = _candidates_impl(jnp.asarray(batch), jnp.asarray(lens), mask_bits=params.mask_bits, cap=cap)
+    want_lanes = _fp_impl(jnp.asarray(batch), jnp.asarray(ends_slots), n_slots=n_slots)
+    np.testing.assert_array_equal(np.asarray(packed), np.asarray(want_packed))
+    np.testing.assert_array_equal(np.asarray(lanes), np.asarray(want_lanes))
+    assert np.asarray(want_lanes)[0].any() and (np.asarray(want_packed)[:, cap] > 0).any()  # not a comparison of zeros
 
 
 def test_meshed_batch_runner_matches_host_path(mesh):
