@@ -294,6 +294,10 @@ DECODE_COUNTER_KEYS = (
     "pool_hit_rate",
     "verify_total",
     "verify_batched",
+    # parse_recipe's literal pass (PR 30): segments per call is how often the batch engages
+    "literal_pass_ns",
+    "literal_segments_verified",
+    "literal_verify_calls",
 )
 
 

@@ -185,6 +185,10 @@ REQUIRED_DECODE_COUNTERS = (
     "pool_hit_rate",
     "verify_total",
     "verify_batched",
+    # parse_recipe's literal pass (PR 30): segments per call is how often the batch engages
+    "literal_pass_ns",
+    "literal_segments_verified",
+    "literal_verify_calls",
 )
 # sender wire-engine section (mirrors bench.py WIRE_COUNTER_KEYS /
 # operators/sender_wire.py SENDER_WIRE_COUNTER_ZERO)

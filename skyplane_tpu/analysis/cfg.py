@@ -303,11 +303,16 @@ class CFG:
             # fallthrough (returned as our exits), the outer exception path
             # (re-raise after cleanup), and the real targets of any
             # return/break routed through it.
+            # They leave through one join, so an exit that is a branch edge of
+            # the finally's last statement (`if buf is not None: release(buf)`)
+            # keeps its kind, and what that edge implies, on the re-raise too.
+            after_fin = self._new("join", stmt.finalbody[-1])
             for src, kind in fin_exits:
-                self._edge(src, frame.exc_target, EXC)
-                for target in self._route_targets.get(fin_first, ()):
-                    self._edge(src, target)
-            exits.extend(fin_exits)
+                self._edge(src, after_fin, kind)
+            self._edge(after_fin, frame.exc_target, EXC)
+            for target in self._route_targets.get(fin_first, ()):
+                self._edge(after_fin, target)
+            exits.append((after_fin, NORMAL))
             head = b_first if b_first is not None else fin_first
         else:
             exits.extend(b_exits)

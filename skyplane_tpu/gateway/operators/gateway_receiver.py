@@ -75,6 +75,9 @@ DECODE_COUNTER_ZERO = {
     "ref_resolve_ns": 0,
     "ref_segments_resolved": 0,
     "ref_bytes_resolved": 0,
+    "literal_pass_ns": 0,
+    "literal_segments_verified": 0,
+    "literal_verify_calls": 0,
     "store_mem_hits": 0,
     "store_spill_reads": 0,
     "store_promotions": 0,
@@ -338,6 +341,9 @@ class GatewayReceiver:
             "ref_resolve_ns": 0,
             "ref_segments_resolved": 0,
             "ref_bytes_resolved": 0,
+            "literal_pass_ns": 0,
+            "literal_segments_verified": 0,
+            "literal_verify_calls": 0,
         }
         self._decode_threads: List[threading.Thread] = []
         for i in range(decode_workers):

@@ -105,20 +105,26 @@ def blockpack_encode(data: np.ndarray, block_bytes: int):
     return tags, lits[:n_lit], int(n_lit)
 
 
-def blockpack_decode(tags: np.ndarray, literals: np.ndarray, block_bytes: int) -> np.ndarray:
-    """(tags [NB], literals, block_bytes) -> [NB*block_bytes] uint8; raises
-    CodecException on a tag/literal length mismatch (corrupt container)."""
+def blockpack_decode(tags: np.ndarray, literals: np.ndarray, block_bytes: int, out=None) -> np.ndarray:
+    """(tags [NB], literals, block_bytes) -> [NB*block_bytes] uint8, written into
+    the head of ``out`` (a C-contiguous uint8 array at least that long) where the
+    caller gives one; raises CodecException on a tag/literal length mismatch
+    (corrupt container)."""
     from skyplane_tpu.exceptions import CodecException
 
     tags = np.ascontiguousarray(tags, dtype=np.uint8)
     literals = np.ascontiguousarray(literals, dtype=np.uint8)
-    out = np.empty(len(tags) * block_bytes, np.uint8)
+    n_out = len(tags) * block_bytes
+    if out is None:
+        out = np.empty(n_out, np.uint8)
+    elif len(out) < n_out or out.dtype != np.uint8 or not out.flags.c_contiguous:
+        raise ValueError(f"blockpack_decode needs a C-contiguous uint8 output of {n_out} bytes")
     rc = load_library().skydp_blockpack_decode(
         _u8p(tags), len(tags), _u8p(literals), len(literals), block_bytes, _u8p(out)
     )
     if rc != 0:
         raise CodecException("blockpack container corrupt: tag/literal length mismatch")
-    return out
+    return out[:n_out]
 
 
 def segment_fp_lanes(data: np.ndarray, ends: np.ndarray) -> np.ndarray:

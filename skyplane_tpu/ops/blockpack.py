@@ -23,6 +23,7 @@ built, its numpy form (ops/host_fallback.py) otherwise.
 from __future__ import annotations
 
 import struct
+from typing import Optional
 
 import numpy as np
 
@@ -45,10 +46,15 @@ def _pack_tags(tags: np.ndarray) -> bytes:
     return packed.astype(np.uint8).tobytes()
 
 
-def _unpack_tags(buf: bytes, n_blocks: int) -> np.ndarray:
+def _unpack_tags(buf, n_blocks: int) -> np.ndarray:
     packed = np.frombuffer(buf, dtype=np.uint8)
     t = np.stack([packed & 3, (packed >> 2) & 3, (packed >> 4) & 3, (packed >> 6) & 3], axis=1).reshape(-1)
     return t[:n_blocks]
+
+
+def padded_len(n_raw: int, block_bytes: int = DEFAULT_BLOCK_BYTES) -> int:
+    """Bytes ``decode_container`` writes for ``n_raw`` decoded bytes: whole blocks."""
+    return -(-n_raw // block_bytes) * block_bytes
 
 
 def encode_container(data: bytes, block_bytes: int = DEFAULT_BLOCK_BYTES) -> bytes:
@@ -75,8 +81,17 @@ def encode_container(data: bytes, block_bytes: int = DEFAULT_BLOCK_BYTES) -> byt
     return header + _pack_tags(tags_np) + lit_np.tobytes()
 
 
-def decode_container(buf: bytes) -> bytes:
-    """Host entry: blockpack container -> raw bytes."""
+def decode_container(buf, out: Optional[np.ndarray] = None) -> memoryview:
+    """Host entry: blockpack container -> a view of the raw bytes.
+
+    ``buf`` is any C-contiguous buffer; tags and literals are read in place.
+    The kernel writes whole blocks, so ``out`` (a uint8 array the caller owns,
+    a pooled buffer on the receiver's decode path) has to hold
+    ``padded_len(n_raw, block_bytes)`` bytes; a shorter one is refused. Without
+    ``out`` the blocks go into a fresh array. The view returned is over the
+    first ``n_raw`` bytes of whichever it was and lives as long as that does.
+    """
+    buf = memoryview(buf)
     head_len = 2 + struct.calcsize("<BBQQ")
     if len(buf) < 2 or buf[:2] != MAGIC:
         raise CodecException("not a blockpack container (bad magic)")
@@ -89,23 +104,26 @@ def decode_container(buf: bytes) -> bytes:
         raise CodecException(f"unsupported blockpack version {ver}")
     block_bytes = 1 << block_log2
     if n_raw == 0:
-        return b""
-    off = 2 + struct.calcsize("<BBQQ")
-    n_padded = ((n_raw + block_bytes - 1) // block_bytes) * block_bytes
+        return memoryview(b"")
+    n_padded = padded_len(n_raw, block_bytes)
     n_blocks = n_padded // block_bytes
     tag_bytes = (n_blocks + 3) // 4
-    if len(buf) < off + tag_bytes:
+    if len(buf) < head_len + tag_bytes:
         raise CodecException("truncated blockpack container (tag region)")
-    tags = _unpack_tags(buf[off : off + tag_bytes], n_blocks)
-    literals = np.frombuffer(buf[off + tag_bytes : off + tag_bytes + n_lit], np.uint8)
+    if out is None:
+        out = np.empty(n_padded, np.uint8)
+    elif len(out) < n_padded:
+        raise CodecException(f"blockpack output buffer holds {len(out)} bytes, the container needs {n_padded}")
+    tags = _unpack_tags(buf[head_len : head_len + tag_bytes], n_blocks)
+    literals = np.frombuffer(buf[head_len + tag_bytes : head_len + tag_bytes + n_lit], np.uint8)
     if len(literals) != n_lit:
         raise CodecException("truncated blockpack container")
     from skyplane_tpu.native import datapath as native_dp
 
     if native_dp.available():
-        out = native_dp.blockpack_decode(tags, literals, block_bytes)
+        native_dp.blockpack_decode(tags, literals, block_bytes, out)
     else:
         from skyplane_tpu.ops.host_fallback import blockpack_decode_host
 
-        out = blockpack_decode_host(tags, literals, block_bytes)
-    return out[:n_raw].tobytes()
+        blockpack_decode_host(tags, literals, block_bytes, out)
+    return memoryview(out)[:n_raw]

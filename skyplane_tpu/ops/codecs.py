@@ -19,17 +19,24 @@ from __future__ import annotations
 
 import os
 import threading
-from typing import Callable, Dict, NamedTuple
+from typing import Callable, Dict, NamedTuple, Optional
 
 from skyplane_tpu.chunk import Codec
 from skyplane_tpu.exceptions import CodecException
 
 
 class CodecSpec(NamedTuple):
+    """``decode`` takes any C-contiguous buffer and may return any (``bytes``, or
+    a view of an array); a caller that needs ``bytes`` converts. A codec that
+    can write into memory its caller owns says so with ``decode_out_len``:
+    the length of the ``out`` array ``decode(buf, out)`` needs for ``n``
+    decoded bytes. What comes back is then a view of ``out``."""
+
     name: str
     codec_id: Codec
     encode: Callable[[bytes], bytes]
-    decode: Callable[[bytes], bytes]
+    decode: Callable[..., object]
+    decode_out_len: Optional[Callable[[int], int]] = None
 
 
 def _zstd():
@@ -112,18 +119,24 @@ def _encode_tpu(data: bytes) -> bytes:
     return blockpack.encode_container(data)
 
 
-def _decode_tpu(buf: bytes) -> bytes:
+def _decode_tpu(buf, out=None):
     from skyplane_tpu.ops import blockpack
 
-    return blockpack.decode_container(buf)
+    return blockpack.decode_container(buf, out)
+
+
+def _tpu_out_len(n: int) -> int:
+    from skyplane_tpu.ops import blockpack
+
+    return blockpack.padded_len(n)
 
 
 def _encode_tpu_zstd(data: bytes) -> bytes:
     return _encode_zstd(_encode_tpu(data))
 
 
-def _decode_tpu_zstd(buf: bytes) -> bytes:
-    return _decode_tpu(_decode_zstd(buf))
+def _decode_tpu_zstd(buf, out=None):
+    return _decode_tpu(_decode_zstd(buf), out)
 
 
 def _encode_native(data: bytes) -> bytes:
@@ -135,7 +148,7 @@ def _encode_native(data: bytes) -> bytes:
 def _decode_native(buf: bytes) -> bytes:
     from skyplane_tpu.native import lz as native_lz
 
-    return native_lz.decompress(buf)
+    return native_lz.decompress(bytes(buf))
 
 
 def _encode_lz4(data: bytes) -> bytes:
@@ -151,7 +164,7 @@ def _decode_lz4(buf: bytes) -> bytes:
     from skyplane_tpu.utils import lz4ref
 
     try:
-        return lz4ref.decompress(buf, MAX_CHUNK_BYTES)
+        return lz4ref.decompress(bytes(buf), MAX_CHUNK_BYTES)
     except ValueError as e:
         raise CodecException(f"lz4 decode failed: {e}") from e
 
@@ -159,8 +172,8 @@ def _decode_lz4(buf: bytes) -> bytes:
 _REGISTRY: Dict[str, CodecSpec] = {
     "none": CodecSpec("none", Codec.NONE, lambda b: b, lambda b: b),
     "zstd": CodecSpec("zstd", Codec.ZSTD, _encode_zstd, _decode_zstd),
-    "tpu": CodecSpec("tpu", Codec.TPU_BLOCK, _encode_tpu, _decode_tpu),
-    "tpu_zstd": CodecSpec("tpu_zstd", Codec.TPU_BLOCK_ZSTD, _encode_tpu_zstd, _decode_tpu_zstd),
+    "tpu": CodecSpec("tpu", Codec.TPU_BLOCK, _encode_tpu, _decode_tpu, _tpu_out_len),
+    "tpu_zstd": CodecSpec("tpu_zstd", Codec.TPU_BLOCK_ZSTD, _encode_tpu_zstd, _decode_tpu_zstd, _tpu_out_len),
     "native_lz": CodecSpec("native_lz", Codec.NATIVE_LZ, _encode_native, _decode_native),
     # the reference's wire codec (gateway_operator.py:358-361), bound to the
     # system liblz4; registered unconditionally — encode/decode raise on

@@ -40,12 +40,13 @@ from skyplane_tpu.exceptions import CodecException, DedupIntegrityException
 from skyplane_tpu.faults import get_injector as _get_injector
 from skyplane_tpu.obs.tracer import NOOP_SPAN, get_tracer as _get_tracer
 from skyplane_tpu.ops.bufpool import BufferPool, bucket_size
-from skyplane_tpu.ops.fingerprint import segment_fingerprint_host
+from skyplane_tpu.ops.fingerprint import MAX_SEGMENT_BYTES, segment_fingerprint_host, segment_fingerprints_host_batch
 from skyplane_tpu.obs import lockwitness as lockcheck
 
 MAGIC = b"\xde\xd1"
 VERSION = 1
 _ENTRY = struct.Struct("<B16sQ")
+_ENTRY_TABLE = np.dtype([("kind", "u1"), ("fp", "u1", (16,)), ("len", "<u8")])  # _ENTRY, packed: a table in one read
 KIND_REF = 0
 KIND_LIT = 1
 # hard cap on the raw bytes a recipe may claim to restore to — mirrors
@@ -851,7 +852,7 @@ class PooledChunk:
 
 
 def parse_recipe(
-    buf: bytes,
+    buf,
     store: SegmentStore,
     decode_blob,
     ref_wait_timeout: float = 0.0,
@@ -860,6 +861,7 @@ def parse_recipe(
     expected_raw_len: Optional[int] = None,
     ref_stats: Optional[dict] = None,
     ref_span=NOOP_SPAN,
+    blob_out_len=None,
 ):
     """Receiver side: resolve a recipe back into raw chunk bytes.
 
@@ -868,21 +870,32 @@ def parse_recipe(
     work — a hostile entry list must not size an allocation, and the
     mismatch fails fast instead of after a full restore.
 
-    Two passes over the entries. The first takes the literals: each is
-    inserted into ``store`` so later refs resolve, and placed in the output.
-    With ``verify_literals``, each literal's fingerprint is recomputed before
-    admission — a corrupted literal stored under a healthy fingerprint would
-    propagate to every future chunk that REFs it. The second resolves the
-    REFs (``store.get`` and the copy into the output), this chunk's own
-    repeats among them, under ``ref_span``; ``ref_stats``, where given,
-    receives what that pass did: ``ref_resolve_ns``, ``ref_segments_resolved``,
-    ``ref_bytes_resolved`` (nothing for a recipe with no REF).
+    The entry table is read in one piece, then two passes. The literal pass
+    takes the chunk's literals as one array: ``decode_blob`` gets the blob as a
+    ``memoryview`` and returns any C-contiguous buffer; with
+    ``verify_literals`` one batched call recomputes every literal's
+    fingerprint — a corrupted literal stored under a healthy fingerprint
+    would propagate to every future chunk that REFs it — and ALL of the
+    chunk's literals are checked before any is admitted; then each is
+    inserted into ``store`` (which keeps its own copy, never a view) so later
+    refs resolve, and each run of consecutive literals is placed in the output
+    with one copy. The second pass resolves the REFs (``store.get`` and the
+    copy into the output), this chunk's own repeats among them, under
+    ``ref_span``. ``ref_stats``, where given, receives what the passes did:
+    ``literal_pass_ns`` (blob decode, verify, admit, place),
+    ``literal_segments_verified``, ``literal_verify_calls``, and, for a recipe
+    that holds a REF, ``ref_resolve_ns``, ``ref_segments_resolved``,
+    ``ref_bytes_resolved``.
 
-    With ``out_pool``, segments are assembled directly into a pooled output
-    buffer (one copy per segment) and a :class:`PooledChunk` is returned
-    instead of ``bytes``; the caller writes its ``view`` out and releases it.
-    Without a pool the historical ``bytes`` return is unchanged.
+    With ``out_pool``, the output is assembled in a pooled buffer and a
+    :class:`PooledChunk` is returned instead of ``bytes``; the caller writes
+    its ``view`` out and releases it. Where the codec can write into memory
+    its caller owns (``blob_out_len``: ``CodecSpec.decode_out_len``), the
+    decoded literals go into a second pooled buffer, handed to
+    ``decode_blob(blob, out)`` and released before the return. Without a pool
+    the historical ``bytes`` return is unchanged.
     """
+    buf = memoryview(buf)
     head_len = 2 + struct.calcsize("<BI")
     if len(buf) < head_len or buf[:2] != MAGIC:
         raise CodecException("not a dedup recipe (bad magic / truncated header)")
@@ -894,58 +907,77 @@ def parse_recipe(
     # or corrupted count must not crash the handler or drive huge allocations
     if n_entries * _ENTRY.size > len(buf) - off:
         raise CodecException(f"recipe claims {n_entries} entries but only {len(buf) - off} bytes follow")
-    entries = []
-    total = 0
-    for _ in range(n_entries):
-        kind, fp, seg_len = _ENTRY.unpack_from(buf, off)
-        off += _ENTRY.size
-        entries.append((kind, fp, seg_len))
-        total += seg_len
+    table = np.frombuffer(buf, _ENTRY_TABLE, count=n_entries, offset=off)
+    off += n_entries * _ENTRY.size
+    kinds = table["kind"]
+    lens_l = table["len"].tolist()  # python ints: a hostile u64 must not wrap a numpy sum
+    total = sum(lens_l)
     if total > MAX_RECIPE_RAW_BYTES:
         raise CodecException(f"recipe claims {total} raw bytes (> {MAX_RECIPE_RAW_BYTES} cap)")
     if expected_raw_len is not None and total != expected_raw_len:
         raise CodecException(f"recipe entries claim {total} raw bytes but the header declared {expected_raw_len}")
-    lit_blob = decode_blob(buf[off:])
-    # the output: a pooled buffer (``arr``, released on every failing path) or a plain one;
-    # no second name for ``arr``: analysis/resources.py follows the pooled buffer by name
+    is_lit = kinds == KIND_LIT
+    bad = np.flatnonzero(~is_lit & (kinds != KIND_REF))
+    if len(bad):
+        raise CodecException(f"bad recipe entry kind {int(kinds[bad[0]])}")
+    lens = np.asarray(lens_l, np.int64)  # each at most the cap, so int64 holds them and their sums
+    out_offs = np.cumsum(lens) - lens  # where each entry starts in the output
+    fp_blob = table["fp"].tobytes()  # 16 bytes an entry, in entry order
+    lit_idx = np.flatnonzero(is_lit)
+    lit_lens = lens[lit_idx]
+    lit_ends = np.cumsum(lit_lens)  # where each literal ends in the decoded blob
+    lit_total = int(lit_ends[-1]) if len(lit_idx) else 0
+    lit_fps = [fp_blob[16 * i : 16 * i + 16] for i in lit_idx.tolist()]
+    # the output: a pooled buffer (``arr``, released on every failing path) or a plain one; the decoded
+    # literals: a second pooled buffer (``lit_arr``, released on every path) where the codec takes one.
+    # No second name for either: analysis/resources.py follows a pooled buffer by name
     plain = np.empty(total, np.uint8) if out_pool is None or total == 0 else None
     arr: Optional[np.ndarray] = None
+    lit_arr: Optional[np.ndarray] = None
     if plain is None:
         arr = out_pool.acquire(bucket_size(total))
-    refs = []  # (offset in the output, fp, seg_len)
-    out_off = 0
-    lit_off = 0
     try:
-        for kind, fp, seg_len in entries:
-            if kind == KIND_LIT:
-                seg = lit_blob[lit_off : lit_off + seg_len]
-                if len(seg) != seg_len:
-                    raise DedupIntegrityException("literal blob shorter than recipe entries")
-                lit_off += seg_len
-                if verify_literals:
-                    if segment_fingerprint_host(seg) != fp:
-                        raise DedupIntegrityException(f"literal segment fingerprint mismatch (claimed {fp.hex()})")
-                store.put(fp, seg)
-                (plain if arr is None else arr)[out_off : out_off + seg_len] = np.frombuffer(seg, np.uint8)
-            elif kind == KIND_REF:
-                refs.append((out_off, fp, seg_len))
-            else:
-                raise CodecException(f"bad recipe entry kind {kind}")
-            out_off += seg_len
-        if lit_off != len(lit_blob):
-            raise DedupIntegrityException("literal blob longer than recipe entries")
-        if refs:
+        t_lit = time.perf_counter_ns()
+        if arr is not None and blob_out_len is not None and lit_total:
+            lit_arr = out_pool.acquire(bucket_size(blob_out_len(lit_total)))
+        try:
+            lit = np.frombuffer(decode_blob(buf[off:]) if lit_arr is None else decode_blob(buf[off:], lit_arr), np.uint8)
+            if len(lit) != lit_total:
+                how = "shorter" if len(lit) < lit_total else "longer"
+                raise DedupIntegrityException(f"literal blob {how} than recipe entries")
+            if verify_literals and lit_fps:
+                _verify_literals(lit, lit_ends, lit_fps)
+            for fp, a, b in zip(lit_fps, (lit_ends - lit_lens).tolist(), lit_ends.tolist()):
+                store.put(fp, lit[a:b].tobytes())  # the store's own copy, never a view of pooled memory
+            # a run of consecutive literal entries is contiguous in the blob and in the output: one copy
+            run_heads = lit_idx[np.flatnonzero(np.diff(lit_idx, prepend=-2) != 1)]
+            run_tails = lit_idx[np.flatnonzero(np.diff(lit_idx, append=-2) != 1)]
+            src = 0
+            for at, end in zip(out_offs[run_heads].tolist(), (out_offs[run_tails] + lens[run_tails]).tolist()):
+                (plain if arr is None else arr)[at:end] = lit[src : src + end - at]
+                src += end - at
+        finally:
+            if lit_arr is not None:
+                out_pool.release(lit_arr)
+        if ref_stats is not None:
+            ref_stats["literal_pass_ns"] = time.perf_counter_ns() - t_lit
+            ref_stats["literal_segments_verified"] = len(lit_fps) if verify_literals else 0
+            ref_stats["literal_verify_calls"] = 1 if verify_literals and lit_fps else 0
+        ref_idx = np.flatnonzero(~is_lit).tolist()
+        if ref_idx:
             t0 = time.perf_counter_ns()
             with ref_span:
-                for at, fp, seg_len in refs:
+                at_l = out_offs.tolist()
+                for i in ref_idx:
+                    fp, at, seg_len = fp_blob[16 * i : 16 * i + 16], at_l[i], lens_l[i]
                     seg = store.get(fp, wait_timeout=ref_wait_timeout)
                     if len(seg) != seg_len:
                         raise DedupIntegrityException(f"dedup ref {fp.hex()} length mismatch")
                     (plain if arr is None else arr)[at : at + seg_len] = np.frombuffer(seg, np.uint8)
             if ref_stats is not None:
                 ref_stats["ref_resolve_ns"] = time.perf_counter_ns() - t0
-                ref_stats["ref_segments_resolved"] = len(refs)
-                ref_stats["ref_bytes_resolved"] = sum(seg_len for _, _, seg_len in refs)
+                ref_stats["ref_segments_resolved"] = len(ref_idx)
+                ref_stats["ref_bytes_resolved"] = total - lit_total
     except BaseException:
         if arr is not None:
             out_pool.release(arr)  # a failed decode must not leak the buffer
@@ -953,3 +985,18 @@ def parse_recipe(
     if arr is not None:
         return PooledChunk(arr, out_pool, total)
     return plain.tobytes()
+
+
+def _verify_literals(lit: np.ndarray, lit_ends: np.ndarray, lit_fps: List[bytes]) -> None:
+    """Recompute the fingerprint of every literal of one chunk in one batched
+    call (native when built, numpy otherwise) and hold each to the fingerprint
+    its entry claims; the first that differs raises."""
+    if len(lit):
+        if int(np.diff(lit_ends, prepend=0).max()) > MAX_SEGMENT_BYTES:
+            raise CodecException(f"literal segment longer than {MAX_SEGMENT_BYTES} bytes: no sender cuts one")
+        got = segment_fingerprints_host_batch(lit, lit_ends)
+    else:  # nothing but empty literals: the batch form answers an empty array with no digests
+        got = [segment_fingerprint_host(b"")] * len(lit_fps)
+    if got != lit_fps:
+        bad = next(fp for fp, g in zip(lit_fps, got) if fp != g)
+        raise DedupIntegrityException(f"literal segment fingerprint mismatch (claimed {bad.hex()})")

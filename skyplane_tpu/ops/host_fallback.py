@@ -66,7 +66,9 @@ def blockpack_encode_host(data: np.ndarray, block_bytes: int) -> Tuple[np.ndarra
     return tags, np.empty(0, np.uint8), 0
 
 
-def blockpack_decode_host(tags: np.ndarray, literals: np.ndarray, block_bytes: int) -> np.ndarray:
+def blockpack_decode_host(tags: np.ndarray, literals: np.ndarray, block_bytes: int, out=None) -> np.ndarray:
+    """Same contract as native ``blockpack_decode``: the blocks go into the first
+    ``len(tags) * block_bytes`` bytes of ``out`` where the caller gives one."""
     from skyplane_tpu.exceptions import CodecException
     from skyplane_tpu.ops.blockpack import TAG_CONST, TAG_LITERAL
 
@@ -77,7 +79,10 @@ def blockpack_decode_host(tags: np.ndarray, literals: np.ndarray, block_bytes: i
         # (keep the error inside the codec contract)
         raise CodecException("blockpack container corrupt: tag/literal length mismatch")
     offsets = np.cumsum(lens) - lens
-    out = np.zeros(nb * block_bytes, np.uint8)
+    if out is None:
+        out = np.empty(nb * block_bytes, np.uint8)
+    out = out[: nb * block_bytes]
+    out[:] = 0
     blocks = out.reshape(nb, block_bytes)
     lit_idx = np.flatnonzero(tags == TAG_LITERAL)
     if len(lit_idx):

@@ -438,7 +438,7 @@ class DataPathProcessor:
         assemble into a pooled buffer and a :class:`PooledChunk` is returned —
         the caller writes ``.view`` out and calls ``.release()``. Non-recipe
         payloads (and ``pooled=False``) return plain ``bytes``. ``ref_stats``
-        is ``parse_recipe``'s: what resolving this chunk's REFs took.
+        is ``parse_recipe``'s: what its literal pass and its REF pass did.
         """
         codec = get_codec_by_id(header.codec)
         if header.is_recipe:
@@ -456,9 +456,12 @@ class DataPathProcessor:
                 ref_span=get_tracer().span(
                     "decode.ref_resolve", trace_id=header.chunk_id, cat="receiver", force=header.is_traced
                 ),
+                blob_out_len=codec.decode_out_len,
             )
         else:
             data = codec.decode(payload)
+            if not isinstance(data, bytes):
+                data = bytes(data)  # a codec may hand back a view of an array; this path returns ``bytes``
         view = data.view if isinstance(data, PooledChunk) else data
         try:
             if len(view) != header.raw_data_len:

@@ -215,6 +215,13 @@ def test_the_sink_resolved_every_ref_the_source_sent(chain):
     assert 0 < chain["sink"]["ref_resolve_ns"] < chain["sink"]["decode_ns"]
 
 
+def test_the_sink_verified_every_literal_the_source_sent_one_call_a_chunk(chain):
+    """The literal pass (PR 30): every literal entry is fingerprint-checked, by one batched call a chunk."""
+    assert chain["sink"]["literal_segments_verified"] == chain["source"]["segments"] - chain["source"]["ref_segments"] > 0
+    assert 0 < chain["sink"]["literal_verify_calls"] <= chain["sink"]["decode_chunks"]
+    assert 0 < chain["sink"]["literal_pass_ns"] < chain["sink"]["decode_ns"]
+
+
 def test_the_codec_part_of_the_recipe_time_is_counted(chain):
     assert 0 < chain["source"]["recipe_encode_ns"] <= chain["source"]["recipe_ns"]
 

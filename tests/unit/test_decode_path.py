@@ -392,3 +392,271 @@ def test_put_drop_oldest_keeps_freshest():
     for i in range(4):
         put_drop_oldest(q, {"i": i})
     assert [q.get_nowait()["i"] for _ in range(2)] == [2, 3]
+
+
+# ------------------------------------------- the literal pass in one sweep (PR 30)
+
+
+def _reference_parse(wire, store, decode_blob, verify=True):
+    """The per-segment literal pass as it stood: slice, fingerprint, put,
+    place, one literal at a time; then the REFs. Returns (raw, ref stats)."""
+    wire = bytes(wire)
+    _ver, n_entries = struct.unpack_from("<BI", wire, 2)
+    off = 2 + struct.calcsize("<BI")
+    entries = []
+    for _ in range(n_entries):
+        entries.append(dedup_mod._ENTRY.unpack_from(wire, off))
+        off += dedup_mod._ENTRY.size
+    blob = bytes(decode_blob(wire[off:]))
+    out = bytearray(sum(e[2] for e in entries))
+    refs, at, lit_off = [], 0, 0
+    for kind, fp, seg_len in entries:
+        if kind == dedup_mod.KIND_LIT:
+            seg = blob[lit_off : lit_off + seg_len]
+            lit_off += seg_len
+            if verify and segment_fingerprint_host(seg) != fp:
+                raise DedupIntegrityException(f"literal segment fingerprint mismatch (claimed {fp.hex()})")
+            store.put(fp, seg)
+            out[at : at + seg_len] = seg
+        else:
+            refs.append((at, fp, seg_len))
+        at += seg_len
+    for at, fp, seg_len in refs:
+        out[at : at + seg_len] = store.get(fp)
+    stats = {"ref_segments_resolved": len(refs), "ref_bytes_resolved": sum(r[2] for r in refs)} if refs else {}
+    return bytes(out), stats
+
+
+def _recipe_case(name):
+    """(segments in order, fingerprints the sender's index already holds)."""
+    case_rng = np.random.default_rng(30)
+
+    def seg(n):
+        data = case_rng.integers(0, 256, n, dtype=np.uint8).tobytes()
+        return segment_fingerprint_host(data), data
+
+    known = [seg(900), seg(1300), seg(70)]
+    fresh = [seg(n) for n in (1100, 1, 4096, 513, 2048, 777, 5000, 64)]
+    if name == "all_literal":
+        return fresh, []
+    if name == "all_ref":
+        return known + known[:1], known
+    if name == "interleaved":  # literal runs of 1, 2, 3 and 1 between REFs, a REF first and a literal last
+        order = [known[0], fresh[0], known[1], fresh[1], fresh[2], known[2], fresh[3], fresh[4], fresh[5], known[0], fresh[6]]
+        return order, known
+    if name == "own_chunk_repeat":  # the second sight of a fresh segment is a REF into this very chunk
+        return [fresh[0], fresh[1], fresh[0], known[0], fresh[2], fresh[2]], known
+    if name == "empty":
+        return [], []
+    if name == "padded_last_block":  # 1100 + 1 + 4096 + 513 bytes of literals: not whole 512-byte blocks
+        return fresh[:4] + [known[1]], known
+    if name == "empty_literal":
+        return [fresh[0], (segment_fingerprint_host(b""), b""), fresh[1]], []
+    raise AssertionError(name)
+
+
+def _codec_pair(codec_name):
+    if codec_name == "ident":
+        return ident, ident, None
+    if codec_name == "tpu_zstd":
+        pytest.importorskip("zstandard")
+    from skyplane_tpu.ops.codecs import get_codec
+
+    spec = get_codec(codec_name)
+    return spec.encode, spec.decode, spec.decode_out_len
+
+
+def _stores_equal(a: SegmentStore, b: SegmentStore, fps):
+    assert a.mem_segment_count == b.mem_segment_count
+    for fp in fps:
+        assert (fp in a) == (fp in b)
+        if fp in a:
+            assert a.peek(fp) == b.peek(fp)
+            assert type(a.peek(fp)) is bytes, "the store keeps its own bytes, never a view"
+
+
+CASES = ["all_literal", "all_ref", "interleaved", "own_chunk_repeat", "empty", "padded_last_block", "empty_literal"]
+
+
+@pytest.mark.parametrize("pooled", [False, True], ids=["plain", "pooled"])
+@pytest.mark.parametrize("codec_name", ["ident", "tpu", "tpu_zstd"])
+@pytest.mark.parametrize("case", CASES)
+def test_literal_pass_matches_per_segment_reference(case, codec_name, pooled):
+    """(a) the one-sweep literal pass against the per-segment form written
+    out above: output bytes, store contents and ref_stats equal."""
+    encode, decode, out_len = _codec_pair(codec_name)
+    segments, known = _recipe_case(case)
+    index = SenderDedupIndex()
+    want_store, got_store = SegmentStore(), SegmentStore()
+    for fp, data in known:
+        index.add(fp, len(data))
+        want_store.put(fp, data)
+        got_store.put(fp, data)
+    wire, n_ref, *_ = build_recipe(segments, index, encode)
+    raw = b"".join(data for _, data in segments)
+    want, want_stats = _reference_parse(wire, want_store, decode)
+    assert want == raw
+    pool = BufferPool()
+    stats: dict = {}
+    got = parse_recipe(
+        wire, got_store, decode, verify_literals=True, out_pool=pool if pooled else None,
+        expected_raw_len=len(raw), ref_stats=stats, blob_out_len=out_len,
+    )
+    if pooled and raw:
+        assert isinstance(got, PooledChunk)
+        assert bytes(got.view) == raw
+        got.release()
+    else:
+        assert got == raw and type(got) is bytes
+    assert pool.counters()["pool_outstanding"] == 0, "a pooled buffer of the literal pass was not released"
+    _stores_equal(want_store, got_store, [fp for fp, _ in segments + known])
+    assert {k: v for k, v in stats.items() if k in ("ref_segments_resolved", "ref_bytes_resolved")} == want_stats
+    assert stats.get("ref_segments_resolved", 0) == n_ref
+    n_lit = len(segments) - n_ref
+    assert stats["literal_segments_verified"] == n_lit
+    assert stats["literal_verify_calls"] == (1 if n_lit else 0)
+    assert 0 < stats["literal_pass_ns"]
+    assert ("ref_resolve_ns" in stats) == bool(n_ref)
+
+
+@pytest.mark.parametrize("pooled", [False, True], ids=["plain", "pooled"])
+@pytest.mark.parametrize("where", ["first", "middle", "last"])
+def test_flipped_literal_byte_admits_no_literal_of_the_chunk(where, pooled):
+    """(b) one flipped literal byte: DedupIntegrityException naming that
+    literal's fingerprint, and NO literal of the chunk is in the store
+    afterwards, those ahead of the bad one included; pooled buffers released."""
+    segments, _ = _recipe_case("all_literal")
+    wire, *_ = build_recipe(segments, SenderDedupIndex(), ident)
+    k = {"first": 0, "middle": len(segments) // 2, "last": len(segments) - 1}[where]
+    at = len(wire) - sum(len(d) for _, d in segments[k:]) + len(segments[k][1]) // 2
+    bad = bytearray(wire)
+    bad[at] ^= 0x01
+    store, pool = SegmentStore(), BufferPool()
+    with pytest.raises(DedupIntegrityException, match=segments[k][0].hex()):
+        parse_recipe(bytes(bad), store, ident, verify_literals=True, out_pool=pool if pooled else None)
+    assert store.mem_segment_count == 0 and all(fp not in store for fp, _ in segments)
+    counters = pool.counters()
+    assert counters["pool_outstanding"] == 0
+    assert counters["pool_recycled"] == (1 if pooled else 0)  # ident takes no buffer: only the output was drawn
+
+
+def test_flipped_literal_byte_releases_the_codecs_buffer_too():
+    """(b) with a codec that decodes into a second pooled buffer (blockpack):
+    both buffers are back in the pool after the refusal."""
+    encode, decode, out_len = _codec_pair("tpu")
+    segments, _ = _recipe_case("all_literal")
+    wire, *_ = build_recipe(segments, SenderDedupIndex(), encode)
+    bad = bytearray(wire)
+    bad[-1000] ^= 0x80  # blockpack of random bytes: the container ends in its literal blocks (the last one padded)
+    store, pool = SegmentStore(), BufferPool()
+    with pytest.raises(DedupIntegrityException, match="fingerprint mismatch"):
+        parse_recipe(bytes(bad), store, decode, verify_literals=True, out_pool=pool, blob_out_len=out_len)
+    assert store.mem_segment_count == 0
+    assert pool.counters()["pool_outstanding"] == 0 and pool.counters()["pool_recycled"] == 2
+
+
+@pytest.mark.parametrize("native", [True, False], ids=["native", "host_fallback"])
+def test_literal_verify_is_one_call_a_chunk(native, monkeypatch):
+    """(d) through restore(): one verify call for a chunk with literals, none
+    for one without; every literal entry verified. With the native library and
+    with the numpy form forced."""
+    from skyplane_tpu.native import datapath as native_dp
+    from skyplane_tpu.ops.pipeline import DataPathProcessor
+
+    if native and not native_dp.available():
+        pytest.skip("no native library on this host")
+    if not native:
+        monkeypatch.setattr(native_dp, "available", lambda: False)
+    data = np.random.default_rng(5).integers(0, 256, 300_000, dtype=np.uint8).tobytes()
+    proc = DataPathProcessor(codec_name="tpu", dedup=True)
+    index, store = SenderDedupIndex(), SegmentStore()
+    seen = []
+    for _ in range(2):  # the second time every segment is a REF
+        p = proc.process(data, index)
+        for fp, size in p.new_fingerprints:
+            index.add(fp, size)
+        header = WireProtocolHeader(
+            chunk_id="d" * 32, data_len=len(p.wire_bytes), raw_data_len=p.raw_len, codec=int(p.codec),
+            flags=int(ChunkFlags.RECIPE), fingerprint=p.fingerprint,
+        )
+        stats: dict = {}
+        out = proc.restore(p.wire_bytes, header, store=store, pooled=True, ref_stats=stats)
+        assert bytes(out.view) == data
+        out.release()
+        seen.append((p, stats))
+    (first, lit_stats), (second, ref_stats) = seen
+    assert first.n_ref_segments == 0 and first.n_segments > 10
+    assert lit_stats["literal_verify_calls"] == 1 and lit_stats["literal_segments_verified"] == first.n_segments
+    assert second.n_ref_segments == second.n_segments
+    assert ref_stats["literal_verify_calls"] == 0 and ref_stats["literal_segments_verified"] == 0
+    assert ref_stats["ref_segments_resolved"] == second.n_segments
+    assert proc.bufpool.counters()["pool_outstanding"] == 0
+
+
+def test_literal_pass_counters_are_served(tmp_path):
+    """(d) the three counters sit in the stable decode schema and move with a decoded chunk."""
+    for key in ("literal_pass_ns", "literal_segments_verified", "literal_verify_calls"):
+        assert DECODE_COUNTER_ZERO[key] == 0
+    r, store, ev, port = _mk_receiver(tmp_path, decode_workers=2)
+    try:
+        segs = [_seg(700), _seg(300), _seg(1100)]
+        assert _send_frames(port, [_literal_frame(segs)]) == ACK_BYTE
+        counters = r.decode_counters()
+        assert counters["literal_verify_calls"] == 1 and counters["literal_segments_verified"] == 3
+        assert 0 < counters["literal_pass_ns"] <= counters["decode_ns"]
+    finally:
+        r.stop_all()
+
+
+def test_decoding_3000_literals_calls_the_fingerprint_kernel_once(monkeypatch):
+    """The guard: the number of native fingerprint calls a decode makes does
+    not grow with the number of segments (per-segment calls each hand the
+    interpreter lock over; at 3,000 a chunk that was most of the sink's decode)."""
+    from skyplane_tpu.native import datapath as native_dp
+
+    if not native_dp.available():
+        pytest.skip("no native library on this host")
+    blob = np.random.default_rng(9).integers(0, 256, 3000 * 640, dtype=np.uint8)
+    ends = np.arange(1, 3001, dtype=np.int64) * 640
+    from skyplane_tpu.ops.fingerprint import segment_fingerprints_host_batch
+
+    fps = segment_fingerprints_host_batch(blob, ends)
+    raw = blob.tobytes()
+    segments = [(fp, raw[e - 640 : e]) for fp, e in zip(fps, ends.tolist())]
+    wire, *_ = build_recipe(segments, SenderDedupIndex(), ident)
+    calls = []
+    real = native_dp.segment_fp_lanes
+
+    def counted(data, seg_ends):
+        calls.append(len(seg_ends))
+        return real(data, seg_ends)
+
+    monkeypatch.setattr(native_dp, "segment_fp_lanes", counted)
+    stats: dict = {}
+    assert parse_recipe(wire, SegmentStore(), ident, verify_literals=True, ref_stats=stats) == raw
+    assert len(calls) <= 2 and sum(calls) == 3000, calls
+    assert stats["literal_segments_verified"] == 3000 and stats["literal_verify_calls"] == 1
+
+
+# sender side, pinned at the parent of PR 30 (7b483d0): blake2b-128 of what process() puts on the wire
+SENDER_WIRE_DIGESTS = {
+    "tpu": "15309385072878db9a483bf0771ec945",
+    "none": "a246691e777b06fccdc951cb4915b21e",
+}
+
+
+@pytest.mark.parametrize("codec_name", sorted(SENDER_WIRE_DIGESTS))
+def test_sender_side_bytes_are_the_parents(codec_name):
+    """The literal pass is the receiver's alone: recipe layout, container
+    layout and the frame's payload are byte for byte what the parent commit
+    sent for the same input (zeros, a constant run and random bytes, so the
+    container holds every tag), so old and new gateways decode each other."""
+    import hashlib
+
+    from skyplane_tpu.ops.pipeline import DataPathProcessor
+
+    r = np.random.default_rng(3030)
+    data = r.integers(0, 256, 200_000, dtype=np.uint8).tobytes() + bytes(40_000) + b"\x07" * 30_000
+    data += r.integers(0, 256, 1234, dtype=np.uint8).tobytes()
+    p = DataPathProcessor(codec_name=codec_name, dedup=True).process(data + data[:100_000], SenderDedupIndex())
+    assert hashlib.blake2b(p.wire_bytes, digest_size=16).hexdigest() == SENDER_WIRE_DIGESTS[codec_name]

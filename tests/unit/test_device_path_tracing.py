@@ -426,6 +426,10 @@ REF_PATH_KEYS = (
     ("profile/decode", "ref_resolve_ns"),
     ("profile/decode", "ref_segments_resolved"),
     ("profile/decode", "ref_bytes_resolved"),
+    # the literal pass's (PR 30): served by the same route, zero where no recipe was parsed
+    ("profile/decode", "literal_pass_ns"),
+    ("profile/decode", "literal_segments_verified"),
+    ("profile/decode", "literal_verify_calls"),
 )
 
 

@@ -79,6 +79,24 @@ def decode(pool, n, risky):
     ) == []
 
 
+@pytest.mark.parametrize("acquire", ["buf = pool.acquire(n)", "buf = None\n    if want:\n        buf = pool.acquire(n)"])
+def test_guarded_release_in_finally_is_clean(acquire):
+    # `finally: if buf is not None: release(buf)` (parse_recipe's second pooled buffer): the branch edge
+    # out of the finally's last statement keeps what it implies on the re-raise too
+    src = f"""
+def decode(pool, n, risky, want):
+    {acquire}
+    try:
+        risky(buf)
+    finally:
+        if buf is not None:
+            pool.release(buf)
+"""
+    assert res_rules(src) == []
+    # and the guard is not a blanket pardon: with no release under it the leak still fires
+    assert res_rules(src.replace("pool.release(buf)", "pass")) == ["resource-leak-on-path"]
+
+
 def test_release_in_exhaustive_handler_is_clean():
     # `except BaseException: release; raise` covers the exception path fully —
     # the dispatch node must not leak an unmatched-exception edge outward

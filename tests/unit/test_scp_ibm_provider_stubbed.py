@@ -14,6 +14,18 @@ import types
 import pytest
 
 
+@pytest.fixture(autouse=True)
+def _modules_imported_against_a_fake_sdk_do_not_outlive_it():
+    """The tests below import the S3-based backends with a fake boto3 in sys.modules. Left loaded, those modules
+    make ``StorageInterface.create("aws:...")`` succeed for whatever test the same worker runs next
+    (test_obj_store_interfaces.py expects MissingDependencyException on this boto3-less image)."""
+    before = set(sys.modules)
+    yield
+    for name in set(sys.modules) - before:
+        if name.startswith("skyplane_tpu.obj_store."):
+            del sys.modules[name]
+
+
 # ---------- SCP (HMAC-signed REST over requests) ----------
 
 

@@ -131,6 +131,100 @@ def test_truncated_tag_region_rejected():
         blockpack.decode_container(enc[:21])
 
 
+# ---- the literal pass in one sweep (PR 30): the same contract for every input type and codec
+
+
+def _as_input(kind, m: bytes):
+    return m if kind == "bytes" else memoryview(bytearray(m))
+
+
+@pytest.mark.parametrize("kind", ["bytes", "memoryview"])
+@pytest.mark.parametrize("codec_name", ["none", "tpu"])
+@pytest.mark.parametrize("pooled", [False, True], ids=["plain", "pooled"])
+def test_recipe_fuzz_stays_in_contract_for_every_input(kind, codec_name, pooled):
+    """Mutated recipes raise CodecException / DedupIntegrityException and never
+    anything else, whether the payload arrives as bytes or as a memoryview, with
+    and without the pooled buffers; no pooled buffer is left out."""
+    from skyplane_tpu.exceptions import CodecException, DedupIntegrityException
+    from skyplane_tpu.ops.bufpool import BufferPool
+    from skyplane_tpu.ops.codecs import get_codec
+    from skyplane_tpu.ops.dedup import PooledChunk
+
+    spec = get_codec(codec_name)
+    segs = []
+    for n in (3000, 1, 700, 2049):
+        b = rng.integers(0, 256, n, dtype=np.uint8).tobytes()
+        segs.append((segment_fingerprint_host(b), b))
+    wire, *_ = build_recipe(segs + segs[:1], SenderDedupIndex(), spec.encode)
+    pool = BufferPool()
+    refused = 0
+    for m in _mutations(wire, 90) + [wire]:
+        try:
+            got = parse_recipe(
+                _as_input(kind, m), SegmentStore(), spec.decode, verify_literals=True,
+                out_pool=pool if pooled else None, blob_out_len=spec.decode_out_len,
+            )
+            if isinstance(got, PooledChunk):
+                got.release()
+        except (CodecException, DedupIntegrityException):
+            refused += 1
+        assert pool.counters()["pool_outstanding"] == 0
+    assert 0 < refused <= 90, "the unmutated recipe has to pass and some mutation has to be refused"
+
+
+@pytest.mark.parametrize("kind", ["bytes", "memoryview"])
+def test_blockpack_container_fuzz_every_input(kind):
+    from skyplane_tpu.exceptions import CodecException
+
+    data = rng.integers(0, 256, 20000, dtype=np.uint8).tobytes() + bytes(12000) + b"\x05" * 3000
+    base = blockpack.encode_container(data)
+    assert blockpack.decode_container(_as_input(kind, base)) == data
+    out = np.empty(blockpack.padded_len(len(data)), np.uint8)
+    for m in _mutations(base):
+        for buf in (None, out):
+            try:
+                blockpack.decode_container(_as_input(kind, m), buf)
+            except CodecException:
+                pass
+
+
+def _hand_container(tags, literals: bytes, n_raw: int, block_log2: int = 8) -> bytes:
+    head = blockpack.MAGIC + struct.pack("<BBQQ", blockpack.VERSION, block_log2, n_raw, len(literals))
+    return head + blockpack._pack_tags(np.asarray(tags, np.uint8)) + literals
+
+
+@pytest.mark.parametrize("native", [True, False], ids=["native", "host_fallback"])
+def test_decode_container_into_a_callers_buffer(native, monkeypatch):
+    """(e) decoding into memory the caller owns gives what decoding into a
+    fresh array gives, for zero / constant / literal / invalid-tag blocks and
+    a last block that is padded; the view is over the caller's array; a buffer
+    shorter than the padded length is refused."""
+    from skyplane_tpu.exceptions import CodecException
+    from skyplane_tpu.native import datapath as native_dp
+
+    if native and not native_dp.available():
+        pytest.skip("no native library on this host")
+    if not native:
+        monkeypatch.setattr(native_dp, "available", lambda: False)
+    lit = rng.integers(1, 255, 256, dtype=np.uint8).tobytes()
+    want = bytes(256) + b"\x09" * 256 + lit + bytes(256) + lit[:100]  # zero, const, literal, invalid tag (a zero block), padded literal
+    container = _hand_container([0, 1, 2, 3, 2], b"\x09" + lit + lit[:100] + bytes(156), n_raw=len(want))
+    fresh = blockpack.decode_container(container)
+    assert fresh == want and isinstance(fresh, memoryview)
+    out = np.full(4096, 0xEE, np.uint8)  # longer than needed, and dirty
+    got = blockpack.decode_container(container, out)
+    assert got == want
+    assert got.obj is out and bytes(out[: len(want)]) == want, "the blocks were not written into the caller's array"
+    assert bytes(out[blockpack.padded_len(len(want), 256) :]) == b"\xee" * (4096 - 1280), "wrote past the padded length"
+    exact = np.empty(blockpack.padded_len(len(want), 256), np.uint8)
+    assert blockpack.decode_container(container, exact) == want
+    with pytest.raises(CodecException, match="output buffer"):
+        blockpack.decode_container(container, np.empty(len(want), np.uint8))  # n_raw bytes are not enough: the kernel writes whole blocks
+    real = blockpack.encode_container(want + want)
+    assert blockpack.decode_container(real, np.empty(blockpack.padded_len(2 * len(want)), np.uint8)) == want + want
+    assert blockpack.decode_container(blockpack.encode_container(b""), out) == b""
+
+
 # ----------------------------------------------------------------------------
 # Injector-driven recovery at the receiver framing boundary
 # (docs/fault-injection.md). A live GatewayReceiver, real sockets, no TLS.
