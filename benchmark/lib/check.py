@@ -141,12 +141,20 @@ def passed(compared: Dict[str, dict]) -> bool:
     return all(c["value"] <= c["limit"] for c in compared.values())
 
 
-def compute_reference(obs: Observed) -> None:
-    """The plain reference over every row the run sent, the set-up row
+def compute_reference(obs: Observed, pool=None) -> dict:
+    """The plain reference over every row the run sent, the set-up rows
     included, at the configuration's cut: no row's ends or fingerprints reach
-    the other comparisons unchecked."""
+    the other comparisons unchecked. A run hands its ``lib.refpool`` pool,
+    whose workers make each row again themselves; without one the rows come
+    from ``obs.row_bytes`` and are cut here, one after the other. Returns
+    what the pool says of its work."""
+    if pool is not None:
+        rows, info = pool.rows(s.index for s in obs.sent)
+        obs.reference_rows.update(rows)
+        return info
     for s in obs.sent:
         obs.reference_rows[s.index] = reference.cdc_and_fingerprints(obs.row_bytes(s.index), *obs.cdc)
+    return {"rows": len(obs.sent), "workers": 0}
 
 
 # ---- controls: one stated guarantee broken, in the program's place ----

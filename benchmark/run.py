@@ -9,7 +9,10 @@ threads on loopback, built at the configuration's values. Chunks go in through
 log says ``complete``. A closed loop keeps the cell's ``in_flight_chunks`` in
 flight; the measured span runs from chunk completion to chunk completion
 (``lib/span.py``). What decides ``correct`` (``lib/check.py``) runs once the
-window has closed. The last line of stdout is the result as one JSON object.
+window has closed, its reference in worker processes (``lib/refpool.py``)
+that are started first of all and do nothing until then. The last line of
+stdout is the result as one JSON object, printed before the gateways are
+stopped and the data removed.
 
 Everything that belongs to one cell, configuration, metric or kind of content
 is a file found by its name in BENCHMARK.json: ``workloads/<cell>.json``,
@@ -36,6 +39,7 @@ import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 import threading  # noqa: E402
+from concurrent.futures import ThreadPoolExecutor  # noqa: E402
 from pathlib import Path  # noqa: E402
 
 HERE = Path(__file__).resolve().parent
@@ -43,6 +47,7 @@ ROOT = HERE.parent
 sys.path[:0] = [str(HERE), str(ROOT)]
 
 DEADLINE_S = 345.0  # a run exits within 360 s
+TEARDOWN_S = 60.0  # stopping the gateways and removing the data, once the result is printed
 LATE_S = 60.0  # how long past the close an answer is waited for
 STAGGER_S = 1.0  # between the posts that fill the pipeline
 POLL_S = 0.1  # small against a row's seconds; completion times are the sink's own stamps
@@ -51,6 +56,11 @@ MARK = "bench:mark"
 
 class GatewayFault(RuntimeError):
     """A gateway put an error on its /errors list."""
+
+
+class SetupUnsound(RuntimeError):
+    """The set-up did not load every program the window will run: the run
+    ends with no result."""
 
 
 def log(msg: str) -> None:
@@ -91,7 +101,11 @@ class Cell:
         self.entry = entries[name]
         self.workload = load_json(HERE / "workloads" / f"{name}.json")
         self.config = load_json(HERE / "configs" / f"{self.entry['config']}.json")
-        self.generator = load_module(HERE / "generators" / f"{self.workload['generator']}.py").Generator
+        self.generator_file = HERE / "generators" / f"{self.workload['generator']}.py"
+        self.generator = load_module(self.generator_file).Generator
+        self.burst = self.workload["traffic"].get("setup_burst_chunks")  # None: the set-up chunk alone, as ever
+        if self.burst is not None and (type(self.burst) is not int or self.burst < 2):
+            raise SystemExit(f"workloads/{name}.json: setup_burst_chunks is {self.burst!r}; it is a whole number of 2 or more, or left out")
 
     def metrics(self, group: str):
         """The metrics of ``group`` this cell reports, each with its file."""
@@ -120,18 +134,52 @@ def read_metric(spec: dict, facts: dict, run: dict):
     return num / den * ratio["scale"]
 
 
-def arm_deadline(seconds: float, tmp: Path) -> None:
+def leave(rc: int) -> None:
+    """End the process now, whatever its threads are doing."""
+    sys.stdout.flush()
+    sys.stderr.flush()
+    os._exit(rc)
+
+
+def arm_deadline(seconds: float, tmp: Path, pool) -> threading.Timer:
     """A hung device call cannot be interrupted: at the deadline say so,
-    remove the data and leave, with no result line."""
+    end the reference's workers, remove the data and leave, with no result
+    line."""
 
     def fire():
         log(f"FAIL: the run's deadline of {seconds:.0f}s was reached")
+        pool.kill()
         shutil.rmtree(tmp, ignore_errors=True)
-        os._exit(3)
+        leave(3)
 
     timer = threading.Timer(seconds, fire)
     timer.daemon = True
     timer.start()
+    return timer
+
+
+def tear_down(gateways, tmp: Path, limit: float) -> bool:
+    """Stop the gateways and remove the run's data, within ``limit`` seconds:
+    the result is printed by now, and a stop that hangs cannot take it back."""
+
+    def work():
+        try:
+            for gw in gateways:
+                if gw is not None:
+                    gw.stop()
+        finally:
+            shutil.rmtree(tmp, ignore_errors=True)
+
+    t = time.monotonic()
+    thread = threading.Thread(target=work, name="bench-teardown", daemon=True)
+    thread.start()
+    thread.join(max(limit, 0.0))
+    if thread.is_alive():
+        log(f"teardown passed its limit of {limit:.0f}s: leaving it")
+        shutil.rmtree(tmp, ignore_errors=True)
+        return False
+    log(f"teardown: gateways stopped and data removed in {time.monotonic() - t:.1f}s")
+    return True
 
 
 def annotate_device_spans():
@@ -222,11 +270,30 @@ class ChunkSource(threading.Thread):
 def main(argv=None) -> int:
     args = parse_args(argv)
     cell = Cell(args.workload)
+    if args.rehearse_scale > 0:
+        os.environ.setdefault("SKYPLANE_TPU_FORCE_ACCEL_PATH", "1")
+
+    # the reference's workers first of all: no jax in this process yet and no
+    # thread; they wait on their stdin until the window has closed
+    from lib import refpool
+
+    cfg = cell.config["transfer"]
+    pool = refpool.ReferencePool(
+        cell.generator_file, cell.workload["content"], args.seed, max(args.rehearse_scale, 1),
+        (cfg["cdc_min_bytes"], cfg["cdc_avg_bytes"], cfg["cdc_max_bytes"]), refpool.pool_size(),
+    )
+    log(f"reference pool: {len(pool.procs)} workers started, pids {pool.pids()} (jax imported: {'jax' in sys.modules})")
+    try:
+        return run_cell(args, cell, pool)
+    finally:
+        pool.close()
+
+
+def run_cell(args, cell: Cell, pool) -> int:
     cfg = cell.config["transfer"]
     traffic = cell.workload["traffic"]
+    burst = cell.burst
     rehearsal = args.rehearse_scale > 0
-    if rehearsal:
-        os.environ.setdefault("SKYPLANE_TPU_FORCE_ACCEL_PATH", "1")
     phases = {}
 
     def phase(name: str, since: float) -> float:
@@ -263,10 +330,13 @@ def main(argv=None) -> int:
     t = phase("native_library_s", t)
 
     tmp = Path(tempfile.mkdtemp(prefix="skyplane_bench_"))
-    arm_deadline(DEADLINE_S, tmp)
+    deadline = arm_deadline(DEADLINE_S, tmp, pool)
+    deadline_at = time.monotonic() + DEADLINE_S
     source = sink = None
     tracing = has_rate = correct = False
+    unsound = None
     compared: dict = {}
+    result: dict = {}
     try:
         src_dir, dst_dir = tmp / "source", tmp / "sink"
         src_dir.mkdir()
@@ -329,6 +399,19 @@ def main(argv=None) -> int:
             return out
 
         pending: dict = {}
+        setup_rows: set = set()  # indices of the set-up chunk and the set-up bursts' chunks
+
+        def land(made_now: list) -> None:
+            """Post these chunks at once and wait until the sink calls every
+            one complete: rows of the set-up, not of the window."""
+            for made in made_now:
+                s = post(made)
+                pending[s.chunk_id] = s
+                setup_rows.add(s.index)
+            while pending:
+                time.sleep(POLL_S)
+                poll(pending)
+
         t0 = first = setup_seconds = None
         at_t0: dict = {}
         feed_wait_s = 0.0
@@ -336,15 +419,28 @@ def main(argv=None) -> int:
         try:
             # ---- set-up chunk: the base of the cell's content, and the row
             # that loads both device programs at the timed shape
-            s = post(setup_made)
-            pending[s.chunk_id] = s
-            while pending:
-                time.sleep(POLL_S)
-                poll(pending)
+            land([setup_made])
             t = phase("setup_chunk_landed_s", t)
 
-            # ---- fill the pipeline; the window opens at the first completion
             chunks.start()
+            if burst:
+                # ---- set-up bursts: the first loads the programs of a window
+                # of several rows, the second proves that nothing is left to load
+                counters = source.get("profile/compression")
+                for nth in (1, 2):
+                    made_now = [chunks.ready.get() for _ in range(burst)]
+                    t_burst = time.monotonic()
+                    land(made_now)
+                    was, counters = counters, source.get("profile/compression")
+                    rows, windows, compiles = (int(counters.get(k, 0)) - int(was.get(k, 0)) for k in ("batch_rows", "batch_windows", "xla_compiles"))
+                    log(f"set-up burst {nth}: {burst} chunks landed in {time.monotonic() - t_burst:.2f}s, {windows} windows for {rows} rows, {compiles} compiles")
+                    if nth == 1 and rows <= windows:
+                        raise SetupUnsound(f"burst 1 of {burst} chunks ran no window of more than one row ({windows} windows for {rows} rows)")
+                    if nth == 2 and compiles > 0:
+                        raise SetupUnsound(f"burst 2 of {burst} chunks compiled {compiles} programs: a window after it would compile too")
+                t = phase("setup_bursts_s", t)
+
+            # ---- fill the pipeline; the window opens at the first completion
             if args.trace:
                 trace_dir = tmp / "trace"
                 opts = jax.profiler.ProfileOptions()
@@ -397,23 +493,24 @@ def main(argv=None) -> int:
             jax.profiler.stop_trace()
             tracing = False
         mem_peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devices[: cell.entry["chips"]])
-        window = [s for s in sent if s.index >= 1 and s is not first]
+        window = [s for s in sent if s.index not in setup_rows and s is not first]
         times = [s.completed_at if s.completed_at is not None else float("inf") for s in window]
         measured = span_lib.measured_span(times, t0, args.seconds) if t0 is not None else None
         n_counted = len(measured.counted) if measured else sum(1 for x in times if t0 is not None and x <= t0 + args.seconds)
         log(f"window closed: {n_counted} completions after t0" + (f", span {measured.seconds:.3f}s" + (" (closed at t0 + seconds: trailing stall)" if measured.stalled else "") if measured else ": no rate"))
 
-        # the reference runs on the host over every row that was sent, while
-        # the device finishes the chunks still in flight; then each is waited for
+        # the reference's workers make every row that was sent, while the
+        # device finishes the chunks still in flight and this thread waits for
+        # each, then reads what the timed path produced (and the trace)
         obs = check.Observed(
             sent=sent, file_digests={}, device_rows={},
             row_bytes=lambda i: generator.chunk(i) if i else generator.setup_chunk(),
             counters={}, frames=[], gateway_errors=0, as_built_departures=[], cdc=cdc,
             wire_codec_id=int(get_codec(cfg["compress"]).codec_id),
         )
-        t_ref = time.monotonic()
-        check.compute_reference(obs)
-        reference_s = time.monotonic() - t_ref
+        t_ref, closed_at = time.monotonic(), time.time()
+        background = ThreadPoolExecutor(max_workers=1, thread_name_prefix="bench-reference")
+        making_reference = background.submit(check.compute_reference, obs, pool)
         late_until = time.monotonic() + LATE_S
         while pending and time.monotonic() < late_until:
             time.sleep(POLL_S)
@@ -441,6 +538,15 @@ def main(argv=None) -> int:
         obs.gateway_errors = len(pair.errors(source)) + len(pair.errors(sink))
         events = source.get("events", params={"since": 0})["events"]
         obs.as_built_departures = departures_from(cfg, (source, sink))
+        if args.trace and measured:
+            record = trace_lib.extract(trace_lib.find_xplane(str(trace_dir)), rehearsal=rehearsal)  # no device plane: raises, no result
+        reference = making_reference.result()
+        background.shutdown()
+        pool.close()
+        reference["workers_ended"] = [p.returncode for p in pool.procs]
+        reference_s = time.monotonic() - t_ref
+        reference["started_after_close_s"] = round(reference.pop("first_started_at") - closed_at, 3)
+        log(f"reference: {reference}")
         compared = check.compare(obs)
         checks_passed = check.passed(compared)
         control = None
@@ -471,8 +577,7 @@ def main(argv=None) -> int:
                     facts[f"{side}_after_t0.{k}"] = v - at_t0[side][k]
         breakdown = None
         device = {"platform": platform, "kind": kind, "count": len(devices), "memory_peak_bytes": int(mem_peak)}
-        if args.trace and measured:
-            record = trace_lib.extract(trace_lib.find_xplane(str(trace_dir)), rehearsal=rehearsal)  # no device plane: raises, no result
+        if record is not None:
             offset = trace_lib.clock_offset_ns(record, MARK, mark_wall_ns)
             lo, hi = measured.start * 1e9 - offset, measured.end * 1e9 - offset
             busy = trace_lib.busy_ns(record, lo, hi)
@@ -522,7 +627,8 @@ def main(argv=None) -> int:
             "gaps_s": [round(b - a, 4) for a, b in zip([t0] + [s.completed_at for s in counted], [s.completed_at for s in counted])] if t0 else [],
             "stalled": bool(measured and measured.stalled), "phases": phases,
             "reference_s": round(reference_s, 3), "drained_s": round(drained_s, 3), "compile_cache": cache_dir,
-            "reference_rows": len(obs.reference_rows),
+            "reference_rows": len(obs.reference_rows), "rows_sent": len(sent), "setup_rows": len(setup_rows), "reference": reference,
+            "device_windows_after_t0": {k: facts.get(f"source_after_t0.batch_{k}") for k in ("rows", "windows", "padded_rows")},
         }
         if control is not None:
             result["control"] = control
@@ -530,22 +636,36 @@ def main(argv=None) -> int:
         log(f"span {facts.get('span.seconds')} s, {len(counted)} completions; reference {reference_s:.1f}s, all landed after {drained_s:.1f}s")
         if control is not None:
             log(f"control {control['name']}: correct={control['correct']} " + " ".join(f"{k}={v['value']}" for k, v in control["compared"].items() if v["value"] > v["limit"]))
+    except SetupUnsound as err:
+        unsound = str(err)
+    except BaseException:
+        tear_down((source, sink), tmp, TEARDOWN_S)
+        raise
     finally:
         if tracing:
             jax.profiler.stop_trace()
-        for gw in (source, sink):
-            if gw is not None:
-                gw.stop()
-        shutil.rmtree(tmp, ignore_errors=True)
-    if not has_rate:
+    # ---- the result first, the teardown after it
+    if unsound:
+        log(f"FAIL: the set-up is not sound: {unsound}: no result")
+        rc = 5
+    elif not has_rate:
         log("FAIL: fewer than two completions after t0 inside the window: no rate")
-    for name, c in compared.items():
-        print(f"compared {name}: {c['value']} (limit {c['limit']})", file=sys.stderr)
-    print(f"correct: {correct}", file=sys.stderr, flush=True)
-    if not has_rate and not rehearsal:
-        return 4
-    print(json.dumps(result), flush=True)
-    return 1 if rehearsal else 0
+        rc = 1 if rehearsal else 4
+    else:
+        rc = 1 if rehearsal else 0
+    if rc in (0, 1):
+        print(json.dumps(result), flush=True)
+        log("result line printed")
+    deadline.cancel()  # what is left is bounded by the teardown's own limit
+    stopped = tear_down((source, sink), tmp, min(TEARDOWN_S, deadline_at - time.monotonic()))
+    if not unsound:  # the numbers compared, each beside its limit, are stderr's last lines
+        for name, c in compared.items():
+            print(f"compared {name}: {c['value']} (limit {c['limit']})", file=sys.stderr)
+        print(f"correct: {correct}", file=sys.stderr, flush=True)
+    if not stopped:
+        pool.kill()
+        leave(rc)
+    return rc
 
 
 if __name__ == "__main__":

@@ -1,0 +1,325 @@
+"""The set-up bursts, the pooled reference and the order result line ->
+teardown: whole rehearsal runs on the CPU backend at chunks 64 times smaller,
+and the pool alone on both generators."""
+
+import ast
+import json
+import os
+import subprocess
+import sys
+import threading
+import time
+from pathlib import Path
+
+import numpy as np
+import pytest
+from lib import check, pair, reference, refpool
+
+BENCH = Path(__file__).resolve().parents[1]
+SPEC = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+CDC = (4096, 16384, 65536)
+CONTENT = {
+    "random_files": {"file_bytes": 60763889},
+    "snapshot_delta": {"region_bytes": 67108864, "extent_bytes": 524288, "extents_per_region": 4},
+}
+SEED = 4000000007
+
+
+def alive(pid: int) -> bool:
+    try:
+        return Path(f"/proc/{pid}/stat").read_text().rsplit(")", 1)[1].split()[0] != "Z"
+    except OSError:
+        return False
+
+
+def gone(pids, within: float = 10.0) -> bool:
+    until = time.monotonic() + within
+    while any(alive(p) for p in pids) and time.monotonic() < until:
+        time.sleep(0.1)
+    return not any(alive(p) for p in pids)
+
+
+def pool_pids(err: str):
+    line = next(l for l in err.splitlines() if "reference pool:" in l and "pids" in l)
+    return json.loads(line.split("pids ")[1].split(" (")[0]), line
+
+
+def make_generator(name: str):
+    import run
+
+    return run.load_module(BENCH / "generators" / f"{name}.py").Generator(CONTENT[name], SEED, 64)
+
+
+def make_pool(name: str, workers: int) -> refpool.ReferencePool:
+    return refpool.ReferencePool(BENCH / "generators" / f"{name}.py", CONTENT[name], SEED, 64, CDC, workers)
+
+
+# ---- the pool alone
+
+
+@pytest.mark.parametrize("workers", [1, 3])
+@pytest.mark.parametrize("name", sorted(CONTENT))
+def test_the_pooled_reference_equals_the_serial_one_row_for_row(name, workers):
+    generator = make_generator(name)
+    indices = [0, 1, 2, 5, 11, 12, 40]
+    pool = make_pool(name, workers)
+    try:
+        rows, info = pool.rows(indices)
+    finally:
+        pool.close()
+    assert sorted(rows) == indices and info["rows"] == len(indices) and info["workers"] == workers
+    for i in indices:
+        ends, fps = reference.cdc_and_fingerprints(generator.chunk(i) if i else generator.setup_chunk(), *CDC)
+        assert np.array_equal(rows[i][0], ends) and rows[i][0].dtype == np.int64 and rows[i][1] == fps, i
+    assert gone(pool.pids(), 1.0) and all(p.returncode == 0 for p in pool.procs)
+
+
+def observed_from_pool(name: str, n_rows: int):
+    """An Observed whose device rows are the serial reference's and whose
+    reference rows are the pool's."""
+    generator = make_generator(name)
+    row = lambda i: generator.chunk(i) if i else generator.setup_chunk()  # noqa: E731
+    sent = [
+        check.Sent(index=i, chunk_id=f"c{i}", key=str(i), digest="", n_bytes=generator.chunk_bytes, src_path=Path("."), dst_path=Path("."),
+                   posted_at=0.0, completed_at=1.0)
+        for i in range(n_rows)
+    ]
+    device_rows = {i: reference.cdc_and_fingerprints(row(i), *CDC) for i in range(n_rows)}
+    segments, fewest, _ = check.expected_refs(device_rows, list(range(n_rows)))
+    obs = check.Observed(
+        sent=sent, file_digests={i: "" for i in range(n_rows)}, device_rows=device_rows, row_bytes=row,
+        counters={"batch_rows": n_rows, "stage_failures": 0, "segments": segments, "ref_segments": fewest},
+        frames=[{"chunk_id": s.chunk_id, "codec": 3, "raw_bytes": s.n_bytes, "wire_bytes": 1} for s in sent],
+        gateway_errors=0, as_built_departures=[], cdc=CDC, wire_codec_id=3,
+    )
+    pool = make_pool(name, 2)
+    try:
+        info = check.compute_reference(obs, pool)
+    finally:
+        pool.close()
+    assert info["rows"] == n_rows and sorted(obs.reference_rows) == list(range(n_rows))
+    return obs
+
+
+def over(compared):
+    return {k for k, v in compared.items() if v["value"] > v["limit"]}
+
+
+@pytest.mark.parametrize("fault, has_to_fail", [("fingerprint", "rows_fingerprints_differ"), ("end", "rows_ends_differ")])
+def test_a_fault_planted_in_the_last_row_is_caught_through_the_pool(fault, has_to_fail):
+    obs = observed_from_pool("snapshot_delta", 9)
+    assert over(check.compare(obs)) == set()
+    ends, fps = obs.device_rows[8]
+    if fault == "fingerprint":
+        obs.device_rows[8] = (ends, fps[:-1] + [bytes([fps[-1][0] ^ 1]) + fps[-1][1:]])
+    else:
+        obs.device_rows[8] = (np.concatenate([ends[:-2], [ends[-2] - 1], ends[-1:]]), fps)
+    assert has_to_fail in over(check.compare(obs))
+
+
+def test_workers_do_nothing_until_asked_and_end_when_the_run_goes():
+    """A worker waits on its stdin; a parent that leaves through os._exit,
+    as the deadline does, takes its workers with it."""
+    code = (
+        "import sys, os, time; sys.path[:0] = [%r]\n"
+        "from pathlib import Path\n"
+        "from lib import refpool\n"
+        "pool = refpool.ReferencePool(Path(%r), %r, 1, 64, %r, 2)\n"
+        "pool.procs[0].stdin.write(b'3\\n'); pool.procs[0].stdin.flush()  # one worker is mid-row when the parent goes\n"
+        "print(pool.pids(), flush=True); time.sleep(0.3); os._exit(3)\n"
+    ) % (str(BENCH), str(BENCH / "generators" / "snapshot_delta.py"), CONTENT["snapshot_delta"], list(CDC))
+    done = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True, timeout=60)
+    assert done.returncode == 3, done.stderr
+    assert gone(json.loads(done.stdout.strip().splitlines()[-1]))
+
+
+def test_the_reference_and_its_workers_import_numpy_and_nothing_of_the_program():
+    allowed = set(sys.stdlib_module_names) | {"numpy", "reference"}
+    for path in [BENCH / "lib" / "reference.py", BENCH / "lib" / "refpool.py", *sorted((BENCH / "generators").glob("*.py"))]:
+        tree = ast.parse(path.read_text())
+        names = {a.name.split(".")[0] for n in ast.walk(tree) if isinstance(n, ast.Import) for a in n.names}
+        names |= {n.module.split(".")[0] for n in ast.walk(tree) if isinstance(n, ast.ImportFrom) and n.module}
+        assert names <= allowed, (path.name, names - allowed)
+
+
+def test_pool_size_is_half_the_cores_and_at_most_eight(monkeypatch):
+    for cores, size in [(None, 1), (1, 1), (2, 1), (13, 6), (16, 8), (96, 8)]:
+        monkeypatch.setattr(os, "cpu_count", lambda cores=cores: cores)
+        assert refpool.pool_size() == size
+
+
+# ---- whole rehearsal runs
+
+
+def with_cell(monkeypatch, traffic: dict, name: str = "test-cell.burst"):
+    """Serve run.py a cell that is in no file: the first cell's configuration
+    and content under ``traffic``."""
+    import run
+
+    base = SPEC["workloads"][0]
+    real = run.load_json
+
+    def load_json(path: Path) -> dict:
+        if path.name == "BENCHMARK.json":
+            spec = real(path)
+            spec["workloads"].append(dict(base, name=name))
+            for m in spec["end_to_end"] + spec["per_layer"]:
+                if "workloads" in m:
+                    m["workloads"].append(name)
+            return spec
+        if path.name == f"{name}.json":
+            return dict(real(path.with_name(f"{base['name']}.json")), name=name, traffic=traffic)
+        return real(path)
+
+    monkeypatch.setattr(run, "load_json", load_json)
+    return name
+
+
+def rehearse(capsys, cell, trace="0", seconds="3"):
+    import run
+
+    rc = run.main(["--workload", cell, "--seed", str(SEED), "--seconds", seconds, "--trace", trace, "--rehearse-scale", "64"])
+    captured = capsys.readouterr()
+    lines = [line for line in captured.out.strip().splitlines() if line.startswith("{")]
+    return rc, (json.loads(lines[-1]) if lines else None), captured.err
+
+
+def line_no(err: str, text: str) -> int:
+    return next(i for i, l in enumerate(err.splitlines()) if text in l)
+
+
+def test_a_burst_workload_forms_its_bursts_before_t0_and_holds_their_rows_to_the_reference(capsys, monkeypatch):
+    cell = with_cell(monkeypatch, {"in_flight_chunks": 4, "setup_burst_chunks": 8})
+    rc, result, err = rehearse(capsys, cell, trace="1")
+    assert rc == 1 and result["rehearsal"]["checks_passed"] is True, err[-3000:]
+    run = result["run"]
+    assert run["setup_rows"] == 1 + 2 * 8 and "setup_bursts_s" in run["phases"]
+    assert run["reference_rows"] == run["rows_sent"] >= run["setup_rows"] + 1 + run["completions"]
+    assert len(run["gaps_s"]) == run["completions"]  # the window's list holds no burst row
+    assert line_no(err, "set-up burst 1: 8 chunks") < line_no(err, "set-up burst 2: 8 chunks") < line_no(err, "t0: first window chunk")
+    assert "0 compiles" in err.splitlines()[line_no(err, "set-up burst 2")]
+    assert result["rehearsal"]["values"]["compiles_after_t0"]["value"] == 0
+    assert run["reference"]["started_after_close_s"] >= 0  # no reference work before the window closed
+    assert line_no(err, "window closed") < line_no(err, "reference:") < line_no(err, "result line printed") < line_no(err, "teardown:")
+    assert err.splitlines()[-1] == "correct: False" and run["reference"]["workers_ended"] == [0] * run["reference"]["workers"]
+    assert gone(pool_pids(err)[0], 1.0)
+
+
+@pytest.mark.parametrize(
+    "plant, says",
+    [
+        (lambda body, n: body.update(xla_compiles=body.get("xla_compiles", 0) + n), "burst 2 of 8 chunks compiled"),
+        (lambda body, n: body.update(batch_windows=body["batch_rows"]), "burst 1 of 8 chunks ran no window of more than one row"),
+    ],
+    ids=["second_burst_compiles", "first_burst_is_lone_rows"],
+)
+def test_a_set_up_that_is_not_sound_ends_the_run_with_no_result_and_says_why(capsys, monkeypatch, plant, says):
+    cell = with_cell(monkeypatch, {"in_flight_chunks": 2, "setup_burst_chunks": 8})
+    real, asked = pair.LocalGateway.get, [0]
+
+    def get(self, route, **kw):
+        body = real(self, route, **kw)
+        if route == "profile/compression":
+            asked[0] += 1
+            plant(body, asked[0])
+        return body
+
+    monkeypatch.setattr(pair.LocalGateway, "get", get)
+    rc, result, err = rehearse(capsys, cell)
+    assert rc == 5 and result is None
+    assert "the set-up is not sound" in err and says in err and "t0:" not in err and "correct:" not in err
+    assert gone(pool_pids(err)[0], 1.0)
+
+
+@pytest.mark.parametrize("value", [1, 0, True, "8", 2.0])
+def test_a_burst_of_fewer_than_two_chunks_is_refused_before_anything_starts(capsys, monkeypatch, value):
+    cell = with_cell(monkeypatch, {"in_flight_chunks": 2, "setup_burst_chunks": value})
+    with pytest.raises(SystemExit, match="setup_burst_chunks"):
+        rehearse(capsys, cell)
+
+
+def test_a_workload_without_the_key_issues_the_posts_it_always_did_and_leaves_no_worker(capsys, monkeypatch):
+    """Recorded from the parent of the PR that brought the bursts: the set-up
+    chunk alone, then ``in_flight_chunks`` posts one STAGGER_S apart, then one
+    post a completion, in the order of the generator's indices."""
+    import run
+
+    events, main_thread = [], threading.current_thread()
+    real_post, real_sleep = pair.post_file, time.sleep
+
+    def post_file(source, src_path, dst_path, chunk_bytes):
+        events.append(("post", int(src_path.stem.split("_")[1])))
+        return real_post(source, src_path, dst_path, chunk_bytes)
+
+    def sleep(seconds):
+        if threading.current_thread() is main_thread and seconds >= 0.5:  # not the polls, nor a retry's back-off
+            events.append(("sleep", seconds))
+        real_sleep(seconds)
+
+    monkeypatch.setattr(pair, "post_file", post_file)
+    monkeypatch.setattr(time, "sleep", sleep)
+    cell = SPEC["workloads"][0]["name"]
+    in_flight = json.loads((BENCH / "workloads" / f"{cell}.json").read_text())["traffic"]["in_flight_chunks"]
+    rc, result, err = rehearse(capsys, cell)
+    monkeypatch.undo()
+    assert rc == 1 and result["rehearsal"]["checks_passed"] is True, err[-3000:]
+    fill = [("post", 1)] + [e for n in range(2, in_flight + 1) for e in (("sleep", run.STAGGER_S), ("post", n))]
+    assert events[: 1 + len(fill)] == [("post", 0)] + fill
+    rest = events[1 + len(fill) :]
+    assert rest == [("post", n) for n in range(in_flight + 1, in_flight + 1 + len(rest))] and rest
+    assert result["run"]["setup_rows"] == 1 and "set-up burst" not in err and "setup_bursts_s" not in result["run"]["phases"]
+    assert result["run"]["reference_rows"] == result["run"]["rows_sent"] == len(events) - (in_flight - 1)
+    assert gone(pool_pids(err)[0], 1.0)
+
+
+def test_a_result_line_is_printed_when_stop_hangs(capsys, monkeypatch):
+    import run
+
+    def hangs(self):
+        time.sleep(30)
+
+    def leave(rc):
+        raise SystemExit(rc)
+
+    monkeypatch.setattr(pair.LocalGateway, "stop", hangs)
+    monkeypatch.setattr(run, "TEARDOWN_S", 0.5)
+    monkeypatch.setattr(run, "leave", leave)
+    with pytest.raises(SystemExit) as left:
+        run.main(["--workload", SPEC["workloads"][0]["name"], "--seed", str(SEED), "--seconds", "3", "--trace", "0", "--rehearse-scale", "64"])
+    captured = capsys.readouterr()
+    assert left.value.code == 1  # a rehearsal's own code, not the deadline's
+    result = json.loads(captured.out.strip().splitlines()[-1])
+    assert result["rehearsal"]["checks_passed"] is True and list(result)[-1] == "compared"
+    assert line_no(captured.err, "result line printed") < line_no(captured.err, "teardown passed its limit")
+    assert captured.err.splitlines()[-1] == "correct: False"  # the numbers compared still end the log
+    assert gone(pool_pids(captured.err)[0], 1.0)
+
+
+def test_no_worker_outlives_a_run_that_a_gateway_fault_ends(capsys, monkeypatch):
+    real, asked = pair.errors, [0]
+
+    def errors(gw):
+        asked[0] += 1
+        return ["planted fault"] if asked[0] > 2 else real(gw)
+
+    monkeypatch.setattr(pair, "errors", errors)
+    rc, result, err = rehearse(capsys, SPEC["workloads"][0]["name"])
+    assert rc != 0 and "planted fault" in err and (result is None or result["rehearsal"]["checks_passed"] is False)
+    assert gone(pool_pids(err)[0], 1.0)
+
+
+def test_the_pool_is_started_before_jax_and_no_worker_outlives_the_deadline():
+    """A run of its own process: the pool's line says jax was not imported when
+    the workers started, and a deadline that fires in the window leaves none."""
+    code = (
+        "import sys; sys.path[:0] = [%r]\n"
+        "import run\n"
+        "run.DEADLINE_S = 6.0\n"
+        "sys.exit(run.main(['--workload', %r, '--seed', '7', '--seconds', '30', '--trace', '0', '--rehearse-scale', '64']))\n"
+    ) % (str(BENCH), SPEC["workloads"][0]["name"])
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    done = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True, timeout=120, env=env)
+    assert done.returncode == 3 and "deadline of 6s was reached" in done.stderr, done.stderr[-3000:]
+    assert not [l for l in done.stdout.splitlines() if l.startswith("{")]
+    pids, line = pool_pids(done.stderr)
+    assert "(jax imported: False)" in line and gone(pids)

@@ -84,7 +84,7 @@ def test_the_generator_imports_numpy_and_nothing_of_the_program():
             imported |= {a.name.split(".")[0] for a in node.names}
         elif isinstance(node, ast.ImportFrom):
             imported.add((node.module or "").split(".")[0])
-    assert imported == {"__future__", "numpy"}
+    assert imported == {"__future__", "numpy", "pathlib"}  # pathlib: where the schedule lies
     words = (BENCH / "generators" / "snapshot_delta.py").read_text().lower()
     assert not [w for w in ("skyplane", "gear", "cdc", "anchor", "boundar", "segment", "fingerprint") if w in words]
 
@@ -104,7 +104,8 @@ def test_the_configuration_states_what_the_issue_asks_and_guarantees_what_the_co
 def test_the_cell_hands_the_generator_the_configurations_sizes_and_nothing_else():
     cell = Cell(CELL)
     content = cell.workload["content"]
-    assert set(content) == {"region_bytes", "extent_bytes", "extents_per_region"}
+    assert set(content) == {"region_bytes", "extent_bytes", "extents_per_region", "corpus_seed", "schedule"}
+    assert (BENCH / content["schedule"]).is_file()
     assert all(content[k] == cell.config["content"][k] for k in content)
     assert cell.workload["traffic"] == {"in_flight_chunks": 2} and cell.entry["chips"] == 1
 
