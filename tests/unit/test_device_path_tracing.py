@@ -526,9 +526,10 @@ def _indexed_ops(module):
 
 
 def test_call_a_gathers_nothing_per_byte():
-    """Call A at a mid bucket gathers nothing over the `bucket` bytes: the gear values are selected, not looked
-    up (an indexed access costs a TPU v5e 8.6 ns an element, an element-wise one 0.03 ns). The compaction's
-    scatter is the one per-byte index left (ROADMAP.md Speed 2), so scatters are not held to the rule yet."""
+    """Call A at a mid bucket indexes nothing over the `bucket` bytes (an indexed access costs a TPU v5e 8.6 ns
+    an element, an element-wise one 0.03 ns): the gear values are selected, not looked up, and the compaction
+    searches a blocked prefix count for `cap` queries, so the program holds no scatter at all and no gather
+    whose index count reaches `bucket`."""
     import jax
     import jax.numpy as jnp
 
@@ -537,10 +538,16 @@ def test_call_a_gathers_nothing_per_byte():
     lowered = fused_mod._candidates_impl.lower(
         jax.ShapeDtypeStruct((2, bucket), jnp.uint8), jax.ShapeDtypeStruct((2,), jnp.int32), mask_bits=14, cap=cap
     )
-    gathers = [o for o in _indexed_ops(lowered.compiler_ir("stablehlo")) if "gather" in o[0]]
-    assert [o for o in gathers if o[1] >= bucket] == [], f"gathered over the row's bytes (cap {cap}, bucket {bucket})"
-    # the reader finds the per-byte form when it is there
-    per_byte = jax.jit(lambda t, d: t[d.astype(jnp.int32)]).lower(
+    indexed = _indexed_ops(lowered.compiler_ir("stablehlo"))
+    assert [o for o in indexed if "scatter" in o[0]] == [], "call A scatters"
+    assert [o for o in indexed if o[1] >= bucket] == [], f"indexed over the row's bytes (cap {cap}, bucket {bucket})"
+    assert [o for o in indexed if "gather" in o[0]], "the compaction's searches gather per query: the reader sees call A's gathers"
+    # the reader finds each per-byte form when it is there
+    per_byte_gather = jax.jit(lambda t, d: t[d.astype(jnp.int32)]).lower(
         jax.ShapeDtypeStruct((256,), jnp.uint32), jax.ShapeDtypeStruct((bucket,), jnp.uint8)
     )
-    assert [o for o in _indexed_ops(per_byte.compiler_ir("stablehlo")) if "gather" in o[0] and o[1] >= bucket]
+    assert [o for o in _indexed_ops(per_byte_gather.compiler_ir("stablehlo")) if "gather" in o[0] and o[1] >= bucket]
+    per_byte_scatter = jax.jit(
+        lambda to: jnp.full((cap,), bucket, jnp.int32).at[to].min(jax.lax.iota(jnp.int32, bucket), mode="drop")
+    ).lower(jax.ShapeDtypeStruct((bucket,), jnp.int32))
+    assert [o for o in _indexed_ops(per_byte_scatter.compiler_ir("stablehlo")) if "scatter" in o[0] and o[1] >= bucket]

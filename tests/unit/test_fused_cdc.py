@@ -209,6 +209,19 @@ COMPACTION_CASES = {  # name: (mask, cap)
     "a_run_that_fills_most_of_a_large_cap": (_mask_with(np.arange(2000, 2000 + 3 * 2048 + 7)), 8192),
     "every_position": (np.ones(COMPACT_BUCKET, bool), COMPACT_CAP),
     "two_rows_that_differ": (np.stack([_mask_with(_scattered(40, 3)), _mask_with(_scattered(300, 4))]), COMPACT_CAP),
+    # the edges of a blocked prefix count (fused_cdc._COUNT_BLOCK = 2048 bytes a block)
+    "last_column_of_a_block_then_first_of_the_next": (_mask_with([2047, 2048, 4095, 4096, 6143]), COMPACT_CAP),
+    "a_full_block_then_empty_blocks": (_mask_with(np.arange(2048, 4096)), 4096),
+    "a_full_block_overflows_a_small_cap": (_mask_with(np.arange(2048, 4096)), COMPACT_CAP),
+    "cap_th_entry_at_the_last_position": (_mask_with([*range(0, 1000 * (COMPACT_CAP - 1), 1000), COMPACT_BUCKET - 1]), COMPACT_CAP),
+    "bucket_a_block_does_not_divide": (_mask_with([0, 7, 8, 15, 16, 1500, 2047, 2048, 2992, 2999], n=3000), COMPACT_CAP),
+    "bucket_a_block_does_not_divide_overflows": (np.ones(3000, bool), COMPACT_CAP),
+    "odd_bucket": (_mask_with([0, 1, 500, 1000], n=1001), COMPACT_CAP),
+    "bucket_smaller_than_a_block": (_mask_with([0, 511, 512, 1023], n=1024), COMPACT_CAP),
+    "four_rows_of_0_1_cap_and_cap_plus_1_entries": (
+        np.stack([_mask_with(_scattered(c, 10 + c)) for c in (0, 1, COMPACT_CAP, COMPACT_CAP + 1)]),
+        COMPACT_CAP,
+    ),
 }
 
 
