@@ -76,6 +76,7 @@ DECODE_COUNTER_ZERO = {
     "ref_segments_resolved": 0,
     "ref_bytes_resolved": 0,
     "literal_pass_ns": 0,
+    "blob_decode_ns": 0,
     "literal_segments_verified": 0,
     "literal_verify_calls": 0,
     "store_mem_hits": 0,
@@ -342,6 +343,7 @@ class GatewayReceiver:
             "ref_segments_resolved": 0,
             "ref_bytes_resolved": 0,
             "literal_pass_ns": 0,
+            "blob_decode_ns": 0,
             "literal_segments_verified": 0,
             "literal_verify_calls": 0,
         }

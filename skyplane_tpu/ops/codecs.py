@@ -19,10 +19,12 @@ from __future__ import annotations
 
 import os
 import threading
-from typing import Callable, Dict, NamedTuple, Optional
+import time
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 from skyplane_tpu.chunk import Codec
 from skyplane_tpu.exceptions import CodecException
+from skyplane_tpu.obs import NOOP_SPAN
 
 
 class CodecSpec(NamedTuple):
@@ -30,13 +32,18 @@ class CodecSpec(NamedTuple):
     a view of an array); a caller that needs ``bytes`` converts. A codec that
     can write into memory its caller owns says so with ``decode_out_len``:
     the length of the ``out`` array ``decode(buf, out)`` needs for ``n``
-    decoded bytes. What comes back is then a view of ``out``."""
+    decoded bytes. What comes back is then a view of ``out``.
+
+    ``encode_steps`` names the steps ``encode`` is made of, in order, for the
+    codecs whose steps have a counter (``blockpack``, ``zstd``): running them
+    one after the other gives ``encode``'s bytes (see :func:`timed_encoder`)."""
 
     name: str
     codec_id: Codec
     encode: Callable[[bytes], bytes]
     decode: Callable[..., object]
     decode_out_len: Optional[Callable[[int], int]] = None
+    encode_steps: Tuple[Tuple[str, Callable[[bytes], bytes]], ...] = ()
 
 
 def _zstd():
@@ -171,9 +178,12 @@ def _decode_lz4(buf: bytes) -> bytes:
 
 _REGISTRY: Dict[str, CodecSpec] = {
     "none": CodecSpec("none", Codec.NONE, lambda b: b, lambda b: b),
-    "zstd": CodecSpec("zstd", Codec.ZSTD, _encode_zstd, _decode_zstd),
-    "tpu": CodecSpec("tpu", Codec.TPU_BLOCK, _encode_tpu, _decode_tpu, _tpu_out_len),
-    "tpu_zstd": CodecSpec("tpu_zstd", Codec.TPU_BLOCK_ZSTD, _encode_tpu_zstd, _decode_tpu_zstd, _tpu_out_len),
+    "zstd": CodecSpec("zstd", Codec.ZSTD, _encode_zstd, _decode_zstd, None, (("zstd", _encode_zstd),)),
+    "tpu": CodecSpec("tpu", Codec.TPU_BLOCK, _encode_tpu, _decode_tpu, _tpu_out_len, (("blockpack", _encode_tpu),)),
+    "tpu_zstd": CodecSpec(
+        "tpu_zstd", Codec.TPU_BLOCK_ZSTD, _encode_tpu_zstd, _decode_tpu_zstd, _tpu_out_len,
+        (("blockpack", _encode_tpu), ("zstd", _encode_zstd)),
+    ),
     "native_lz": CodecSpec("native_lz", Codec.NATIVE_LZ, _encode_native, _decode_native),
     # the reference's wire codec (gateway_operator.py:358-361), bound to the
     # system liblz4; registered unconditionally — encode/decode raise on
@@ -182,6 +192,25 @@ _REGISTRY: Dict[str, CodecSpec] = {
 }
 
 _BY_ID: Dict[int, CodecSpec] = {int(spec.codec_id): spec for spec in _REGISTRY.values()}
+
+
+def timed_encoder(spec: CodecSpec, timings: dict, span=lambda name: NOOP_SPAN) -> Callable[[bytes], bytes]:
+    """``spec.encode`` taken step by step: the callable returned gives the
+    same bytes and leaves ``<step>_ns`` in ``timings`` for each of
+    ``spec.encode_steps``, each run under ``span("codec.<step>")``. A codec
+    that names no steps is returned as it is."""
+    if not spec.encode_steps:
+        return spec.encode
+
+    def encode(data: bytes) -> bytes:
+        for step, fn in spec.encode_steps:
+            t = time.perf_counter_ns()
+            with span(f"codec.{step}"):
+                data = fn(data)
+            timings[f"{step}_ns"] = time.perf_counter_ns() - t
+        return data
+
+    return encode
 
 
 def get_codec(name: str) -> CodecSpec:

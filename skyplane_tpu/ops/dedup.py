@@ -799,7 +799,9 @@ def build_recipe(
 
     ``timings``, where given, receives ``recipe_encode_ns``: the literal join
     and ``encode_blob``. What the call takes beyond that is index lookups and
-    recipe assembly.
+    recipe assembly. It also receives ``literal_blob_bytes``, the length of
+    the encoded literal blob: ``n_literal_bytes_pre_codec`` over it is what
+    the codec alone took off, apart from dedup.
     """
     entries = bytearray()
     lit_parts: List[bytes] = []
@@ -819,6 +821,7 @@ def build_recipe(
     lit_blob = encode_blob(b"".join(lit_parts))
     if timings is not None:
         timings["recipe_encode_ns"] = time.perf_counter_ns() - t0
+        timings["literal_blob_bytes"] = len(lit_blob)
     head = MAGIC + struct.pack("<BI", VERSION, len(segments))
     return head + bytes(entries) + lit_blob, len(ref_fps), sum(len(p) for p in lit_parts), new_fps, ref_fps
 
@@ -862,6 +865,7 @@ def parse_recipe(
     ref_stats: Optional[dict] = None,
     ref_span=NOOP_SPAN,
     blob_out_len=None,
+    blob_span=NOOP_SPAN,
 ):
     """Receiver side: resolve a recipe back into raw chunk bytes.
 
@@ -882,7 +886,8 @@ def parse_recipe(
     with one copy. The second pass resolves the REFs (``store.get`` and the
     copy into the output), this chunk's own repeats among them, under
     ``ref_span``. ``ref_stats``, where given, receives what the passes did:
-    ``literal_pass_ns`` (blob decode, verify, admit, place),
+    ``literal_pass_ns`` (blob decode, verify, admit, place) and inside it
+    ``blob_decode_ns`` (``decode_blob`` alone, run under ``blob_span``),
     ``literal_segments_verified``, ``literal_verify_calls``, and, for a recipe
     that holds a REF, ``ref_resolve_ns``, ``ref_segments_resolved``,
     ``ref_bytes_resolved``.
@@ -941,7 +946,10 @@ def parse_recipe(
         if arr is not None and blob_out_len is not None and lit_total:
             lit_arr = out_pool.acquire(bucket_size(blob_out_len(lit_total)))
         try:
-            lit = np.frombuffer(decode_blob(buf[off:]) if lit_arr is None else decode_blob(buf[off:], lit_arr), np.uint8)
+            t_blob = time.perf_counter_ns()
+            with blob_span:
+                lit = np.frombuffer(decode_blob(buf[off:]) if lit_arr is None else decode_blob(buf[off:], lit_arr), np.uint8)
+            blob_decode_ns = time.perf_counter_ns() - t_blob
             if len(lit) != lit_total:
                 how = "shorter" if len(lit) < lit_total else "longer"
                 raise DedupIntegrityException(f"literal blob {how} than recipe entries")
@@ -961,6 +969,7 @@ def parse_recipe(
                 out_pool.release(lit_arr)
         if ref_stats is not None:
             ref_stats["literal_pass_ns"] = time.perf_counter_ns() - t_lit
+            ref_stats["blob_decode_ns"] = blob_decode_ns
             ref_stats["literal_segments_verified"] = len(lit_fps) if verify_literals else 0
             ref_stats["literal_verify_calls"] = 1 if verify_literals and lit_fps else 0
         ref_idx = np.flatnonzero(~is_lit).tolist()

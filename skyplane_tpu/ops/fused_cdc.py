@@ -330,7 +330,7 @@ class FusedCDCFP:
         self._shards = int(np.prod([mesh.shape[a] for a in self.shard_axes])) if mesh is not None else 1
         self._sharded = {}  # bucket -> (candidates_fn, fp_fn)
         self._stats_lock = threading.Lock()
-        self._counters = {"donated_batches": 0, "fused_rows": 0, "fused_gap_ns": 0, "fused_gap_cpu_ns": 0}
+        self._counters = {"donated_batches": 0, "fused_rows": 0, "fused_gap_ns": 0, "fused_gap_cpu_ns": 0, "overflow_rows": 0}
         _listen_for_compiles()
 
     def _kernels(self, bucket: int):
@@ -372,8 +372,11 @@ class FusedCDCFP:
         enqueued (the extent of ``fused.select`` + ``fused.enqueue_b``);
         ``fused_gap_cpu_ns``: this thread's CPU time over the same interval —
         the rest is time the leader did not run (interpreter lock, scheduler,
-        a blocked transfer). ``xla_compiles`` / ``xla_compile_ns`` are the
-        process's, see :func:`compile_counters`."""
+        a blocked transfer). ``overflow_rows``: rows whose candidates passed
+        ``candidate_cap``, so that the device's list was cut short and
+        ``_host_exact`` made the row again on the host: the device's work on
+        such a row is thrown away. ``xla_compiles`` / ``xla_compile_ns`` are
+        the process's, see :func:`compile_counters`."""
         with self._stats_lock:
             out = dict(self._counters)
         out.update(compile_counters())
@@ -465,6 +468,7 @@ class FusedCDCFP:
                 c["fused_rows"] += int(np.count_nonzero(lens_np))
                 c["fused_gap_ns"] += gap_ns
                 c["fused_gap_cpu_ns"] += gap_cpu_ns
+                c["overflow_rows"] += sum(f is not None for f in fallback)
         except BaseException:
             if ends_scratch is not None:
                 # an overflow-row host recompute or a failed device dispatch

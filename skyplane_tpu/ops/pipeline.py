@@ -23,7 +23,7 @@ from skyplane_tpu.exceptions import ChecksumMismatchException, CodecException
 from skyplane_tpu.obs import get_tracer
 from skyplane_tpu.ops.bufpool import MIN_BUCKET, BufferPool, bucket_size
 from skyplane_tpu.ops.cdc import CDCParams, cdc_segment_ends
-from skyplane_tpu.ops.codecs import CodecSpec, get_codec, get_codec_by_id
+from skyplane_tpu.ops.codecs import CodecSpec, get_codec, get_codec_by_id, timed_encoder
 from skyplane_tpu.ops.dedup import PooledChunk, SegmentStore, SenderDedupIndex, build_recipe, parse_recipe
 
 # canonical home is ops/bufpool.py (the pool keys on it); kept under the old
@@ -44,6 +44,7 @@ class ProcessedPayload:
     n_segments: int = 0
     n_ref_segments: int = 0
     literal_bytes: int = 0  # pre-codec literal bytes shipped (dedup mode)
+    literal_blob_bytes: int = 0  # the same literals after the codec: the recipe's encoded blob
     new_fingerprints: list = field(default_factory=list)  # commit to index AFTER delivery
     ref_fingerprints: list = field(default_factory=list)  # discard from index on unresolvable-ref nack
 
@@ -74,10 +75,13 @@ class DataPathStats:
         "segments",
         "ref_segments",
         "literal_bytes",
+        "literal_blob_bytes",
         "device_wait_ns",
         "device_path_ns",
         "recipe_ns",
         "recipe_encode_ns",
+        "blockpack_ns",
+        "zstd_ns",
         "seal_ns",
     )
     EXTERNAL_ZERO = {
@@ -98,6 +102,7 @@ class DataPathStats:
         "fused_rows": 0,
         "fused_gap_ns": 0,
         "fused_gap_cpu_ns": 0,
+        "overflow_rows": 0,
         "xla_compiles": 0,
         "xla_compile_ns": 0,
     }
@@ -117,18 +122,19 @@ class DataPathStats:
             self._tls.counters = d
         return d
 
-    def observe(
-        self, p: ProcessedPayload, device_path_ns: int = 0, recipe_ns: int = 0, recipe_encode_ns: int = 0
-    ) -> None:
+    def observe(self, p: ProcessedPayload, device_path_ns: int = 0, recipe_ns: int = 0, timings: Optional[dict] = None) -> None:
         """One chunk done. ``device_path_ns``: wall time its worker spent on
         CDC + fingerprints, from submission to finalized digests — the pad
         copy and staging, the window wait, a leader's whole batch, a
         follower's waits, ``finalize_row`` (on a gateway with no accelerator,
         the host kernels). ``recipe_ns``: ``build_recipe`` (dedup-index
-        lookups, literal join, codec); ``recipe_encode_ns`` is the join and
-        the codec inside it. ``literal_bytes``: raw bytes of the segments
-        that went as literals, so what dedup left (0 with dedup off: no
-        recipe, no literal). Added together with ``chunks``, their
+        lookups, literal join, codec). ``timings`` is ``build_recipe``'s:
+        ``recipe_encode_ns``, the join and the codec inside ``recipe_ns``,
+        and inside that ``blockpack_ns`` and ``zstd_ns``, the steps of the
+        codec that ran (a step it does not have stays 0). ``literal_bytes``:
+        raw bytes of the segments that went as literals, so what dedup left
+        (0 with dedup off: no recipe, no literal); ``literal_blob_bytes``:
+        what the codec made of them. Added together with ``chunks``, their
         denominator, so a scrape between chunks sees whole chunks."""
         d = self._shard()
         d["chunks"] += 1
@@ -137,9 +143,11 @@ class DataPathStats:
         d["segments"] += p.n_segments
         d["ref_segments"] += p.n_ref_segments
         d["literal_bytes"] += p.literal_bytes
+        d["literal_blob_bytes"] += p.literal_blob_bytes
         d["device_path_ns"] += device_path_ns
         d["recipe_ns"] += recipe_ns
-        d["recipe_encode_ns"] += recipe_encode_ns
+        for k in ("recipe_encode_ns", "blockpack_ns", "zstd_ns"):
+            d[k] += (timings or {}).get(k, 0)
 
     def observe_device_wait(self, ns: int) -> None:
         """Time this worker spent BLOCKED on the device (phase waits in the
@@ -354,9 +362,11 @@ class DataPathProcessor:
         self, data: bytes, index: Optional[SenderDedupIndex] = None, trace_id: Optional[str] = None
     ) -> ProcessedPayload:
         """``trace_id`` (the chunk id) keys the sampling of the ``recipe.build``
-        span, as it does for the framer's ``wire.frame`` around this call."""
+        span and of ``codec.blockpack`` / ``codec.zstd`` inside it, as it does
+        for the framer's ``wire.frame`` around this call."""
         raw_len = len(data)
-        device_path_ns = recipe_ns = recipe_encode_ns = 0
+        device_path_ns = recipe_ns = 0
+        timings: dict = {}
         if self.dedup and index is not None and raw_len > 0:
             arr = np.frombuffer(data, np.uint8)
             t = time.perf_counter_ns()
@@ -379,12 +389,12 @@ class DataPathProcessor:
             device_path_ns += time.perf_counter_ns() - t
             self.stats.observe_device_wait(phased.wait_ns)
             segments = list(zip(seg_fps, spans))
-            timings: dict = {}
+            tracer = get_tracer()
+            encode = timed_encoder(self.codec, timings, lambda name: tracer.span(name, trace_id=trace_id, cat="sender"))
             t = time.perf_counter_ns()
-            with get_tracer().span("recipe.build", trace_id=trace_id, cat="sender"):
-                wire, n_ref, lit_bytes, new_fps, ref_fps = build_recipe(segments, index, self.codec.encode, timings)
+            with tracer.span("recipe.build", trace_id=trace_id, cat="sender"):
+                wire, n_ref, lit_bytes, new_fps, ref_fps = build_recipe(segments, index, encode, timings)
             recipe_ns = time.perf_counter_ns() - t
-            recipe_encode_ns = timings["recipe_encode_ns"]
             payload = ProcessedPayload(
                 wire_bytes=wire,
                 codec=self.codec.codec_id,
@@ -395,6 +405,7 @@ class DataPathProcessor:
                 n_segments=len(segments),
                 n_ref_segments=n_ref,
                 literal_bytes=lit_bytes,
+                literal_blob_bytes=timings["literal_blob_bytes"],
                 new_fingerprints=new_fps,
                 ref_fingerprints=ref_fps,
             )
@@ -414,7 +425,7 @@ class DataPathProcessor:
                 raw_len=raw_len,
                 fingerprint=fp,
             )
-        self.stats.observe(payload, device_path_ns, recipe_ns, recipe_encode_ns)
+        self.stats.observe(payload, device_path_ns, recipe_ns, timings)
         return payload
 
     # ---- decode ----
@@ -457,6 +468,7 @@ class DataPathProcessor:
                     "decode.ref_resolve", trace_id=header.chunk_id, cat="receiver", force=header.is_traced
                 ),
                 blob_out_len=codec.decode_out_len,
+                blob_span=get_tracer().span("decode.blob", trace_id=header.chunk_id, cat="receiver", force=header.is_traced),
             )
         else:
             data = codec.decode(payload)

@@ -46,6 +46,7 @@ NEW_KEYS = (
     "fused_gap_cpu_ns",
     "xla_compiles",
     "xla_compile_ns",
+    "overflow_rows",  # PR 36
 )
 
 
@@ -396,6 +397,68 @@ def test_disabled_tracer_gives_the_ref_path_sites_the_noop_span(span_name):
     assert tracer.counters()["spans_recorded"] == 0
 
 
+# ---- (g3) the codec's steps: counters and spans on both sides (PR 36)
+
+
+def _compressible(n: int = 300_000) -> bytes:
+    """Words from a small vocabulary, then a run of zeros: zstd shrinks the first, blockpack suppresses the second."""
+    words = rng.integers(0x20, 0x40, (64, 8), dtype=np.uint8)
+    text = words[rng.integers(0, 64, n // 8)].ravel()
+    return np.concatenate([text, np.zeros(n // 4, np.uint8)]).tobytes()
+
+
+#: codec name -> the steps its encode is made of that have a counter
+CODEC_STEPS = {"none": (), "zstd": ("zstd",), "tpu": ("blockpack",), "tpu_zstd": ("blockpack", "zstd")}
+
+
+@pytest.mark.parametrize("codec_name", sorted(CODEC_STEPS))
+def test_encode_counters_and_spans_name_the_steps_of_the_codec_that_ran(codec_name):
+    pytest.importorskip("zstandard")
+    from skyplane_tpu.chunk import ChunkFlags, WireProtocolHeader
+    from skyplane_tpu.ops.codecs import get_codec, timed_encoder
+    from skyplane_tpu.ops.dedup import SegmentStore, SenderDedupIndex
+
+    tracer = configure_tracer(sample=1.0)
+    data, trace_id = _compressible(), "36" * 16
+    proc = DataPathProcessor(codec_name=codec_name, dedup=True, cdc_params=PARAMS)
+    p = proc.process(data, SenderDedupIndex(), trace_id=trace_id)
+    d = proc.stats.as_dict()
+    steps = CODEC_STEPS[codec_name]
+    assert [k for k in ("blockpack", "zstd") if d[f"{k}_ns"] > 0] == list(steps)
+    assert d["blockpack_ns"] + d["zstd_ns"] <= d["recipe_encode_ns"] <= d["recipe_ns"]
+    # a recipe is 7 bytes of head, 25 bytes an entry, and the encoded literal blob
+    assert d["literal_blob_bytes"] == p.literal_blob_bytes == len(p.wire_bytes) - 7 - 25 * p.n_segments > 0
+    if steps:
+        assert p.literal_blob_bytes < p.literal_bytes  # the content compresses under every step
+    else:
+        assert p.literal_blob_bytes == p.literal_bytes
+    # taken step by step the codec gives the bytes it gives in one piece
+    spec, timings = get_codec(codec_name), {}
+    assert bytes(timed_encoder(spec, timings)(data)) == bytes(spec.encode(data))
+    assert sorted(timings) == sorted(f"{k}_ns" for k in steps)
+
+    header = WireProtocolHeader(
+        chunk_id=trace_id, data_len=len(p.wire_bytes), raw_data_len=p.raw_len, codec=int(p.codec),
+        flags=int(ChunkFlags.RECIPE), fingerprint=p.fingerprint,
+    )
+    ref_stats: dict = {}
+    assert proc.restore(p.wire_bytes, header, store=SegmentStore(), ref_stats=ref_stats) == data
+    assert 0 < ref_stats["blob_decode_ns"] <= ref_stats["literal_pass_ns"]
+
+    events = {}
+    for e in tracer.export()["traceEvents"]:
+        if e.get("name") in ("recipe.build", "codec.blockpack", "codec.zstd", "decode.blob"):
+            assert e["args"]["chunk_id"] == trace_id and e["name"] not in events
+            events[e["name"]] = e
+    assert sorted(events) == sorted(["recipe.build", "decode.blob"] + [f"codec.{k}" for k in steps])
+    assert events["decode.blob"]["cat"] == "receiver" and events["decode.blob"]["dur"] * 1e3 <= ref_stats["blob_decode_ns"]
+    build = events["recipe.build"]
+    for k in steps:  # each step's span lies inside recipe.build and under its counter
+        e = events[f"codec.{k}"]
+        assert e["cat"] == "sender" and build["ts"] <= e["ts"] and e["ts"] + e["dur"] <= build["ts"] + build["dur"]
+        assert e["dur"] * 1e3 <= d[f"{k}_ns"]
+
+
 # ---- (h) the schema is stable, and served
 
 
@@ -430,6 +493,11 @@ REF_PATH_KEYS = (
     ("profile/decode", "literal_pass_ns"),
     ("profile/decode", "literal_segments_verified"),
     ("profile/decode", "literal_verify_calls"),
+    # the codec's steps on both sides (PR 36)
+    ("profile/compression", "blockpack_ns"),
+    ("profile/compression", "zstd_ns"),
+    ("profile/compression", "literal_blob_bytes"),
+    ("profile/decode", "blob_decode_ns"),
 )
 
 

@@ -87,6 +87,31 @@ class TestFusedMatchesHost:
         np.testing.assert_array_equal(ends, want_ends)
         assert fps == want_fps
 
+    def test_an_overflowing_row_is_counted_once_and_still_exact(self, monkeypatch):
+        """``overflow_rows`` (PR 36): a row whose candidates pass the cap is made
+        again on the host and says so in the runner's counters, where nothing
+        else would; the row beside it, under the cap, is not counted."""
+        import skyplane_tpu.ops.fused_cdc as fused_mod
+        from skyplane_tpu.ops.batch_runner import DeviceBatchRunner
+
+        params = CDCParams(min_bytes=64, avg_bytes=256, max_bytes=1024)
+        n = 1 << 16
+        crowded = rng.integers(0, 256, n, dtype=np.uint8)  # ~n/256 = 256 candidates
+        sparse = np.zeros(n, np.uint8)  # no candidate in a run of zeros: every cut forced
+        monkeypatch.setattr(fused_mod, "candidate_cap", lambda bucket, params=None: 64)
+        runner = DeviceBatchRunner(cdc_params=params, max_batch=2, max_wait_ms=2.0)
+        assert runner.counters()["overflow_rows"] == 0
+        ends, fps = runner.cdc_and_fps(sparse)
+        assert runner.counters()["overflow_rows"] == 0
+        want_ends, want_fps = _expected(sparse, params)
+        np.testing.assert_array_equal(ends, want_ends)
+        assert fps == want_fps
+        ends, fps = runner.cdc_and_fps(crowded)
+        assert runner.counters()["overflow_rows"] == 1
+        want_ends, want_fps = _expected(crowded, params)
+        np.testing.assert_array_equal(ends, want_ends)
+        assert fps == want_fps
+
 
 def test_fuzz_params_and_lengths():
     """Seeded sweep over CDC params x lengths x content shapes: the fused
