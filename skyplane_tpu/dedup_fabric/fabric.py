@@ -376,7 +376,7 @@ class DedupFabric:
         if owner is None or owner == self.gateway_id or member is None or not member.get("url"):
             return
         try:
-            self._push_q.put_nowait((owner, fp, data))
+            self._push_q.put_nowait((owner, fp, bytes(data)))  # the store may hold a view: its own bytes leave
         except queue.Full:
             self._c["fabric_pushes_dropped"] += 1
 
@@ -478,7 +478,7 @@ class DedupFabric:
             data = store.peek(fp)
             if data is not None:
                 self._c["fabric_serves"] += 1
-                return data
+                return bytes(data)  # the store may hold a view of a chunk's literals
         cs = self.chunk_store
         if cs is not None:
             ref = cs.sealed_open_by_fp(fp.hex())

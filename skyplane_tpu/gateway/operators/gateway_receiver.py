@@ -92,6 +92,9 @@ DECODE_COUNTER_ZERO = {
     "store_spill_bytes": 0,
     "store_spill_adopted": 0,
     "store_spill_write_failures": 0,
+    "store_blobs": 0,
+    "store_blob_segments": 0,
+    "store_blob_bytes": 0,
     "pool_hits": 0,
     "pool_misses": 0,
     "pool_hit_rate": 0.0,

@@ -260,11 +260,25 @@ class _StoreStripe:
 
     def __init__(self):
         self.lock = lockcheck.wrap(threading.Lock(), "_StoreStripe.lock")
-        self.mem: "OrderedDict[bytes, list]" = OrderedDict()  # fp -> [data, last-touch seq]
+        # fp -> [data, last-touch seq, _StoreBlob or None]: a segment admitted by
+        # ``put_blob`` is a view of its blob, one admitted by ``put`` stands alone
+        self.mem: "OrderedDict[bytes, list]" = OrderedDict()
         # fp -> [arrival Event, waiter refcount]: REFs that raced ahead of
         # their LITERAL park here and wake the moment put() lands the bytes
         self.waiters: Dict[bytes, list] = {}
         self.contended = 0  # monitoring counter (GIL increments; approximate)
+
+
+class _StoreBlob:
+    """One buffer whose segments a SegmentStore holds as views: charged once,
+    credited when ``live`` (its segments resident in memory) reaches 0.
+    ``live`` moves only under the store's budget lock."""
+
+    __slots__ = ("nbytes", "live")
+
+    def __init__(self, nbytes: int, live: int):
+        self.nbytes = nbytes
+        self.live = live
 
 
 class SegmentStore:
@@ -273,6 +287,16 @@ class SegmentStore:
     In-memory LRU bounded by bytes, with optional disk spill directory so the
     working set can exceed RAM (gateway VMs stage chunks on disk anyway,
     reference: skyplane/gateway/chunk_store.py:108-109).
+
+    A stored value is ``bytes`` or a read-only ``memoryview``: ``put_blob``
+    admits a whole chunk's literals as views into one buffer (the sink's
+    literal pass), ``put`` one segment as it is given. The byte bound counts
+    what the store really holds: a buffer is charged its full length once,
+    when its first segment is admitted, and credited when its last segment
+    leaves memory (evicted or spilled), since one resident view keeps all of
+    it alive; a segment stored alone (``put``, a promotion from spill) is
+    charged its own length. Bytes that leave the process (fabric pushes and
+    serves) are taken as ``bytes`` where they leave.
 
     Hot-path striping (the receiver mirror of ``SenderDedupIndex``): every
     decode worker resolves one ``get``/``put`` per SEGMENT, so a single mutex
@@ -310,6 +334,7 @@ class SegmentStore:
         self._budget_lock = lockcheck.wrap(threading.Lock(), "SegmentStore._budget_lock")  # guards the global mem byte total
         self._max_bytes = max_bytes
         self._mem_bytes = 0
+        self._blob_bytes = 0  # the part of _mem_bytes that is put_blob buffers
         self._spill_dir = Path(spill_dir) if spill_dir else None
         self._spill_max_bytes = spill_max_bytes
         self._spill_lock = lockcheck.wrap(threading.Lock(), "SegmentStore._spill_lock")  # guards spill index + in-transit map
@@ -359,6 +384,8 @@ class SegmentStore:
         self._c_mem_evictions = 0
         self._c_spill_evictions = 0
         self._c_spill_write_failures = 0
+        self._c_blobs = 0
+        self._c_blob_segments = 0
         # consecutive spill-write failures before escalation (any success
         # resets): a transient disk error degrades gracefully — the evictee is
         # dropped and later REFs to it recover via NACK -> literal resend —
@@ -409,6 +436,62 @@ class SegmentStore:
             # so a fetch never push-loops back to the gateway it came from.
             self.fabric.note_put(fp, data)
 
+    def put_blob(self, buf, fps: List[bytes], starts: List[int], ends: List[int]) -> None:
+        """Admit the segments ``buf[starts[i]:ends[i]]`` under ``fps[i]`` as
+        read-only views of ``buf``, which the store keeps from now on: the
+        caller must own it and never write it again (never pooled memory).
+        Each stripe lock is taken once for its group of segments; a
+        fingerprint already resident is touched and takes no view."""
+        if not fps:
+            return
+        whole = memoryview(buf).toreadonly()
+        segs = [whole[a:b] for a, b in zip(starts, ends)]
+        # charged up front with every segment counted live, so an evictor that
+        # pops one before this call returns can never credit the blob early
+        blob = _StoreBlob(whole.nbytes, len(fps))
+        with self._hold(self._budget_lock):
+            self._mem_bytes += blob.nbytes
+            self._blob_bytes += blob.nbytes
+        groups: Dict[int, List[int]] = {}
+        for i, fp in enumerate(fps):
+            groups.setdefault(fp[0] & self._mask, []).append(i)
+        admitted = 0
+        woken = []
+        for si, idx in groups.items():
+            s = self._stripes[si]
+            with self._hold(s.lock, s):
+                for i in idx:
+                    fp = fps[i]
+                    entry = s.mem.get(fp)
+                    if entry is not None:
+                        entry[1] = next(self._seq)
+                        s.mem.move_to_end(fp)
+                    else:
+                        s.mem[fp] = [segs[i], next(self._seq), blob]
+                        admitted += 1
+                    waiter = s.waiters.pop(fp, None)
+                    if waiter is not None:
+                        woken.append(waiter)
+        for waiter in woken:
+            waiter[0].set()  # outside the stripe locks; waiters re-check under them
+        if admitted < len(fps):
+            self._drop_live(blob, len(fps) - admitted)
+        if admitted:
+            self._c_blobs += 1
+            self._c_blob_segments += admitted
+        self._evict_to_budget()
+        if self.fabric is not None:
+            for fp, seg in zip(fps, segs):
+                self.fabric.note_put(fp, seg)
+
+    def _drop_live(self, blob: "_StoreBlob", n: int) -> None:
+        """``n`` of ``blob``'s segments left memory: credit it if none is left."""
+        with self._hold(self._budget_lock):
+            blob.live -= n
+            if blob.live == 0:
+                self._mem_bytes -= blob.nbytes
+                self._blob_bytes -= blob.nbytes
+
     def _insert(self, fp: bytes, data: bytes) -> None:
         """Insert into the striped in-memory map and wake any parked REFs."""
         s = self._stripe(fp)
@@ -419,7 +502,7 @@ class SegmentStore:
                 entry[1] = next(self._seq)
                 s.mem.move_to_end(fp)
             else:
-                s.mem[fp] = [data, next(self._seq)]
+                s.mem[fp] = [data, next(self._seq), None]
                 added = len(data)
             waiter = s.waiters.pop(fp, None)
         if waiter is not None:
@@ -449,15 +532,18 @@ class SegmentStore:
             with self._hold(victim.lock, victim):
                 if not victim.mem:
                     continue  # raced with another evictor; rescan
-                vfp, (data, _) = victim.mem.popitem(last=False)
+                vfp, (data, _, blob) = victim.mem.popitem(last=False)
                 if self._spill_dir is not None:
                     # stage for spill INSIDE the stripe lock (stripe -> spill
                     # nesting, this one site only) so a concurrent get()
                     # always finds the segment in mem ∪ in_transit ∪ spill
                     with self._hold(self._spill_lock):
                         self._in_transit[vfp] = data
-            with self._hold(self._budget_lock):
-                self._mem_bytes -= len(data)
+            if blob is None:
+                with self._hold(self._budget_lock):
+                    self._mem_bytes -= len(data)
+            else:
+                self._drop_live(blob, 1)
             self._c_mem_evictions += 1
             if self._spill_dir is not None:
                 self._spill_out(vfp, data)
@@ -587,7 +673,7 @@ class SegmentStore:
         with self._hold(self._spill_lock):
             data = self._in_transit.get(fp)
             if data is not None:
-                return data
+                return bytes(data)  # a view of a blob already credited: its own bytes, charged alone if promoted
             if fp not in self._spill_order:
                 return None
             self._spill_order.move_to_end(fp)
@@ -757,7 +843,7 @@ class SegmentStore:
         """Decode-side health counters (merged into the receiver's stable
         decode-counter schema; see docs/datapath-performance.md)."""
         with self._hold(self._budget_lock):
-            mem_bytes = self._mem_bytes
+            mem_bytes, blob_bytes = self._mem_bytes, self._blob_bytes
         with self._hold(self._spill_lock):
             spill_bytes = self._spill_bytes
         return {
@@ -775,6 +861,9 @@ class SegmentStore:
             "store_spill_adopted": self._adopted_spill_count,
             "store_spill_write_failures": self._c_spill_write_failures,
             "store_fabric_hits": self._c_fabric_hits,
+            "store_blobs": self._c_blobs,
+            "store_blob_segments": self._c_blob_segments,
+            "store_blob_bytes": blob_bytes,
         }
 
 
@@ -880,12 +969,13 @@ def parse_recipe(
     ``verify_literals`` one batched call recomputes every literal's
     fingerprint — a corrupted literal stored under a healthy fingerprint
     would propagate to every future chunk that REFs it — and ALL of the
-    chunk's literals are checked before any is admitted; then each is
-    inserted into ``store`` (which keeps its own copy, never a view) so later
-    refs resolve, and each run of consecutive literals is placed in the output
-    with one copy. The second pass resolves the REFs (``store.get`` and the
-    copy into the output), this chunk's own repeats among them, under
-    ``ref_span``. ``ref_stats``, where given, receives what the passes did:
+    chunk's literals are checked before any is admitted; then all of them go
+    into ``store`` in one ``put_blob`` call, as views of the one buffer the
+    literals were decoded into (which the store keeps: never pooled memory),
+    so later refs resolve, and each run of consecutive literals is placed in
+    the output with one copy. The second pass resolves the REFs
+    (``store.get`` and the copy into the output), this chunk's own repeats
+    among them, under ``ref_span``. ``ref_stats``, where given, receives what the passes did:
     ``literal_pass_ns`` (blob decode, verify, admit, place) and inside it
     ``blob_decode_ns`` (``decode_blob`` alone, run under ``blob_span``),
     ``literal_segments_verified``, ``literal_verify_calls``, and, for a recipe
@@ -896,9 +986,9 @@ def parse_recipe(
     :class:`PooledChunk` is returned instead of ``bytes``; the caller writes
     its ``view`` out and releases it. Where the codec can write into memory
     its caller owns (``blob_out_len``: ``CodecSpec.decode_out_len``), the
-    decoded literals go into a second pooled buffer, handed to
-    ``decode_blob(blob, out)`` and released before the return. Without a pool
-    the historical ``bytes`` return is unchanged.
+    decoded literals go into a fresh array handed to ``decode_blob(blob,
+    out)``, the buffer the store adopts. Without a pool the historical
+    ``bytes`` return is unchanged.
     """
     buf = memoryview(buf)
     head_len = 2 + struct.calcsize("<BI")
@@ -933,40 +1023,40 @@ def parse_recipe(
     lit_ends = np.cumsum(lit_lens)  # where each literal ends in the decoded blob
     lit_total = int(lit_ends[-1]) if len(lit_idx) else 0
     lit_fps = [fp_blob[16 * i : 16 * i + 16] for i in lit_idx.tolist()]
-    # the output: a pooled buffer (``arr``, released on every failing path) or a plain one; the decoded
-    # literals: a second pooled buffer (``lit_arr``, released on every path) where the codec takes one.
-    # No second name for either: analysis/resources.py follows a pooled buffer by name
+    # the output: a pooled buffer (``arr``, released on every failing path) or a plain one.
+    # No second name for it: analysis/resources.py follows a pooled buffer by name
     plain = np.empty(total, np.uint8) if out_pool is None or total == 0 else None
     arr: Optional[np.ndarray] = None
-    lit_arr: Optional[np.ndarray] = None
     if plain is None:
         arr = out_pool.acquire(bucket_size(total))
     try:
         t_lit = time.perf_counter_ns()
-        if arr is not None and blob_out_len is not None and lit_total:
-            lit_arr = out_pool.acquire(bucket_size(blob_out_len(lit_total)))
-        try:
-            t_blob = time.perf_counter_ns()
-            with blob_span:
-                lit = np.frombuffer(decode_blob(buf[off:]) if lit_arr is None else decode_blob(buf[off:], lit_arr), np.uint8)
-            blob_decode_ns = time.perf_counter_ns() - t_blob
-            if len(lit) != lit_total:
-                how = "shorter" if len(lit) < lit_total else "longer"
-                raise DedupIntegrityException(f"literal blob {how} than recipe entries")
-            if verify_literals and lit_fps:
-                _verify_literals(lit, lit_ends, lit_fps)
-            for fp, a, b in zip(lit_fps, (lit_ends - lit_lens).tolist(), lit_ends.tolist()):
-                store.put(fp, lit[a:b].tobytes())  # the store's own copy, never a view of pooled memory
-            # a run of consecutive literal entries is contiguous in the blob and in the output: one copy
-            run_heads = lit_idx[np.flatnonzero(np.diff(lit_idx, prepend=-2) != 1)]
-            run_tails = lit_idx[np.flatnonzero(np.diff(lit_idx, append=-2) != 1)]
-            src = 0
-            for at, end in zip(out_offs[run_heads].tolist(), (out_offs[run_tails] + lens[run_tails]).tolist()):
-                (plain if arr is None else arr)[at:end] = lit[src : src + end - at]
-                src += end - at
-        finally:
-            if lit_arr is not None:
-                out_pool.release(lit_arr)
+        # the decoded literals: one buffer a chunk that the store adopts, so never pooled memory. Where
+        # the codec writes into its caller's memory that is a fresh array; else what it returns, if that
+        # is ``bytes`` of its own, or one copy of it (a view of the payload, say)
+        lit_buf = np.empty(blob_out_len(lit_total), np.uint8) if blob_out_len is not None and lit_total else None
+        t_blob = time.perf_counter_ns()
+        with blob_span:
+            got = decode_blob(buf[off:]) if lit_buf is None else decode_blob(buf[off:], lit_buf)
+        blob_decode_ns = time.perf_counter_ns() - t_blob
+        if lit_buf is None:
+            if type(got) is not bytes:
+                got = bytes(got)
+            lit_buf = got
+        lit = np.frombuffer(got, np.uint8)
+        if len(lit) != lit_total:
+            how = "shorter" if len(lit) < lit_total else "longer"
+            raise DedupIntegrityException(f"literal blob {how} than recipe entries")
+        if verify_literals and lit_fps:
+            _verify_literals(lit, lit_ends, lit_fps)
+        store.put_blob(lit_buf, lit_fps, (lit_ends - lit_lens).tolist(), lit_ends.tolist())
+        # a run of consecutive literal entries is contiguous in the blob and in the output: one copy
+        run_heads = lit_idx[np.flatnonzero(np.diff(lit_idx, prepend=-2) != 1)]
+        run_tails = lit_idx[np.flatnonzero(np.diff(lit_idx, append=-2) != 1)]
+        src = 0
+        for at, end in zip(out_offs[run_heads].tolist(), (out_offs[run_tails] + lens[run_tails]).tolist()):
+            (plain if arr is None else arr)[at:end] = lit[src : src + end - at]
+            src += end - at
         if ref_stats is not None:
             ref_stats["literal_pass_ns"] = time.perf_counter_ns() - t_lit
             ref_stats["blob_decode_ns"] = blob_decode_ns

@@ -471,8 +471,8 @@ def _stores_equal(a: SegmentStore, b: SegmentStore, fps):
     for fp in fps:
         assert (fp in a) == (fp in b)
         if fp in a:
-            assert a.peek(fp) == b.peek(fp)
-            assert type(a.peek(fp)) is bytes, "the store keeps its own bytes, never a view"
+            assert bytes(a.peek(fp)) == bytes(b.peek(fp))
+            assert memoryview(b.peek(fp)).readonly, "a stored segment is bytes or a read-only view"
 
 
 CASES = ["all_literal", "all_ref", "interleaved", "own_chunk_repeat", "empty", "padded_last_block", "empty_literal"]
@@ -541,8 +541,9 @@ def test_flipped_literal_byte_admits_no_literal_of_the_chunk(where, pooled):
 
 
 def test_flipped_literal_byte_releases_the_codecs_buffer_too():
-    """(b) with a codec that decodes into a second pooled buffer (blockpack):
-    both buffers are back in the pool after the refusal."""
+    """(b) with a codec that decodes into memory its caller gives (blockpack):
+    the output buffer is back in the pool after the refusal, and the codec's
+    buffer (the one the store would have adopted) never came from it."""
     encode, decode, out_len = _codec_pair("tpu")
     segments, _ = _recipe_case("all_literal")
     wire, *_ = build_recipe(segments, SenderDedupIndex(), encode)
@@ -551,8 +552,9 @@ def test_flipped_literal_byte_releases_the_codecs_buffer_too():
     store, pool = SegmentStore(), BufferPool()
     with pytest.raises(DedupIntegrityException, match="fingerprint mismatch"):
         parse_recipe(bytes(bad), store, decode, verify_literals=True, out_pool=pool, blob_out_len=out_len)
-    assert store.mem_segment_count == 0
-    assert pool.counters()["pool_outstanding"] == 0 and pool.counters()["pool_recycled"] == 2
+    assert store.mem_segment_count == 0 and store.counters()["store_mem_bytes"] == 0
+    counters = pool.counters()
+    assert counters["pool_outstanding"] == 0 and counters["pool_recycled"] == 1 and counters["pool_misses"] == 1
 
 
 @pytest.mark.parametrize("native", [True, False], ids=["native", "host_fallback"])
@@ -660,3 +662,188 @@ def test_sender_side_bytes_are_the_parents(codec_name):
     data += r.integers(0, 256, 1234, dtype=np.uint8).tobytes()
     p = DataPathProcessor(codec_name=codec_name, dedup=True).process(data + data[:100_000], SenderDedupIndex())
     assert hashlib.blake2b(p.wire_bytes, digest_size=16).hexdigest() == SENDER_WIRE_DIGESTS[codec_name]
+
+
+# ------------------------------- one buffer a chunk: the store's views (PR 37)
+
+
+def _lit_buffer_len(segments, out_len):
+    """Bytes of the buffer the literal pass hands the store for an all-literal recipe."""
+    n = sum(len(d) for _, d in segments)
+    return out_len(n) if out_len is not None else n
+
+
+@pytest.mark.parametrize("codec_name", ["ident", "tpu", "tpu_zstd"])
+def test_store_holds_no_view_of_pooled_memory(codec_name):
+    """(a) two chunks decoded back to back through a pooled ``out_pool``; then
+    every buffer the pool ever handed out is written over: each stored
+    segment still equals its source bytes."""
+    encode, decode, out_len = _codec_pair(codec_name)
+    pool, store, handed = BufferPool(), SegmentStore(), []
+    acquire = pool.acquire
+
+    def acquire_and_note(bucket):
+        handed.append(acquire(bucket))
+        return handed[-1]
+
+    pool.acquire = acquire_and_note
+    chunks = [[_seg(n) for n in (1500, 700, 4096, 33)] for _ in range(2)]
+    for segments in chunks:
+        wire, *_ = build_recipe(segments, SenderDedupIndex(), encode)
+        raw = b"".join(d for _, d in segments)
+        out = parse_recipe(
+            wire, store, decode, verify_literals=True, out_pool=pool, expected_raw_len=len(raw), blob_out_len=out_len
+        )
+        assert bytes(out.view) == raw
+        out.release()
+    assert handed and pool.counters()["pool_outstanding"] == 0
+    for arr in handed:
+        arr[:] = 0xA5
+    for fp, data in (seg for segments in chunks for seg in segments):
+        assert bytes(store.get(fp)) == data
+
+
+@pytest.mark.parametrize("codec_name", ["ident", "tpu"])
+def test_store_charges_a_buffer_once_and_credits_it_with_its_last_segment(codec_name, tmp_path):
+    """(b) after one chunk the store is charged the buffer's length; the charge
+    holds while all but one of the buffer's segments are evicted, and goes in
+    full with the last."""
+    encode, decode, out_len = _codec_pair(codec_name)
+    store = SegmentStore(spill_dir=tmp_path / "spill")
+    segments = [_seg(n) for n in (900, 1300, 70, 2048)]
+    wire, *_ = build_recipe(segments, SenderDedupIndex(), encode)
+    parse_recipe(wire, store, decode, verify_literals=True, blob_out_len=out_len)
+    blob_len = _lit_buffer_len(segments, out_len)
+    c = store.counters()
+    assert c["store_mem_bytes"] == c["store_blob_bytes"] == blob_len
+    lone_fp, lone = _seg(100)
+    store.put(lone_fp, lone)
+    assert store.counters()["store_mem_bytes"] == blob_len + len(lone)
+    keep_fp = segments[2][0]
+    store.get(keep_fp)  # the newest now: evicted last
+    store.set_bounds(max_bytes=blob_len)  # the buffer's other three go (no credit), then the lone segment
+    c = store.counters()
+    assert store.mem_segment_count == 1 and c["store_mem_evictions"] == len(segments)
+    assert c["store_mem_bytes"] == c["store_blob_bytes"] == blob_len
+    store.set_bounds(max_bytes=blob_len - 1)  # the last segment of the buffer goes: the whole length is credited
+    c = store.counters()
+    assert store.mem_segment_count == 0 and c["store_mem_bytes"] == c["store_blob_bytes"] == 0
+    for fp, data in segments + [(lone_fp, lone)]:
+        assert bytes(store.get(fp)) == data  # from spill
+
+
+def test_a_resident_fingerprint_takes_no_view_of_the_new_buffer():
+    """(c) a literal whose fingerprint the store already holds keeps the held
+    bytes; a chunk whose every literal is resident charges nothing."""
+    store = SegmentStore()
+    segments = [_seg(n) for n in (800, 600, 1200)]
+    held = bytes(segments[1][1])
+    store.put(segments[1][0], held)
+    wire, *_ = build_recipe(segments, SenderDedupIndex(), ident)  # all three as literals
+    parse_recipe(wire, store, ident, verify_literals=True)
+    assert store.peek(segments[1][0]) is held
+    c = store.counters()
+    assert (c["store_blobs"], c["store_blob_segments"]) == (1, 2)
+    assert c["store_mem_bytes"] == len(held) + 2600 and c["store_blob_bytes"] == 2600
+    parse_recipe(wire, store, ident, verify_literals=True)  # every fingerprint resident now
+    assert store.counters() == c
+    assert store.mem_segment_count == 3
+
+
+@pytest.mark.parametrize("codec_name", ["ident", "tpu"])
+def test_a_segment_spilled_and_promoted_is_its_own_bytes_charged_alone(codec_name, tmp_path):
+    """(d) every segment of a chunk evicted to spill, one promoted back: it
+    resolves byte for byte, as ``bytes`` of its own, charged its length."""
+    encode, decode, out_len = _codec_pair(codec_name)
+    store = SegmentStore(spill_dir=tmp_path / "spill")
+    segments = [_seg(n) for n in (700, 1900, 256)]
+    wire, *_ = build_recipe(segments, SenderDedupIndex(), encode)
+    parse_recipe(wire, store, decode, verify_literals=True, blob_out_len=out_len)
+    store.flush_to_spill()
+    assert store.mem_segment_count == 0 and store.counters()["store_mem_bytes"] == 0
+    fp, data = segments[1]
+    got = store.get(fp)
+    assert type(got) is bytes and got == data
+    c = store.counters()
+    assert c["store_promotions"] == 1 and c["store_mem_bytes"] == len(data) and c["store_blob_bytes"] == 0
+
+
+def test_store_blob_counters_count_one_buffer_a_chunk_with_literals(tmp_path):
+    """(e) through a live receiver: a chunk with literals admits one buffer and
+    as many views as it has literal entries; a chunk of REFs alone admits none.
+    The three counters are in the stable decode schema."""
+    for key in ("store_blobs", "store_blob_segments", "store_blob_bytes"):
+        assert DECODE_COUNTER_ZERO[key] == 0
+    r, store, ev, port = _mk_receiver(tmp_path, decode_workers=2)
+    try:
+        s1, s2, s3, s4 = _seg(700), _seg(300), _seg(1100), _seg(450)
+        index = SenderDedupIndex()
+        index.add(s2[0], len(s2[1]))
+        mixed, *_ = build_recipe([s4, s2], index, ident)  # one literal, one REF
+        mixed_frame = (
+            WireProtocolHeader(chunk_id=uuid.uuid4().hex, data_len=len(mixed), raw_data_len=len(s4[1] + s2[1]),
+                               flags=int(ChunkFlags.RECIPE)),
+            mixed, s4[1] + s2[1],
+        )
+        frames = [_literal_frame([s1, s2, s3]), _ref_frame(s1[0], len(s1[1]), s1[1]), mixed_frame]
+        assert _send_frames(port, frames) == ACK_BYTE * 3
+        c = r.decode_counters()
+        assert (c["store_blobs"], c["store_blob_segments"]) == (2, 4)
+        assert c["store_blob_bytes"] == c["store_mem_bytes"] == sum(len(s[1]) for s in (s1, s2, s3, s4))
+    finally:
+        r.stop_all()
+
+
+def test_blob_accounting_holds_under_concurrent_puts_and_evictions(tmp_path):
+    """Many threads admit buffers (some segments shared between them) into a
+    store small enough that eviction to spill runs all the time, with a short
+    switch interval: the charge always equals what the memory tier holds,
+    every segment reads back, and emptying the tier credits everything."""
+    import os
+    import sys
+
+    store = SegmentStore(max_bytes=1_500, spill_dir=tmp_path / "spill", stripes=4)  # under one buffer: new views get evicted too
+    common = [rng.integers(0, 256, 300, dtype=np.uint8).tobytes() for _ in range(6)]
+    errors = []
+
+    def worker(w):
+        try:
+            r = np.random.default_rng(1000 + w)
+            for k in range(20):
+                parts = [common[(w + k) % len(common)]] + [r.integers(0, 256, int(n), dtype=np.uint8).tobytes() for n in r.integers(1, 700, 6)]
+                buf = b"".join(parts)
+                ends = np.cumsum([len(p) for p in parts]).tolist()
+                starts = [e - len(p) for e, p in zip(ends, parts)]
+                fps = [segment_fingerprint_host(p) for p in parts]
+                store.put_blob(buf, fps, starts, ends)
+                for fp, p in zip(fps, parts):
+                    assert bytes(store.get(fp)) == p
+        except BaseException as e:  # noqa: BLE001 — handed to the main thread
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=worker, args=(w,), daemon=True) for w in range((os.cpu_count() or 4) + 4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads) and not errors, errors
+    blobs, alone, live = {}, 0, {}
+    for s in store._stripes:
+        for data, _, blob in s.mem.values():
+            if blob is None:
+                alone += len(data)
+            else:
+                blobs[id(blob)] = blob
+                live[id(blob)] = live.get(id(blob), 0) + 1
+    assert all(blob.live == live[key] for key, blob in blobs.items())
+    c = store.counters()
+    assert c["store_blob_bytes"] == sum(b.nbytes for b in blobs.values())
+    assert c["store_mem_bytes"] == alone + c["store_blob_bytes"]
+    store.set_bounds(max_bytes=1)
+    c = store.counters()
+    assert store.mem_segment_count == 0 and c["store_mem_bytes"] == c["store_blob_bytes"] == 0
