@@ -12,8 +12,11 @@ Used by the devloop trace-smoke step on the trace bench.py exports
      inside parent, small tolerance for clock granularity) or are disjoint —
      partial overlap means broken span scoping;
   4. correlation: at least one chunk id appears on BOTH a sender-side span
-     (cat "sender") and a receiver-side span (cat "receiver") — the
-     cross-wire stitching the TRACED header flag exists for.
+     and a receiver-side span — the cross-wire stitching the TRACED header
+     flag exists for. A span's side is its category ("sender", "receiver"):
+     the envelopes of a chunk (``wire.frame``, ``decode``) carry one. A step
+     of the round (category "device", written into the device profile)
+     takes the side of its chunk's envelope at the same gateway.
 
 With ``--multihop`` (the collector-merged fleet timeline of a relayed
 transfer, docs/observability.md), additionally:
@@ -38,11 +41,34 @@ import sys
 from collections import defaultdict
 
 NEST_TOLERANCE_US = 5.0  # wall-clock ts vs perf-counter dur granularity skew
+SIDES = ("sender", "receiver")
+PROFILE_CAT = "device"  # steps of a chunk's round: no side of their own (obs/tracer.py PROFILE_CAT)
 
 
 def fail(msg: str) -> int:
     print(f"trace-smoke: {msg}", file=sys.stderr)
     return 1
+
+
+def span_sides(events: list):
+    """(event, sides) of every event that names a chunk: a sender- or
+    receiver-category span has its own category; a step of the round takes
+    the sides its chunk's envelopes have at the same ``args.gateway``."""
+    envelope = defaultdict(set)  # (chunk id, gateway) -> sides
+    for ev in events:
+        args = ev.get("args") or {}
+        if args.get("chunk_id") and ev.get("cat") in SIDES:
+            envelope[(args["chunk_id"], args.get("gateway"))].add(ev["cat"])
+    for ev in events:
+        args = ev.get("args") or {}
+        cid = args.get("chunk_id")
+        if not cid:
+            continue
+        cat = ev.get("cat")
+        if cat in SIDES:
+            yield ev, {cat}
+        elif cat == PROFILE_CAT:
+            yield ev, envelope.get((cid, args.get("gateway")), set())
 
 
 def validate_multihop(trace: dict) -> int:
@@ -60,17 +86,17 @@ def validate_multihop(trace: dict) -> int:
         )
     per_chunk: dict = {}
     hops = set()
-    for ev in events:
-        args = ev.get("args") or {}
-        cid, gw = args.get("chunk_id"), args.get("gateway")
-        if ev.get("cat") == "sender" and isinstance(args.get("hop"), int):
+    for ev, sides in span_sides(events):
+        args = ev["args"]
+        cid, gw = args["chunk_id"], args.get("gateway")
+        if "sender" in sides and isinstance(args.get("hop"), int):
             hops.add(args["hop"])
-        if not cid or not gw:
+        if not gw:
             continue
         entry = per_chunk.setdefault(cid, {"gateways": set(), "sender": set(), "receiver": set()})
         entry["gateways"].add(gw)
-        if ev.get("cat") in ("sender", "receiver"):
-            entry[ev["cat"]].add(gw)
+        for side in sides:
+            entry[side].add(gw)
     full_path = [
         cid
         for cid, e in per_chunk.items()
@@ -144,12 +170,10 @@ def validate(trace: dict, multihop: bool = False) -> int:
             stack.append((end, name))
 
     # 4: sender<->receiver correlation by chunk id
-    sides = defaultdict(set)  # chunk_id -> {cats}
-    for ev in events:
-        cid = (ev.get("args") or {}).get("chunk_id")
-        if cid:
-            sides[cid].add(ev.get("cat", ""))
-    stitched = [cid for cid, cats in sides.items() if "sender" in cats and "receiver" in cats]
+    sides = defaultdict(set)  # chunk_id -> {sides}
+    for ev, ev_sides in span_sides(events):
+        sides[ev["args"]["chunk_id"]] |= ev_sides
+    stitched = [cid for cid, found in sides.items() if "sender" in found and "receiver" in found]
     if not stitched:
         return fail(
             "no chunk id appears on both sender- and receiver-side spans — the TRACED wire-flag "

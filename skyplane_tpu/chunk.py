@@ -38,7 +38,7 @@ import socket
 from dataclasses import dataclass, field, asdict
 from enum import Enum, IntEnum, auto
 from functools import total_ordering
-from typing import Optional
+from typing import ClassVar, Optional
 
 from skyplane_tpu.exceptions import SkyplaneTpuException
 
@@ -212,6 +212,13 @@ class ChunkRequest:
     src_random_size_mb: Optional[int] = None
     src_object_store_bucket: Optional[str] = None
     dst_object_store_bucket: Optional[str] = None
+
+    # this gateway's clocks of the request's round (``perf_counter_ns``; 0 =
+    # not stamped). Not fields: they never cross to another process
+    accepted_ns: ClassVar[int] = 0  # the control API accepted it (ChunkStore.add_chunk_request)
+    queued_ns: ClassVar[int] = 0  # put on an operator queue, or its sender's window was registered
+    since_ns: ClassVar[int] = 0  # sink: its frame header was read
+    done_ns: ClassVar[int] = 0  # sink: the receiver marked it ``.done``
 
     def as_dict(self) -> dict:
         return asdict(self)

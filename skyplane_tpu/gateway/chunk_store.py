@@ -14,6 +14,14 @@ Entries are refcounted: :meth:`sealed_open` hands out a
 (:meth:`sealed_discard`, driven by the daemon's terminal-chunk sweep)
 defers the unlink until the last borrow closes — the same
 in_progress→terminal discipline the chunk accounting protocol enforces.
+
+Round counters: the steps of a chunk's round that belong to no one processor
+(the operators' queues and file I/O, the registration POST, the residence
+envelopes, the hand-off from receiver to write operator) count into
+:attr:`ChunkStore.source_round` and :attr:`ChunkStore.sink_round`, the one
+object every operator and the receiver of a gateway share
+(``obs/stage.py``; served by ``/profile/compression`` and
+``/profile/decode``).
 """
 
 from __future__ import annotations
@@ -31,9 +39,25 @@ from skyplane_tpu.chunk import ChunkRequest, ChunkState
 from skyplane_tpu.gateway.gateway_queue import GatewayQueue
 from skyplane_tpu.utils.logger import logger
 from skyplane_tpu.obs import lockwitness as lockcheck
+from skyplane_tpu.obs.stage import StageCounters
 
 SEALED_SUFFIX = ".sealed"
 SEALED_META_SUFFIX = ".sealed.meta"
+
+#: the source's steps of a chunk's round counted by its operators: from the
+#: control API accepting the request to the ack that delivers it
+#: (``residence_ns``), the waits in operator queues and in a sender's window
+#: (``queue_wait_ns``), reading the source file and the staged chunk
+#: (``io_ns``), and the registration POST to the next hop (``register_ns``)
+SOURCE_ROUND_KEYS = ("residence_ns", "queue_wait_ns", "io_ns", "register_ns")
+#: the sink's: from the frame header to write_local's ``complete``
+#: (``residence_ns``), the receive of the payload (``recv_ns``), the waits on
+#: the decode pool and for the ack's turn (``queue_wait_ns``), ``cipher.open``
+#: (``open_ns``), landing the chunk file (``land_ns``), from ``.done`` to the
+#: write operator's start (``handoff_ns``: the wait receiver's poll and the
+#: write operator's queue) and the write itself (``write_local_ns``)
+SINK_ROUND_KEYS = ("residence_ns", "recv_ns", "queue_wait_ns", "open_ns", "land_ns", "handoff_ns", "write_local_ns")
+MAX_LANDED = 4096  # landed chunks no wait operator has taken yet (a pump worker's receiver marks chunks no operator of its process takes)
 
 
 class SealedFrameRef:
@@ -91,6 +115,11 @@ class ChunkStore:
         # staged-file fds the pump parent passed over the ctrl channel
         # (SCM_RIGHTS): adopted here, popped once at frame time
         self._adopted_fds: Dict[str, int] = {}
+        self.source_round = StageCounters(SOURCE_ROUND_KEYS)
+        self.sink_round = StageCounters(SINK_ROUND_KEYS)
+        # chunk_id -> (frame header clock, .done clock) of a landed chunk,
+        # until the wait operator takes it for the write operator's counters
+        self._landed: Dict[str, tuple] = {}
 
     def add_partition(self, partition_id: str, inbound_queue: GatewayQueue) -> None:
         if partition_id in self.chunk_requests:
@@ -101,6 +130,7 @@ class ChunkStore:
         partition = chunk_req.chunk.partition_id
         if partition not in self.chunk_requests:
             raise ValueError(f"unknown partition {partition} (known: {list(self.chunk_requests)})")
+        chunk_req.accepted_ns = time.perf_counter_ns()
         self.log_chunk_state(chunk_req, state)
         self.chunk_requests[partition].put(chunk_req)
 
@@ -126,6 +156,23 @@ class ChunkStore:
 
     def chunk_path(self, chunk_id: str) -> Path:
         return self.chunk_dir / f"{chunk_id}.chunk"
+
+    def mark_done(self, chunk_id: str, since_ns: int, done_ns: int) -> None:
+        """Expose a landed chunk to the operators downstream of the receiver
+        (its ``.done`` marker), noting when its frame header was read and
+        when it was marked, for :meth:`take_landed`."""
+        with self._lock:
+            if len(self._landed) >= MAX_LANDED:
+                self._landed.pop(next(iter(self._landed)))
+            self._landed[chunk_id] = (since_ns, done_ns)
+        self.chunk_path(chunk_id).with_suffix(".done").touch()
+
+    def take_landed(self, chunk_id: str) -> Optional[tuple]:
+        """``(header_ns, done_ns)`` of a chunk :meth:`mark_done` marked in
+        this process, once; None where the receiver ran elsewhere (a pump
+        worker process) or the note was dropped."""
+        with self._lock:
+            return self._landed.pop(chunk_id, None)
 
     def remaining_bytes(self) -> int:
         return shutil.disk_usage(self.chunk_dir).free

@@ -706,6 +706,13 @@ class GatewayDaemon:
             hot_path.update(self.batch_runner.counters())
         agg["compression_ratio"] = (agg["raw_bytes"] / agg["wire_bytes"]) if agg["wire_bytes"] else 1.0
         agg.update(hot_path)
+        # the source's steps of a chunk's round outside the processors: the
+        # operators' (ChunkStore.source_round) and the wire engines'
+        agg.update(self.chunk_store.source_round.totals())
+        wire = self._sender_wire_counters()
+        agg["send_ns"] = wire["send_ns"]
+        agg["ack_lag_ns"] = wire["ack_lag_ns"]
+        agg["queue_wait_ns"] += wire["frame_wait_ns"]
         return agg
 
     def _build_operators(self, program: dict) -> None:

@@ -3,11 +3,14 @@
 Reference parity: skyplane/gateway/gateway_queue.py:4-62 (GatewayQueue fan-in
 / GatewayANDQueue multicast replication). Thread-based queues (queue.Queue)
 instead of multiprocessing.Queue — operators are threads in this runtime.
+A put stamps the request's ``queued_ns``: the operator that takes it counts
+the wait where the wait is a step of the source's round (gateway_operator.py).
 """
 
 from __future__ import annotations
 
 import queue
+import time
 from typing import Dict, List, Optional
 
 from skyplane_tpu.chunk import ChunkRequest
@@ -24,6 +27,7 @@ class GatewayQueue:
         self.handles.append(handle)
 
     def put(self, chunk_req: ChunkRequest) -> None:
+        chunk_req.queued_ns = time.perf_counter_ns()
         self.q.put(chunk_req)
 
     def put_for_handle(self, handle: str, chunk_req: ChunkRequest) -> None:
@@ -31,7 +35,7 @@ class GatewayQueue:
 
         On a shared (OR) queue this is a plain put — any competing sibling may
         legitimately pick the chunk up."""
-        self.q.put(chunk_req)
+        self.put(chunk_req)
 
     def pop(self, requester_handle: str = "", timeout: Optional[float] = None) -> ChunkRequest:
         return self.q.get(timeout=timeout) if timeout else self.q.get_nowait()
@@ -60,7 +64,7 @@ class GatewayANDQueue(GatewayQueue):
 
     def put(self, chunk_req: ChunkRequest) -> None:
         for handle in self.handles:
-            self.subqueues[handle].put(chunk_req)
+            self.subqueues[handle].put(chunk_req)  # stamps queued_ns
 
     def put_for_handle(self, handle: str, chunk_req: ChunkRequest) -> None:
         """Requeue to one branch's sub-queue without re-multicasting."""

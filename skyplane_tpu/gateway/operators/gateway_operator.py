@@ -35,6 +35,7 @@ from skyplane_tpu.exceptions import SkyplaneTpuException
 from skyplane_tpu.faults import get_injector
 from skyplane_tpu.gateway.operators.gateway_receiver import ACK_BYTE, NACK_UNRESOLVED, put_drop_oldest
 from skyplane_tpu.obs import NOOP_SPAN, get_registry, get_tracer
+from skyplane_tpu.obs.stage import Stage
 from skyplane_tpu.gateway.operators.sender_wire import (
     RECONNECT_POLICY,
     EngineCallbacks,
@@ -79,6 +80,9 @@ class GatewayOperator:
     """Base operator: thread pool + worker loop (reference :32-122)."""
 
     log_in_progress = True  # poll-style operators override to avoid log spam
+    #: the wait in this operator's input queue is a step of the source's
+    #: round (the read and send operators): counted as ``queue_wait_ns``
+    counts_queue_wait = False
 
     def __init__(
         self,
@@ -130,6 +134,11 @@ class GatewayOperator:
                 batch = self._drain_batch()
                 if not batch:
                     continue
+                if self.counts_queue_wait:
+                    now = time.perf_counter_ns()
+                    for chunk_req in batch:
+                        if chunk_req.queued_ns:
+                            self.chunk_store.source_round.add("queue_wait_ns", now - chunk_req.queued_ns)
                 if self.log_in_progress:
                     for chunk_req in batch:
                         # sklint: disable=resource-leak-on-path -- ownership transfer: when process_batch returns None the batch moved into a streaming pipeline (pipelined sender) whose ack path performs the terminal complete/requeue/failed accounting
@@ -208,6 +217,10 @@ class GatewayWaitReceiverOperator(GatewayOperator):
         chunk_id = chunk_req.chunk.chunk_id
         done_marker = self.chunk_store.chunk_path(chunk_id).with_suffix(".done")
         if done_marker.exists():
+            # the receiver's clocks of the chunk ride on to the write operator
+            landed = self.chunk_store.take_landed(chunk_id)
+            if landed is not None:
+                chunk_req.since_ns, chunk_req.done_ns = landed
             return True
         time.sleep(self.CHECK_INTERVAL)
         return False  # re-queue until the receiver finishes
@@ -232,15 +245,22 @@ class GatewayRandomDataGenOperator(GatewayOperator):
 class GatewayReadLocalOperator(GatewayOperator):
     """Reads a byte range of a local (POSIX) source file into the chunk store."""
 
+    counts_queue_wait = True
+
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        self._t_read = Stage(self.chunk_store.source_round.add, "io_ns", "chunk.read")
+
     def process(self, chunk_req: ChunkRequest, worker_id: int) -> bool:
         chunk = chunk_req.chunk
         offset = chunk.file_offset_bytes or 0
-        with open(chunk.src_key, "rb") as f:
-            f.seek(offset)
-            data = f.read(chunk.chunk_length_bytes)
-        if len(data) != chunk.chunk_length_bytes:
-            raise IOError(f"short read on {chunk.src_key}: {len(data)} != {chunk.chunk_length_bytes}")
-        self.chunk_store.chunk_path(chunk.chunk_id).write_bytes(data)
+        with self._t_read(chunk.chunk_id, force=bool(chunk.traced)):
+            with open(chunk.src_key, "rb") as f:
+                f.seek(offset)
+                data = f.read(chunk.chunk_length_bytes)
+            if len(data) != chunk.chunk_length_bytes:
+                raise IOError(f"short read on {chunk.src_key}: {len(data)} != {chunk.chunk_length_bytes}")
+            self.chunk_store.chunk_path(chunk.chunk_id).write_bytes(data)
         return True
 
 
@@ -266,6 +286,7 @@ class GatewayWriteLocalOperator(GatewayOperator):
         self.root = root
         self._fd_lock = threading.Lock()
         self._fds: "OrderedDict[str, list]" = OrderedDict()  # dest -> [fd, refcount]
+        self._t_write = Stage(self.chunk_store.sink_round.add, "write_local_ns", "chunk.write_local")
 
     def _dest_path(self, dest_key: str) -> Path:
         if not self.root:
@@ -323,9 +344,8 @@ class GatewayWriteLocalOperator(GatewayOperator):
         span_args = (
             {"gateway": self.gateway_id, "hop": chunk.hop} if (tracer.enabled and self.gateway_id) else None
         )
-        with tracer.span(
-            "chunk.write_local", trace_id=chunk.chunk_id, cat="receiver", force=bool(chunk.traced), args=span_args
-        ):
+        t = self._t_write
+        with t(chunk.chunk_id, force=bool(chunk.traced), args=span_args):
             data = self.chunk_store.chunk_path(chunk.chunk_id).read_bytes()
             dest = self._dest_path(chunk.dest_key)
             offset = chunk.file_offset_bytes or 0
@@ -337,6 +357,12 @@ class GatewayWriteLocalOperator(GatewayOperator):
                     written += os.pwrite(fd, view[written:], offset + written)
             finally:
                 self._release_fd(dest)
+        # the sink's round: from .done to this write (the wait operator's poll
+        # and this operator's queue), and from the frame header to here
+        if chunk_req.done_ns:
+            self.chunk_store.sink_round.add("handoff_ns", t.started_ns - chunk_req.done_ns)
+        if chunk_req.since_ns:
+            self.chunk_store.sink_round.add("residence_ns", t.ended_ns - chunk_req.since_ns)
         return True
 
 
@@ -504,6 +530,8 @@ class _SenderEngineOps(EngineCallbacks):
 
     def on_delivered(self, frame) -> None:
         op = self.op
+        if frame.req.accepted_ns:
+            op.chunk_store.source_round.add("residence_ns", time.perf_counter_ns() - frame.req.accepted_ns)
         tenant = frame.req.chunk.tenant_id or DEFAULT_TENANT_ID
         if op.dedup_index is not None:
             # the ack means the chunk (and its dedup literals) is durably
@@ -602,6 +630,8 @@ class GatewaySenderOperator(GatewayOperator):
     The payload runs through DataPathProcessor (codec + dedup) and optional
     AES-GCM seal.
     """
+
+    counts_queue_wait = True
 
     def __init__(
         self,
@@ -724,8 +754,13 @@ class GatewaySenderOperator(GatewayOperator):
         # one stateless raw engine serves the serial path (pipelined workers
         # use their wire engine's); serial raw counters merge in wire_counters
         self._raw_serial = RawForwardEngine()
-        self._serial_raw_lock = threading.Lock()
-        self._serial_raw = {"wire_raw_frames": 0, "wire_raw_bytes": 0, "wire_raw_fallbacks": 0}
+        self._serial_wire_lock = threading.Lock()
+        self._serial_wire = {"wire_raw_frames": 0, "wire_raw_bytes": 0, "wire_raw_fallbacks": 0, "send_ns": 0}
+        # the steps of a chunk's round this operator runs (obs/stage.py)
+        self._t_load = Stage(self.chunk_store.source_round.add, "io_ns", "chunk.load")  # the staged chunk, read to frame it
+        self._t_register = Stage(self.chunk_store.source_round.add, "register_ns", "chunk.register")
+        self._t_seal = Stage(self.processor.stats.add, "seal_ns", "wire.seal")
+        self._t_send_serial = Stage(self._bump_serial_wire, "send_ns", "wire.send")
         # per-(src,dst)-edge egress bytes, keyed by target gateway id at the
         # moment the bytes hit the socket (retargets start a new key) — the
         # counter-measured source of skyplane_egress_bytes_total{src,dst}
@@ -970,8 +1005,8 @@ class GatewaySenderOperator(GatewayOperator):
             counters = engine.counters()
             for k in out:
                 out[k] += counters.get(k, 0)
-        with self._serial_raw_lock:
-            for k, v in self._serial_raw.items():
+        with self._serial_wire_lock:
+            for k, v in self._serial_wire.items():
                 out[k] += v
         with self._events_dropped_lock:
             out["profile_events_dropped"] += self._events_dropped
@@ -1124,9 +1159,17 @@ class GatewaySenderOperator(GatewayOperator):
         except OSError as e:
             logger.fs.warning(f"[{self.handle}] sealed-frame staging failed for {chunk.chunk_id}: {e}")
 
-    def _bump_serial_raw(self, key: str, n: int = 1) -> None:
-        with self._serial_raw_lock:
-            self._serial_raw[key] += n
+    def _bump_serial_wire(self, key: str, n: int = 1) -> None:
+        with self._serial_wire_lock:
+            self._serial_wire[key] += n
+
+    def _count_window_wait(self, chunk_req: ChunkRequest) -> None:
+        """The chunk's wait in its sender's window, from the registration
+        POST to the start of its ``chunk.load``: the chunks framed before it
+        and the fair-share gate. Counted once a registration."""
+        if chunk_req.queued_ns:
+            self.chunk_store.source_round.add("queue_wait_ns", self._t_load.started_ns - chunk_req.queued_ns)
+            chunk_req.queued_ns = 0
 
     def _frame_chunk(self, chunk_req: ChunkRequest, view: Optional[_WindowFpView], n_left: int):
         """Build (payload, wire, header) for one chunk. payload is None on the
@@ -1143,9 +1186,12 @@ class GatewaySenderOperator(GatewayOperator):
                 pass
         fpath = self.chunk_store.chunk_path(chunk.chunk_id)
         hdr_sidecar = fpath.with_suffix(".hdr")
+        traced = bool(chunk.traced)
         if hdr_sidecar.exists():
             meta = json.loads(hdr_sidecar.read_text())
-            wire = fpath.read_bytes()
+            with self._t_load(chunk.chunk_id, force=traced):
+                wire = fpath.read_bytes()
+            self._count_window_wait(chunk_req)
             return None, wire, WireProtocolHeader(
                 chunk_id=chunk.chunk_id,
                 data_len=len(wire),
@@ -1156,16 +1202,17 @@ class GatewaySenderOperator(GatewayOperator):
                 n_chunks_left_on_socket=n_left,
                 tenant_id=meta.get("tenant", DEFAULT_TENANT_ID),
             )
-        data = fpath.read_bytes()
+        with self._t_load(chunk.chunk_id, force=traced):
+            data = fpath.read_bytes()
+        self._count_window_wait(chunk_req)
         payload = self.processor.process(data, view if view is not None else self.dedup_index, trace_id=chunk.chunk_id)
         if view is not None:
             # later chunks in this window may REF these (in-order socket)
             view.pending.update(fp for fp, _ in payload.new_fingerprints)
         wire = payload.wire_bytes
         if self.cipher is not None:
-            t_seal = time.perf_counter_ns()
-            wire = self.cipher.seal(wire)
-            self.processor.stats.observe_seal(time.perf_counter_ns() - t_seal)
+            with self._t_seal(chunk.chunk_id, force=traced):
+                wire = self.cipher.seal(wire)
         chunk.fingerprint = payload.fingerprint
         header = chunk.to_wire_header(
             n_chunks_left_on_socket=n_left,
@@ -1221,7 +1268,11 @@ class GatewaySenderOperator(GatewayOperator):
 
     def process_batch(self, batch: List[ChunkRequest], worker_id: int) -> Optional[List[bool]]:
         gen0 = self._target_gen
-        self._register_batch(batch)
+        with self._t_register():  # one POST for the window: no chunk's span
+            self._register_batch(batch)
+        registered = self._t_register.ended_ns
+        for req in batch:
+            req.queued_ns = registered  # from here each waits its turn in the window
         if not self.pipelined:
             results = self._process_batch_serial(batch, worker_id)
             self._reregister_if_retargeted(batch, gen0)
@@ -1383,18 +1434,7 @@ class GatewaySenderOperator(GatewayOperator):
                         header.flags |= ChunkFlags.TRACED
                 elif traced and payload is not None:
                     header.flags |= ChunkFlags.TRACED  # receiver spans follow the sender's sample
-                send_span = (
-                    tracer.span(
-                        "wire.send",
-                        trace_id=req.chunk.chunk_id,
-                        cat="sender",
-                        force=True,
-                        args=self._frame_span_args(req),
-                    )
-                    if traced
-                    else NOOP_SPAN
-                )
-                with send_span:
+                with self._t_send_serial(req.chunk.chunk_id, force=traced, args=self._frame_span_args(req) if traced else None):
                     if raw is not None:
                         try:
                             self._raw_serial.send(sock, header.to_bytes(), source)
@@ -1404,12 +1444,12 @@ class GatewaySenderOperator(GatewayOperator):
                             # socket-error handler (reset + requeue unacked)
                             # with raw disabled for this worker from now on
                             self._local.raw_ok = False
-                            self._bump_serial_raw("wire_raw_fallbacks")
+                            self._bump_serial_wire("wire_raw_fallbacks")
                             raise
                         finally:
                             source.release()
-                        self._bump_serial_raw("wire_raw_frames")
-                        self._bump_serial_raw("wire_raw_bytes", source.length)
+                        self._bump_serial_wire("wire_raw_frames")
+                        self._bump_serial_wire("wire_raw_bytes", source.length)
                         sent_len = source.length
                     else:
                         # vectored codec send: header as the iovec prefix,
@@ -1435,6 +1475,8 @@ class GatewaySenderOperator(GatewayOperator):
             for i, req, payload in sent:
                 ack = sock.recv(1)
                 if ack == ACK_BYTE:
+                    if req.accepted_ns:
+                        self.chunk_store.source_round.add("residence_ns", time.perf_counter_ns() - req.accepted_ns)
                     if self.dedup_index is not None and payload is not None:
                         for fp, size in payload.new_fingerprints:
                             self.dedup_index.add(fp, size, tenant=req.chunk.tenant_id or DEFAULT_TENANT_ID)

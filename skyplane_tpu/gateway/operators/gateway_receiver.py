@@ -47,9 +47,10 @@ from skyplane_tpu.chunk import WireProtocolHeader
 from skyplane_tpu.exceptions import DedupIntegrityException, SkyplaneTpuException
 from skyplane_tpu.faults import get_injector
 from skyplane_tpu.gateway.cert import generate_self_signed_certificate
-from skyplane_tpu.gateway.chunk_store import ChunkStore
+from skyplane_tpu.gateway.chunk_store import SINK_ROUND_KEYS, ChunkStore
 from skyplane_tpu.gateway.crypto import ChunkCipher
 from skyplane_tpu.obs import NOOP_SPAN, get_registry, get_tracer
+from skyplane_tpu.obs.stage import Stage
 from skyplane_tpu.ops.dedup import PooledChunk, SegmentStore
 from skyplane_tpu.ops.pipeline import DataPathProcessor
 from skyplane_tpu.utils.logger import logger
@@ -102,6 +103,8 @@ DECODE_COUNTER_ZERO = {
     "verify_batched": 0,
     "decode_events_dropped": 0,
     "socket_events_dropped": 0,
+    # the steps of a chunk's round (ChunkStore.sink_round, obs/stage.py)
+    **dict.fromkeys(SINK_ROUND_KEYS, 0),
 }
 
 
@@ -135,12 +138,21 @@ def put_drop_oldest(q: "queue.Queue[dict]", event: dict) -> bool:
 class _DecodeTask:
     """One framed chunk handed from a connection's framing loop to the pool."""
 
-    __slots__ = ("header", "payload", "state", "done", "outcome", "detail", "raw_len", "decode_ns", "fpath")
+    __slots__ = (
+        "header", "payload", "state", "done", "outcome", "detail", "raw_len", "decode_ns", "fpath",
+        "since_ns", "received_ns", "finished_ns",
+    )
 
-    def __init__(self, header: WireProtocolHeader, payload: bytes, state: "_ConnState"):
+    def __init__(self, header: WireProtocolHeader, payload: bytes, state: "_ConnState", since_ns: int = 0, received_ns: int = 0):
         self.header = header
         self.payload = payload
         self.state = state
+        # clocks of the sink's round (perf_counter_ns): the payload's receive
+        # started (its header was read) and ended (the task goes to the pool),
+        # and the decode finished
+        self.since_ns = since_ns
+        self.received_ns = received_ns
+        self.finished_ns = 0
         self.done = False  # set (under state.lock) when the worker finished
         self.outcome = "fatal"  # ack | nack | payload_error | fatal
         self.detail = ""
@@ -350,6 +362,11 @@ class GatewayReceiver:
             "literal_segments_verified": 0,
             "literal_verify_calls": 0,
         }
+        # the receiver's steps of a chunk's round (obs/stage.py)
+        self._round = chunk_store.sink_round
+        self._t_recv = Stage(self._round.add, "recv_ns", "frame.recv")
+        self._t_open = Stage(self._round.add, "open_ns", "decode.open")
+        self._t_land = Stage(self._round.add, "land_ns", "store.write")
         self._decode_threads: List[threading.Thread] = []
         for i in range(decode_workers):
             t = threading.Thread(target=self._decode_worker, name=f"receiver-decode-{i}", daemon=True)
@@ -526,20 +543,9 @@ class GatewayReceiver:
                     header = WireProtocolHeader.from_socket(conn)
                 except (ConnectionError, OSError):
                     break  # clean peer close
-                t0 = time.time()
-                recv_span = (
-                    get_tracer().span(
-                        "frame.recv",
-                        trace_id=header.chunk_id,
-                        cat="receiver",
-                        force=header.is_traced,
-                        args=self._span_args,
-                    )
-                    if get_tracer().enabled
-                    else NOOP_SPAN
-                )
+                t = self._t_recv
                 try:
-                    with recv_span:
+                    with t(header.chunk_id, force=header.is_traced, args=self._span_args):
                         payload = self._recv_exact(conn, header.data_len)
                 except (ConnectionError, OSError) as e:
                     # peer died mid-payload (e.g. sender resetting a broken socket
@@ -548,11 +554,11 @@ class GatewayReceiver:
                     break
                 if put_drop_oldest(
                     self.socket_profile_events,
-                    {"port": port, "chunk_id": header.chunk_id, "bytes": header.data_len, "time_s": time.time() - t0},
+                    {"port": port, "chunk_id": header.chunk_id, "bytes": header.data_len, "time_s": t.last_ns / 1e9},
                 ):
                     with self._lock:
                         self._socket_events_dropped += 1
-                task = _DecodeTask(header, payload, state)
+                task = _DecodeTask(header, payload, state, since_ns=t.started_ns, received_ns=t.ended_ns)
                 with state.lock:
                     if state.dead:
                         break
@@ -650,15 +656,11 @@ class GatewayReceiver:
             if tracer.enabled
             else NOOP_SPAN
         )
-        store_span = lambda: (  # noqa: E731 — nested under the decode span
-            tracer.span(
-                "store.write", trace_id=header.chunk_id, cat="receiver", force=header.is_traced, args=self._span_args
-            )
-            if tracer.enabled
-            else NOOP_SPAN
-        )
+        land = self._t_land(header.chunk_id, force=header.is_traced, args=self._span_args)  # nested under the decode span
         ref_stats: dict = {}
         t0 = time.perf_counter_ns()
+        if task.received_ns:
+            self._round.add("queue_wait_ns", t0 - task.received_ns)
         try:
           with span:
             with state.lock:
@@ -671,7 +673,7 @@ class GatewayReceiver:
                 return
             fpath = self.chunk_store.chunk_path(header.chunk_id)
             if self.raw_forward:
-                with store_span():
+                with land:
                     self._land(fpath, task.payload)
                     self._land(
                         fpath.with_suffix(".hdr"),
@@ -698,7 +700,8 @@ class GatewayReceiver:
                         raise SkyplaneTpuException(
                             f"unencrypted frame for chunk {header.chunk_id} at E2EE-enabled receiver"
                         )
-                    payload = self.cipher.open(payload)
+                    with self._t_open(header.chunk_id, force=header.is_traced, args=self._span_args):
+                        payload = self.cipher.open(payload)
                 elif header.is_encrypted:
                     raise SkyplaneTpuException("received encrypted chunk but no E2EE key configured")
                 try:
@@ -732,11 +735,11 @@ class GatewayReceiver:
                 if isinstance(data, PooledChunk):
                     # zero-copy handoff: the pooled view goes straight to the
                     # chunk file and the buffer recycles for the next decode
-                    with store_span():
+                    with land:
                         self._land(fpath, data.view)
                     data.release()
                 else:
-                    with store_span():
+                    with land:
                         self._land(fpath, data)
             # .done is NOT touched here: with out-of-order decode, chunks
             # landed behind a frame whose in-order response later fails would
@@ -747,6 +750,7 @@ class GatewayReceiver:
             task.outcome = "ack"
             task.raw_len = header.raw_data_len
             task.decode_ns = time.perf_counter_ns() - t0
+            task.finished_ns = t0 + task.decode_ns
             if self.tenant_registry is not None:
                 self.tenant_registry.note_decoded(header.tenant_id, header.raw_data_len)
             with self._stats_lock:
@@ -809,7 +813,13 @@ class GatewayReceiver:
             # response commit (see _process_task) — and strictly BEFORE the
             # ack goes out, so an acked chunk always has its .done marker
             if task.fpath is not None:
-                task.fpath.with_suffix(".done").touch()
+                # the decode waited from here for its turn at the head of
+                # the connection's responses; the write operator's hand-off
+                # starts at the marker
+                now = time.perf_counter_ns()
+                if task.finished_ns:
+                    self._round.add("queue_wait_ns", now - task.finished_ns)
+                self.chunk_store.mark_done(task.header.chunk_id, task.since_ns, now)
             # count BEFORE the wire write: a peer that reads the response and
             # immediately polls counters must never observe the pre-response
             # state (budget resets are rate bookkeeping, not delivery proof)
@@ -930,6 +940,7 @@ class GatewayReceiver:
         """Stable-schema decode-path counters (GET /api/v1/profile/decode and
         bench.py's ``decode_counters`` section; docs/datapath-performance.md)."""
         out = dict(DECODE_COUNTER_ZERO)
+        out.update(self._round.totals())
         with self._stats_lock:
             out.update(self._decode_stats)
             out["decode_events_dropped"] = self._decode_events_dropped

@@ -56,10 +56,11 @@ from typing import Callable, List, Optional
 
 from skyplane_tpu.faults import get_injector
 from skyplane_tpu.gateway.operators.gateway_receiver import ACK_BYTE, NACK_UNRESOLVED
-from skyplane_tpu.obs import NOOP_SPAN, get_tracer
+from skyplane_tpu.obs import get_tracer
 from skyplane_tpu.utils.logger import logger
 from skyplane_tpu.utils.retry import RetryPolicy
 from skyplane_tpu.obs import lockwitness as lockcheck
+from skyplane_tpu.obs.stage import Stage
 
 #: reconnect pacing for a stream whose socket keeps dying: jittered
 #: exponential (docs/fault-injection.md) — every worker's streams re-dialing
@@ -235,6 +236,8 @@ SENDER_WIRE_COUNTER_ZERO = {
     "wire_inflight_bytes": 0,  # gauge: sent-but-unacked bytes across streams
     "wire_stall_ns": 0,  # pump idle with a frame READY but the in-flight window full
     "ack_lag_ns": 0,  # sum over frames of (ack received - frame fully sent)
+    "send_ns": 0,  # the frames' sends: TLS and the syscalls (stage wire.send)
+    "frame_wait_ns": 0,  # framed frames waiting in the frame-ahead queue for the pump
     "frames_pipelined": 0,  # frames sent while >=1 earlier frame was still unacked
     "streams_open": 0,  # gauge: live striped connections across engines
     "frames_sent": 0,
@@ -269,6 +272,7 @@ class WireFrame:
         "ref_fps",
         "relay",
         "raw",
+        "queued_ns",
         "sent_ns",
         "sent_wall_ns",
         "window",
@@ -297,6 +301,7 @@ class WireFrame:
         self.new_fps = list(new_fps)  # (fp, size) committed to the durable index on ack
         self.ref_fps = list(ref_fps)  # fps discarded on an unresolvable-REF nack
         self.relay = relay  # opaque re-framed bytes: a NACK is unrecoverable
+        self.queued_ns = 0  # framed: submitted to a stream's frame-ahead queue
         self.sent_ns = 0
         self.sent_wall_ns = 0
         self.window = window  # optional per-window stats carrier (profile events)
@@ -490,6 +495,7 @@ class SenderWireEngine:
         self._completion_cond = threading.Condition(lockcheck.wrap(threading.RLock(), "SenderWireEngine._completion_cond"))
         self._counters = dict(SENDER_WIRE_COUNTER_ZERO)
         self._counters_lock = lockcheck.wrap(threading.Lock(), "SenderWireEngine._counters_lock")
+        self._t_send = Stage(self._bump, "send_ns", "wire.send")
         # raw-forward stream mode: kernel-side payload splicing for frames
         # that carry a RawFrameSource (per-stream opt-out via _Stream.raw_ok)
         self.raw_engine = RawForwardEngine()
@@ -512,6 +518,7 @@ class SenderWireEngine:
         (empty) pending view so REF-safety stays per-socket."""
         stream = self._pick_stream()
         frame = frame_fn(stream.pending_fps)
+        frame.queued_ns = time.perf_counter_ns()
         while True:
             with stream.lock:
                 if stream.dead:
@@ -540,6 +547,7 @@ class SenderWireEngine:
                     stream = new
                     frame.release_raw()  # the re-frame acquires its own source
                     frame = frame_fn(stream.pending_fps)
+                    frame.queued_ns = time.perf_counter_ns()
                     continue
             if self.abort_check is not None and self.abort_check():
                 frame.counted_retry = False  # shutdown, not a failure
@@ -803,16 +811,10 @@ class SenderWireEngine:
                 stream.frames_bytes -= frame.wire_len
                 stream.cond.notify_all()  # the framer may enqueue the next chunk
         if frame is not None:
-            send_span = (
-                get_tracer().span(
-                    "wire.send", trace_id=frame.header.chunk_id, cat="sender", force=True, args=self._span_args
-                )
-                if frame.traced
-                else NOOP_SPAN
-            )
+            t = self._t_send
             inj = get_injector()
             try:
-                with send_span:
+                with t(frame.header.chunk_id, force=frame.traced, args=self._span_args):
                     if inj.enabled:
                         # docs/fault-injection.md: sender.send raises a socket
                         # error mid-send; sender.corrupt_payload flips one wire
@@ -848,7 +850,9 @@ class SenderWireEngine:
                     stream.frames.appendleft(frame)
                     stream.frames_bytes += frame.wire_len
                 raise
-            frame.sent_ns = time.perf_counter_ns()
+            if frame.queued_ns:
+                self._bump("frame_wait_ns", t.started_ns - frame.queued_ns)
+            frame.sent_ns = t.ended_ns
             frame.sent_wall_ns = time.time_ns()
             frame.wire = b""  # wire bytes are on the socket; keep only bookkeeping
             with stream.lock:

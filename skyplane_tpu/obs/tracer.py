@@ -22,9 +22,10 @@ Design constraints (the hot paths this instruments move GB/s):
     carry the chunk id in ``args`` — the correlation key across pids.
   * **The device trace sits on these spans.** An enabled tracer also enters
     ``jax.profiler.TraceAnnotation("host:<name>")`` for the duration of every
-    ``cat="device"`` span, so a ``jax.profiler`` trace taken of a running
-    gateway holds the host steps of the device path on the profile's own
-    clock, beside the device operations they enqueue (:class:`_DeviceSpan`).
+    ``cat="device"`` (:data:`PROFILE_CAT`) span, so a ``jax.profiler`` trace
+    taken of a running gateway holds the steps of a chunk's round (the
+    device path's host steps and the stages of ``obs/stage.py``) on the
+    profile's own clock, beside the device operations (:class:`_DeviceSpan`).
 
 Export is Chrome trace-event JSON (the ``traceEvents`` array form): complete
 ``"X"`` events for context-managed spans (they nest by containment on one
@@ -120,7 +121,11 @@ class _Span:
         return False
 
 
-PROFILE_CAT = "device"  # spans of this category also go into a jax.profiler trace
+#: the category of a step of a chunk's round that is written into a
+#: ``jax.profiler`` trace, as ``host:<name>``: the device path's host steps
+#: and every other step of the round (``obs/stage.py``). The value is the
+#: one the benchmark's swap of :meth:`Tracer.span` matches.
+PROFILE_CAT = "device"
 PROFILE_PREFIX = "host:"
 _annotation_cls = None  # jax.profiler.TraceAnnotation, or False where jax cannot be imported
 

@@ -38,6 +38,7 @@ import numpy as np
 
 from skyplane_tpu.exceptions import CodecException, DedupIntegrityException
 from skyplane_tpu.faults import get_injector as _get_injector
+from skyplane_tpu.obs.stage import Stage
 from skyplane_tpu.obs.tracer import NOOP_SPAN, get_tracer as _get_tracer
 from skyplane_tpu.ops.bufpool import BufferPool, bucket_size
 from skyplane_tpu.ops.fingerprint import MAX_SEGMENT_BYTES, segment_fingerprint_host, segment_fingerprints_host_batch
@@ -943,6 +944,12 @@ class PooledChunk:
         self._arr = None
 
 
+#: the two passes of :func:`parse_recipe`, steps of the sink's round. They
+#: count into the ``ref_stats`` a call names, and nowhere without one
+LITERAL_PASS = Stage(None, "literal_pass_ns", "decode.literal_pass")
+REF_PASS = Stage(None, "ref_resolve_ns", "decode.ref_resolve")
+
+
 def parse_recipe(
     buf,
     store: SegmentStore,
@@ -952,9 +959,10 @@ def parse_recipe(
     out_pool: Optional[BufferPool] = None,
     expected_raw_len: Optional[int] = None,
     ref_stats: Optional[dict] = None,
-    ref_span=NOOP_SPAN,
     blob_out_len=None,
     blob_span=NOOP_SPAN,
+    trace_id: Optional[str] = None,
+    force: bool = False,
 ):
     """Receiver side: resolve a recipe back into raw chunk bytes.
 
@@ -975,7 +983,9 @@ def parse_recipe(
     so later refs resolve, and each run of consecutive literals is placed in
     the output with one copy. The second pass resolves the REFs
     (``store.get`` and the copy into the output), this chunk's own repeats
-    among them, under ``ref_span``. ``ref_stats``, where given, receives what the passes did:
+    among them. Each pass is a stage of the chunk's round (``LITERAL_PASS``,
+    ``REF_PASS``: spans ``decode.literal_pass`` and ``decode.ref_resolve``
+    under ``trace_id`` / ``force``). ``ref_stats``, where given, receives what the passes did:
     ``literal_pass_ns`` (blob decode, verify, admit, place) and inside it
     ``blob_decode_ns`` (``decode_blob`` alone, run under ``blob_span``),
     ``literal_segments_verified``, ``literal_verify_calls``, and, for a recipe
@@ -1030,42 +1040,40 @@ def parse_recipe(
     if plain is None:
         arr = out_pool.acquire(bucket_size(total))
     try:
-        t_lit = time.perf_counter_ns()
-        # the decoded literals: one buffer a chunk that the store adopts, so never pooled memory. Where
-        # the codec writes into its caller's memory that is a fresh array; else what it returns, if that
-        # is ``bytes`` of its own, or one copy of it (a view of the payload, say)
-        lit_buf = np.empty(blob_out_len(lit_total), np.uint8) if blob_out_len is not None and lit_total else None
-        t_blob = time.perf_counter_ns()
-        with blob_span:
-            got = decode_blob(buf[off:]) if lit_buf is None else decode_blob(buf[off:], lit_buf)
-        blob_decode_ns = time.perf_counter_ns() - t_blob
-        if lit_buf is None:
-            if type(got) is not bytes:
-                got = bytes(got)
-            lit_buf = got
-        lit = np.frombuffer(got, np.uint8)
-        if len(lit) != lit_total:
-            how = "shorter" if len(lit) < lit_total else "longer"
-            raise DedupIntegrityException(f"literal blob {how} than recipe entries")
-        if verify_literals and lit_fps:
-            _verify_literals(lit, lit_ends, lit_fps)
-        store.put_blob(lit_buf, lit_fps, (lit_ends - lit_lens).tolist(), lit_ends.tolist())
-        # a run of consecutive literal entries is contiguous in the blob and in the output: one copy
-        run_heads = lit_idx[np.flatnonzero(np.diff(lit_idx, prepend=-2) != 1)]
-        run_tails = lit_idx[np.flatnonzero(np.diff(lit_idx, append=-2) != 1)]
-        src = 0
-        for at, end in zip(out_offs[run_heads].tolist(), (out_offs[run_tails] + lens[run_tails]).tolist()):
-            (plain if arr is None else arr)[at:end] = lit[src : src + end - at]
-            src += end - at
+        with LITERAL_PASS(trace_id, force=force, into=ref_stats):
+            # the decoded literals: one buffer a chunk that the store adopts, so never pooled memory. Where
+            # the codec writes into its caller's memory that is a fresh array; else what it returns, if that
+            # is ``bytes`` of its own, or one copy of it (a view of the payload, say)
+            lit_buf = np.empty(blob_out_len(lit_total), np.uint8) if blob_out_len is not None and lit_total else None
+            t_blob = time.perf_counter_ns()
+            with blob_span:
+                got = decode_blob(buf[off:]) if lit_buf is None else decode_blob(buf[off:], lit_buf)
+            blob_decode_ns = time.perf_counter_ns() - t_blob
+            if lit_buf is None:
+                if type(got) is not bytes:
+                    got = bytes(got)
+                lit_buf = got
+            lit = np.frombuffer(got, np.uint8)
+            if len(lit) != lit_total:
+                how = "shorter" if len(lit) < lit_total else "longer"
+                raise DedupIntegrityException(f"literal blob {how} than recipe entries")
+            if verify_literals and lit_fps:
+                _verify_literals(lit, lit_ends, lit_fps)
+            store.put_blob(lit_buf, lit_fps, (lit_ends - lit_lens).tolist(), lit_ends.tolist())
+            # a run of consecutive literal entries is contiguous in the blob and in the output: one copy
+            run_heads = lit_idx[np.flatnonzero(np.diff(lit_idx, prepend=-2) != 1)]
+            run_tails = lit_idx[np.flatnonzero(np.diff(lit_idx, append=-2) != 1)]
+            src = 0
+            for at, end in zip(out_offs[run_heads].tolist(), (out_offs[run_tails] + lens[run_tails]).tolist()):
+                (plain if arr is None else arr)[at:end] = lit[src : src + end - at]
+                src += end - at
         if ref_stats is not None:
-            ref_stats["literal_pass_ns"] = time.perf_counter_ns() - t_lit
             ref_stats["blob_decode_ns"] = blob_decode_ns
             ref_stats["literal_segments_verified"] = len(lit_fps) if verify_literals else 0
             ref_stats["literal_verify_calls"] = 1 if verify_literals and lit_fps else 0
         ref_idx = np.flatnonzero(~is_lit).tolist()
         if ref_idx:
-            t0 = time.perf_counter_ns()
-            with ref_span:
+            with REF_PASS(trace_id, force=force, into=ref_stats):
                 at_l = out_offs.tolist()
                 for i in ref_idx:
                     fp, at, seg_len = fp_blob[16 * i : 16 * i + 16], at_l[i], lens_l[i]
@@ -1074,7 +1082,6 @@ def parse_recipe(
                         raise DedupIntegrityException(f"dedup ref {fp.hex()} length mismatch")
                     (plain if arr is None else arr)[at : at + seg_len] = np.frombuffer(seg, np.uint8)
             if ref_stats is not None:
-                ref_stats["ref_resolve_ns"] = time.perf_counter_ns() - t0
                 ref_stats["ref_segments_resolved"] = len(ref_idx)
                 ref_stats["ref_bytes_resolved"] = total - lit_total
     except BaseException:

@@ -11,7 +11,6 @@ power-of-two buckets so XLA compiles a handful of shapes, not one per chunk.
 from __future__ import annotations
 
 import hashlib
-import threading
 import time
 from dataclasses import dataclass, field
 from typing import Callable, List, Optional
@@ -21,6 +20,7 @@ import numpy as np
 from skyplane_tpu.chunk import Codec, WireProtocolHeader
 from skyplane_tpu.exceptions import ChecksumMismatchException, CodecException
 from skyplane_tpu.obs import get_tracer
+from skyplane_tpu.obs.stage import Stage, StageCounters
 from skyplane_tpu.ops.bufpool import MIN_BUCKET, BufferPool, bucket_size
 from skyplane_tpu.ops.cdc import CDCParams, cdc_segment_ends
 from skyplane_tpu.ops.codecs import CodecSpec, get_codec, get_codec_by_id, timed_encoder
@@ -49,18 +49,20 @@ class ProcessedPayload:
     ref_fingerprints: list = field(default_factory=list)  # discard from index on unresolvable-ref nack
 
 
-class DataPathStats:
+class DataPathStats(StageCounters):
     """Cumulative sender-side accounting (feeds /profile/compression).
 
     observe() is called for EVERY chunk from every worker of an operator pool
     sharing one processor; a single mutex here measurably serializes 16-32
     workers whose actual work (numpy/zstd/XLA) releases the GIL. Counters are
-    therefore SHARDED per thread: each worker increments its own dict (plain
-    GIL-atomic int ops, no lock), and ``as_dict()`` merges the shards. The
-    merge may interleave with in-flight increments — each counter is
-    individually monotonic and exact once traffic quiesces, which is all a
-    monitoring surface needs; the old whole-snapshot consistency bought
-    nothing but contention.
+    therefore SHARDED per thread (:class:`StageCounters`): each worker
+    increments its own dict (plain GIL-atomic int ops, no lock), and
+    ``as_dict()`` merges the shards. The merge may interleave with in-flight
+    increments — each counter is individually monotonic and exact once
+    traffic quiesces, which is all a monitoring surface needs; the old
+    whole-snapshot consistency bought nothing but contention. The stages of
+    the processor and of its sender (``recipe.build``, ``wire.seal``) count
+    through :meth:`add`.
 
     External per-subsystem counters (buffer pool, batch runner, donation) are
     merged in via registered source callables, with a zero-filled default set
@@ -108,29 +110,19 @@ class DataPathStats:
     }
 
     def __init__(self):
-        self._lock = threading.Lock()  # guards shard/source registries only
-        self._tls = threading.local()
-        self._shards: List[dict] = []
+        super().__init__(self._KEYS)
         self._sources: List[Callable[[], dict]] = []
 
-    def _shard(self) -> dict:
-        d = getattr(self._tls, "counters", None)
-        if d is None:
-            d = {k: 0 for k in self._KEYS}
-            with self._lock:
-                self._shards.append(d)
-            self._tls.counters = d
-        return d
-
-    def observe(self, p: ProcessedPayload, device_path_ns: int = 0, recipe_ns: int = 0, timings: Optional[dict] = None) -> None:
+    def observe(self, p: ProcessedPayload, device_path_ns: int = 0, timings: Optional[dict] = None) -> None:
         """One chunk done. ``device_path_ns``: wall time its worker spent on
         CDC + fingerprints, from submission to finalized digests — the pad
         copy and staging, the window wait, a leader's whole batch, a
         follower's waits, ``finalize_row`` (on a gateway with no accelerator,
-        the host kernels). ``recipe_ns``: ``build_recipe`` (dedup-index
-        lookups, literal join, codec). ``timings`` is ``build_recipe``'s:
-        ``recipe_encode_ns``, the join and the codec inside ``recipe_ns``,
-        and inside that ``blockpack_ns`` and ``zstd_ns``, the steps of the
+        the host kernels). ``timings`` is ``build_recipe``'s:
+        ``recipe_encode_ns``, the join and the codec inside ``recipe_ns``
+        (``build_recipe`` whole: dedup-index lookups, literal join, codec;
+        counted by the ``recipe.build`` stage), and inside that
+        ``blockpack_ns`` and ``zstd_ns``, the steps of the
         codec that ran (a step it does not have stays 0). ``literal_bytes``:
         raw bytes of the segments that went as literals, so what dedup left
         (0 with dedup off: no recipe, no literal); ``literal_blob_bytes``:
@@ -145,7 +137,6 @@ class DataPathStats:
         d["literal_bytes"] += p.literal_bytes
         d["literal_blob_bytes"] += p.literal_blob_bytes
         d["device_path_ns"] += device_path_ns
-        d["recipe_ns"] += recipe_ns
         for k in ("recipe_encode_ns", "blockpack_ns", "zstd_ns"):
             d[k] += (timings or {}).get(k, 0)
 
@@ -157,11 +148,6 @@ class DataPathStats:
         if ns:
             self._shard()["device_wait_ns"] += int(ns)
 
-    def observe_seal(self, ns: int) -> None:
-        """Wall time of the E2EE seal of one chunk's wire payload (the sender
-        operator's, after ``process`` returned)."""
-        self._shard()["seal_ns"] += int(ns)
-
     def add_source(self, fn: Callable[[], dict]) -> None:
         """Register an external counter provider merged into as_dict()."""
         with self._lock:
@@ -169,12 +155,8 @@ class DataPathStats:
 
     def as_dict(self) -> dict:
         with self._lock:
-            shards = list(self._shards)
             sources = list(self._sources)
-        out = {k: 0 for k in self._KEYS}
-        for d in shards:
-            for k in self._KEYS:
-                out[k] += d[k]
+        out = self.totals()
         out["compression_ratio"] = out["raw_bytes"] / out["wire_bytes"] if out["wire_bytes"] else 1.0
         merged = dict(self.EXTERNAL_ZERO)
         for fn in sources:
@@ -274,6 +256,7 @@ class DataPathProcessor:
         self._verify_total = 0
         self._verify_batched = 0
         self.stats = DataPathStats()
+        self._t_recipe = Stage(self.stats.add, "recipe_ns", "recipe.build")
         if batch_runner is not None:
             # the runner's counters() already folds in its pool + fused stats
             self.stats.add_source(batch_runner.counters)
@@ -365,7 +348,7 @@ class DataPathProcessor:
         span and of ``codec.blockpack`` / ``codec.zstd`` inside it, as it does
         for the framer's ``wire.frame`` around this call."""
         raw_len = len(data)
-        device_path_ns = recipe_ns = 0
+        device_path_ns = 0
         timings: dict = {}
         if self.dedup and index is not None and raw_len > 0:
             arr = np.frombuffer(data, np.uint8)
@@ -391,10 +374,8 @@ class DataPathProcessor:
             segments = list(zip(seg_fps, spans))
             tracer = get_tracer()
             encode = timed_encoder(self.codec, timings, lambda name: tracer.span(name, trace_id=trace_id, cat="sender"))
-            t = time.perf_counter_ns()
-            with tracer.span("recipe.build", trace_id=trace_id, cat="sender"):
+            with self._t_recipe(trace_id):
                 wire, n_ref, lit_bytes, new_fps, ref_fps = build_recipe(segments, index, encode, timings)
-            recipe_ns = time.perf_counter_ns() - t
             payload = ProcessedPayload(
                 wire_bytes=wire,
                 codec=self.codec.codec_id,
@@ -425,7 +406,7 @@ class DataPathProcessor:
                 raw_len=raw_len,
                 fingerprint=fp,
             )
-        self.stats.observe(payload, device_path_ns, recipe_ns, timings)
+        self.stats.observe(payload, device_path_ns, timings)
         return payload
 
     # ---- decode ----
@@ -464,9 +445,8 @@ class DataPathProcessor:
                 out_pool=self.bufpool if pooled else None,
                 expected_raw_len=header.raw_data_len,
                 ref_stats=ref_stats,
-                ref_span=get_tracer().span(
-                    "decode.ref_resolve", trace_id=header.chunk_id, cat="receiver", force=header.is_traced
-                ),
+                trace_id=header.chunk_id,
+                force=header.is_traced,
                 blob_out_len=codec.decode_out_len,
                 blob_span=get_tracer().span("decode.blob", trace_id=header.chunk_id, cat="receiver", force=header.is_traced),
             )

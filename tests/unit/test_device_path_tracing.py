@@ -103,8 +103,11 @@ def test_disabled_tracer_gives_every_device_site_the_noop_span(site):
 
 
 def _device_spans(tracer) -> list:
-    """(name, tid, start_us, end_us) of the recorded device spans, by start."""
-    events = [e for e in tracer.export()["traceEvents"] if e.get("ph") == "X" and e.get("cat") == "device"]
+    """(name, tid, start_us, end_us) of the recorded spans of the device path's
+    sites (the profile category holds every step of a chunk's round), by start."""
+    events = [
+        e for e in tracer.export()["traceEvents"] if e.get("ph") == "X" and e.get("cat") == "device" and e["name"] in SITES
+    ]
     return [(e["name"], e["tid"], e["ts"], e["ts"] + e["dur"]) for e in sorted(events, key=lambda e: e["ts"])]
 
 
@@ -373,7 +376,7 @@ def _send_twice_and_restore(trace_id: str):
     return p, ref_stats
 
 
-@pytest.mark.parametrize("span_name, cat", [("recipe.build", "sender"), ("decode.ref_resolve", "receiver")])
+@pytest.mark.parametrize("span_name, cat", [("recipe.build", "device"), ("decode.ref_resolve", "device")])
 def test_ref_path_spans_record_when_enabled_and_carry_the_chunk_id(span_name, cat):
     tracer = configure_tracer(sample=1.0)
     p, ref_stats = _send_twice_and_restore("cd" * 16)
@@ -387,13 +390,15 @@ def test_ref_path_spans_record_when_enabled_and_carry_the_chunk_id(span_name, ca
         assert events[0]["dur"] * 1e3 <= ref_stats["ref_resolve_ns"]
 
 
-@pytest.mark.parametrize("span_name", ["recipe.build", "decode.ref_resolve"])
-def test_disabled_tracer_gives_the_ref_path_sites_the_noop_span(span_name):
+@pytest.mark.parametrize("span_name, n_calls", [("recipe.build", 2), ("decode.ref_resolve", 1)])
+def test_disabled_tracer_gives_the_ref_path_sites_the_noop_span(span_name, n_calls):
+    """recipe.build: once per process(); decode.ref_resolve: the stage looks
+    its span up on entry, so only for the recipe that holds REFs."""
     tracer = configure_tracer(sample=0.0)
     calls = _spy_on_span(tracer)
     _send_twice_and_restore("ef" * 16)
     got = [span for name, _cat, span, _tid in calls if name == span_name]
-    assert len(got) == 2 and all(span is NOOP_SPAN for span in got)
+    assert len(got) == n_calls and all(span is NOOP_SPAN for span in got)
     assert tracer.counters()["spans_recorded"] == 0
 
 

@@ -33,6 +33,7 @@ from skyplane_tpu.gateway.operators.sender_wire import (
 )
 from skyplane_tpu.obs import NOOP_SPAN, MetricsRegistry, configure_tracer, get_tracer
 from skyplane_tpu.obs.metrics import get_registry
+from skyplane_tpu.obs.stage import Stage, StageCounters
 from skyplane_tpu.obs.tracer import Tracer
 
 REPO_ROOT = Path(__file__).resolve().parents[2]
@@ -179,6 +180,68 @@ def test_noop_tracer_zero_allocation():
     assert not hits, f"disabled tracer allocates per call: {hits}"
     assert t.counters()["spans_recorded"] == 0
 
+    # the stage timer of a chunk's round (obs/stage.py): with tracing off a
+    # step is two clock reads into its counter, and a call makes no object
+    configure_tracer(sample=0.0)
+    counters = StageCounters(("hot_ns",))
+    stage = Stage(counters.add, "hot_ns", "hot")
+    assert stage("00" * 16, force=True) is stage
+    stage_file = sys.modules["skyplane_tpu.obs.stage"].__file__
+    for _ in range(100):
+        with stage("00" * 16):
+            pass
+    before = counters.totals()["hot_ns"]
+    tracemalloc.start()
+    try:
+        for _ in range(1000):
+            with stage("00" * 16, force=False):
+                pass
+        snapshot = tracemalloc.take_snapshot()
+    finally:
+        tracemalloc.stop()
+    hits = [
+        s
+        for s in snapshot.statistics("filename")
+        if s.traceback[0].filename in (tracer_file, stage_file) and s.count >= 10
+    ]
+    assert not hits, f"a stage allocates per call with tracing off: {hits}"
+    assert counters.totals()["hot_ns"] > before and stage.last_ns >= 0
+    assert get_tracer().counters()["spans_recorded"] == 0
+
+
+def test_stage_counts_its_step_and_holds_its_profile_span():
+    tracer = configure_tracer(sample=1.0)
+    counters = StageCounters(("step_ns",))
+    stage = Stage(counters.add, "step_ns", "step")
+    with stage("ab" * 16, args={"gateway": "gw"}):
+        time.sleep(0.01)
+    (ev,) = [e for e in tracer.export()["traceEvents"] if e.get("name") == "step"]
+    assert ev["cat"] == "device" and ev["args"] == {"gateway": "gw", "chunk_id": "ab" * 16}
+    total = counters.totals()["step_ns"]
+    assert total == stage.last_ns >= 10_000_000 and ev["dur"] * 1e3 <= total
+    assert stage.ended_ns == stage.started_ns + stage.last_ns
+    # a call that names a per-chunk record counts there, not in the sink
+    record: dict = {}
+    with stage("ab" * 16, into=record):
+        pass
+    assert record["step_ns"] == stage.last_ns and counters.totals()["step_ns"] == total
+
+
+def test_stage_counters_sum_their_threads_shards():
+    counters = StageCounters(("a_ns", "b_ns"))
+
+    def work():
+        for _ in range(1000):
+            counters.add("a_ns", 2)
+            counters.add("b_ns", 1)
+
+    threads = [threading.Thread(target=work) for _ in range(6)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert counters.totals() == {"a_ns": 12_000, "b_ns": 6_000}
+
 
 def test_unsampled_chunk_span_is_noop():
     t = Tracer(sample=0.5)
@@ -284,8 +347,10 @@ def test_loopback_transfer_spans_correlate_and_nest(tmp_path):
             by_chunk.setdefault(cid, {}).setdefault(e["cat"], set()).add(e["name"])
     for h in headers:
         cats = by_chunk.get(h.chunk_id, {})
-        assert {"wire.frame", "wire.send", "wire.ack_lag"} <= cats.get("sender", set()), cats
-        assert {"frame.recv", "decode", "store.write"} <= cats.get("receiver", set()), cats
+        # the envelopes keep their sides; the steps of the round are in the profile's category
+        assert {"wire.frame", "wire.ack_lag"} <= cats.get("sender", set()), cats
+        assert {"decode"} <= cats.get("receiver", set()), cats
+        assert {"wire.send", "frame.recv", "store.write"} <= cats.get("device", set()), cats
     # store.write nests inside decode for every traced chunk (same worker tid)
     spans = [e for e in out["traceEvents"] if e.get("ph") == "X"]
     for h in headers:
