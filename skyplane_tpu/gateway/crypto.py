@@ -35,13 +35,16 @@ class ChunkCipher:
         nonce = os.urandom(NONCE_BYTES)
         return nonce + self._aead.encrypt(nonce, plaintext, None)
 
-    def open(self, sealed: bytes) -> bytes:
+    def open(self, sealed) -> bytes:
+        """Plaintext of a sealed payload (any bytes-like object; read through
+        a view, so a received buffer is not copied first)."""
         from cryptography.exceptions import InvalidTag
 
         if len(sealed) < NONCE_BYTES + 16:
             raise SkyplaneTpuException("sealed payload too short")
+        view = memoryview(sealed)
         try:
-            return self._aead.decrypt(sealed[:NONCE_BYTES], sealed[NONCE_BYTES:], None)
+            return self._aead.decrypt(view[:NONCE_BYTES], view[NONCE_BYTES:], None)
         except InvalidTag as e:
             raise SkyplaneTpuException("E2EE authentication failed (wrong key or corrupted payload)") from e
 

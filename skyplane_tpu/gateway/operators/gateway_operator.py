@@ -49,6 +49,7 @@ from skyplane_tpu.gateway.operators.sender_wire import (
 from skyplane_tpu.gateway.chunk_store import ChunkStore
 from skyplane_tpu.gateway.crypto import ChunkCipher
 from skyplane_tpu.gateway.gateway_queue import GatewayQueue
+from skyplane_tpu.native.tlsstream import NativeTLSStream, TLSStreamContext
 from skyplane_tpu.ops.cdc import CDCParams
 from skyplane_tpu.ops.dedup import SenderDedupIndex
 from skyplane_tpu.ops.pipeline import DataPathProcessor
@@ -665,6 +666,8 @@ class GatewaySenderOperator(GatewayOperator):
         self.target_host = target_host
         self.target_control_port = target_control_port
         self.use_tls = use_tls
+        self._tls_context = None  # made at the first dial (native/tlsstream.py)
+        self._tls_context_lock = threading.Lock()
         # raw config retained for the multi-process pump (gateway/pump.py):
         # worker processes rebuild the framing stack from these fields
         self._codec_name = codec_name
@@ -755,7 +758,7 @@ class GatewaySenderOperator(GatewayOperator):
         # use their wire engine's); serial raw counters merge in wire_counters
         self._raw_serial = RawForwardEngine()
         self._serial_wire_lock = threading.Lock()
-        self._serial_wire = {"wire_raw_frames": 0, "wire_raw_bytes": 0, "wire_raw_fallbacks": 0, "send_ns": 0}
+        self._serial_wire = {"wire_raw_frames": 0, "wire_raw_bytes": 0, "wire_raw_fallbacks": 0, "send_ns": 0, "tls_native_frames": 0}
         # the steps of a chunk's round this operator runs (obs/stage.py)
         self._t_load = Stage(self.chunk_store.source_round.add, "io_ns", "chunk.load")  # the staged chunk, read to frame it
         self._t_register = Stage(self.chunk_store.source_round.add, "register_ns", "chunk.register")
@@ -817,10 +820,7 @@ class GatewaySenderOperator(GatewayOperator):
             try:
                 sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
                 if self.use_tls:
-                    ctx = ssl.SSLContext(ssl.PROTOCOL_TLS_CLIENT)
-                    ctx.check_hostname = False
-                    ctx.verify_mode = ssl.CERT_NONE  # self-signed receiver certs
-                    sock = ctx.wrap_socket(sock)
+                    sock = self._tls_client().wrap(sock)
             except BaseException:
                 # a failed TLS handshake (or setsockopt on a dying connection)
                 # must not strand the TCP socket: retarget()/redial loops call
@@ -832,6 +832,15 @@ class GatewaySenderOperator(GatewayOperator):
         finally:
             if end_warm is not None:
                 end_warm()
+
+    def _tls_client(self) -> TLSStreamContext:
+        """The data sockets' TLS client context, made once: native where
+        libskytls loads, Python's ssl otherwise; it verifies nothing, since
+        receivers' certificates are self-signed."""
+        with self._tls_context_lock:
+            if self._tls_context is None:
+                self._tls_context = TLSStreamContext(server_side=False)
+            return self._tls_context
 
     def _apply_dedup_budget(self, server_info: dict) -> None:
         """Split the sink's advertised segment-store capacity fairly across
@@ -1456,6 +1465,8 @@ class GatewaySenderOperator(GatewayOperator):
                         # one sendmsg, no concatenation copy
                         send_vectored(sock, header.to_bytes(), wire)
                         sent_len = len(wire)
+                if isinstance(sock, NativeTLSStream):
+                    self._bump_serial_wire("tls_native_frames")
                 window_wire += sent_len
                 self.note_egress(sent_len)
                 del wire

@@ -49,6 +49,7 @@ from skyplane_tpu.faults import get_injector
 from skyplane_tpu.gateway.cert import generate_self_signed_certificate
 from skyplane_tpu.gateway.chunk_store import SINK_ROUND_KEYS, ChunkStore
 from skyplane_tpu.gateway.crypto import ChunkCipher
+from skyplane_tpu.native.tlsstream import NativeTLSStream, TLSStreamContext
 from skyplane_tpu.obs import NOOP_SPAN, get_registry, get_tracer
 from skyplane_tpu.obs.stage import Stage
 from skyplane_tpu.ops.dedup import PooledChunk, SegmentStore
@@ -103,6 +104,7 @@ DECODE_COUNTER_ZERO = {
     "verify_batched": 0,
     "decode_events_dropped": 0,
     "socket_events_dropped": 0,
+    "recv_native_frames": 0,  # payloads read by a native TLS stream, one call a frame
     # the steps of a chunk's round (ChunkStore.sink_round, obs/stage.py)
     **dict.fromkeys(SINK_ROUND_KEYS, 0),
 }
@@ -143,7 +145,7 @@ class _DecodeTask:
         "since_ns", "received_ns", "finished_ns",
     )
 
-    def __init__(self, header: WireProtocolHeader, payload: bytes, state: "_ConnState", since_ns: int = 0, received_ns: int = 0):
+    def __init__(self, header: WireProtocolHeader, payload: "bytes | bytearray", state: "_ConnState", since_ns: int = 0, received_ns: int = 0):
         self.header = header
         self.payload = payload
         self.state = state
@@ -361,6 +363,7 @@ class GatewayReceiver:
             "blob_decode_ns": 0,
             "literal_segments_verified": 0,
             "literal_verify_calls": 0,
+            "recv_native_frames": 0,
         }
         # the receiver's steps of a chunk's round (obs/stage.py)
         self._round = chunk_store.sink_round
@@ -372,7 +375,7 @@ class GatewayReceiver:
             t = threading.Thread(target=self._decode_worker, name=f"receiver-decode-{i}", daemon=True)
             t.start()
             self._decode_threads.append(t)
-        self._ssl_ctx: Optional[ssl.SSLContext] = None
+        self._tls: Optional[TLSStreamContext] = None
         self._ssl_cert_files: Optional[tuple] = None
         if use_tls:
             if ssl_cert_files is not None:
@@ -385,8 +388,8 @@ class GatewayReceiver:
                     "skyplane-tpu-gateway", cert_dir / "cert.pem", cert_dir / "key.pem"
                 )
             self._ssl_cert_files = (str(cert), str(key))
-            self._ssl_ctx = ssl.SSLContext(ssl.PROTOCOL_TLS_SERVER)
-            self._ssl_ctx.load_cert_chain(certfile=str(cert), keyfile=str(key))
+            # native where libskytls loads, Python's ssl otherwise: the same TLS either way
+            self._tls = TLSStreamContext(server_side=True, certfile=cert, keyfile=key)
 
     def enable_pump(self, procs: int, persist_dedup: bool = False) -> None:
         """Shard this receiver's decode path across ``procs`` worker
@@ -506,9 +509,9 @@ class GatewayReceiver:
         """Serve one already-accepted TCP connection: TLS handshake (when
         configured) + a dedicated framing thread. Shared by the in-process
         accept loop and pump worker processes adopting fd-passed sockets."""
-        if self._ssl_ctx is not None:
+        if self._tls is not None:
             try:
-                conn = self._ssl_ctx.wrap_socket(conn, server_side=True)
+                conn = self._tls.wrap(conn)
             except (ssl.SSLError, OSError) as e:
                 logger.fs.warning(f"[receiver:{port}] TLS handshake failed from {addr}: {e}")
                 try:
@@ -969,7 +972,11 @@ class GatewayReceiver:
         with self._lock:
             return self._socket_events_dropped
 
-    def _recv_exact(self, conn: socket.socket, n: int) -> bytes:
+    def _recv_exact(self, conn: socket.socket, n: int) -> bytearray:
+        """One frame's payload. A native TLS stream reads it in one call; a
+        plain socket or a Python SSLSocket in a loop, one TLS record a call
+        for the latter. The buffer is handed on as it is: the decode takes
+        any bytes-like payload."""
         inj = get_injector()
         if inj.enabled:
             # docs/fault-injection.md: a mid-payload disconnect at the framing
@@ -977,6 +984,11 @@ class GatewayReceiver:
             # and the sender's socket-death path re-queues and resends it
             inj.check("receiver.recv", ConnectionError, "injected mid-payload disconnect")
         buf = bytearray(n)
+        if isinstance(conn, NativeTLSStream):
+            conn.recv_exact_into(buf)
+            with self._stats_lock:
+                self._decode_stats["recv_native_frames"] += 1
+            return buf
         view = memoryview(buf)
         got = 0
         while got < n:
@@ -984,4 +996,4 @@ class GatewayReceiver:
             if r == 0:
                 raise ConnectionError(f"socket closed mid-payload ({got}/{n} bytes)")
             got += r
-        return bytes(buf)
+        return buf

@@ -56,6 +56,7 @@ from typing import Callable, List, Optional
 
 from skyplane_tpu.faults import get_injector
 from skyplane_tpu.gateway.operators.gateway_receiver import ACK_BYTE, NACK_UNRESOLVED
+from skyplane_tpu.native.tlsstream import NativeTLSStream, is_tls_stream
 from skyplane_tpu.obs import get_tracer
 from skyplane_tpu.utils.logger import logger
 from skyplane_tpu.utils.retry import RetryPolicy
@@ -94,7 +95,7 @@ def send_vectored(sock, header: bytes, payload) -> None:
     record layer) and test fakes without sendmsg fall back to two sendalls,
     which is the old behavior exactly."""
     sendmsg = getattr(sock, "sendmsg", None)
-    if sendmsg is None or isinstance(sock, ssl.SSLSocket):
+    if sendmsg is None or is_tls_stream(sock):
         sock.sendall(header)
         if len(payload):
             sock.sendall(payload)
@@ -181,7 +182,7 @@ class RawForwardEngine:
             # drop), the sender stream falls back to the codec path
             tear_at = source.length // 2
         try:
-            if isinstance(sock, ssl.SSLSocket):
+            if is_tls_stream(sock):
                 self._send_mmap(sock, header_bytes, source, tear_at)
             else:
                 self._send_sendfile(sock, header_bytes, source, tear_at)
@@ -241,6 +242,7 @@ SENDER_WIRE_COUNTER_ZERO = {
     "frames_pipelined": 0,  # frames sent while >=1 earlier frame was still unacked
     "streams_open": 0,  # gauge: live striped connections across engines
     "frames_sent": 0,
+    "tls_native_frames": 0,  # frames whose TLS stream ran in native code, one call a frame
     "wire_bytes_sent": 0,
     "acks_reaped": 0,
     "nacks_reaped": 0,
@@ -860,6 +862,8 @@ class SenderWireEngine:
                 stream.inflight.append(frame)
                 stream.inflight_bytes += frame.wire_len
             self._bump("frames_sent")
+            if isinstance(stream.sock, NativeTLSStream):
+                self._bump("tls_native_frames")
             self._bump("wire_bytes_sent", frame.wire_len)
             self.callbacks.on_wire_sent(frame.wire_len)
             if pipelined:

@@ -1,6 +1,9 @@
 """Native (C++) data-path components, built on demand with g++.
 
-The compiled library is cached next to the sources; set
+``libskydp`` (skylz.cpp, datapath.cpp) holds the host codecs and data-path
+kernels; ``libskytls`` (tlsstream.cpp, loaded by tlsstream.py) the data
+socket's TLS record loop. Each library is cached next to the sources with a
+stamp of its own; set
 ``SKYPLANE_TPU_NATIVE_BUILD_DIR`` to relocate build artifacts (e.g. on
 read-only installs).
 """
@@ -12,7 +15,7 @@ import os
 import subprocess
 import threading
 from pathlib import Path
-from typing import Optional
+from typing import Optional, Tuple
 
 from skyplane_tpu.exceptions import MissingDependencyException
 
@@ -48,14 +51,14 @@ def _cpu_features() -> str:
     return f"{platform.machine()} {platform.processor()}"
 
 
-def build_stamp() -> str:
-    """Digest of everything the compiled library depends on: the two sources,
-    the compiler flags and this CPU's feature flags. A library whose sidecar
+def build_stamp(sources: Tuple[str, ...] = _SOURCES) -> str:
+    """Digest of everything a compiled library depends on: its sources, the
+    compiler flags and this CPU's feature flags. A library whose sidecar
     stamp differs (stale sources, built on another CPU, planted) is rebuilt."""
     import hashlib
 
     h = hashlib.sha256()
-    for name in _SOURCES:
+    for name in sources:
         h.update((_SRC_DIR / name).read_bytes())
     h.update(" ".join(_NATIVE_FLAGS).encode())
     h.update(_cpu_features().encode())
@@ -68,8 +71,8 @@ def build_info() -> dict:
     return dict(_build_info)
 
 
-def _compile(out: Path) -> None:
-    src_args = [str(_SRC_DIR / name) for name in _SOURCES]
+def _compile(out: Path, sources: Tuple[str, ...]) -> None:
+    src_args = [str(_SRC_DIR / name) for name in sources]
     # build beside the target and rename: a concurrent loader (pump workers
     # start together) never maps a half-written library
     tmp = out.with_name(f"{out.name}.{os.getpid()}.tmp")
@@ -77,15 +80,30 @@ def _compile(out: Path) -> None:
         # -march=native can fail in emulated environments; retry portable
         for flags in (_NATIVE_FLAGS, _PORTABLE_FLAGS):
             try:
-                proc = subprocess.run(["g++", *flags, *src_args, "-o", str(tmp)], capture_output=True, text=True, timeout=120)
+                proc = subprocess.run(["g++", *flags, *src_args, "-o", str(tmp), "-ldl"], capture_output=True, text=True, timeout=120)
             except FileNotFoundError as e:
-                raise MissingDependencyException("native codec requires g++ in PATH") from e
+                raise MissingDependencyException("native libraries require g++ in PATH") from e
             if proc.returncode == 0:
                 os.replace(tmp, out)
                 return
-        raise MissingDependencyException(f"native codec build failed: {proc.stderr[-2000:]}")
+        raise MissingDependencyException(f"native build of {out.name} failed: {proc.stderr[-2000:]}")
     finally:
         tmp.unlink(missing_ok=True)
+
+
+def build_and_load(name: str, sources: Tuple[str, ...]) -> Tuple[ctypes.CDLL, dict]:
+    """Build ``lib<name>.so`` from ``sources`` unless its stamp matches, and
+    load it. Returns the library and its {"path", "stamp", "built"}. Callers
+    hold ``_BUILD_LOCK``."""
+    out = _build_dir() / f"lib{name}.so"
+    stamp_file = _build_dir() / f"lib{name}.stamp"
+    stamp = build_stamp(sources)
+    built = not out.exists() or not stamp_file.exists() or stamp_file.read_text() != stamp
+    if built:
+        out.parent.mkdir(parents=True, exist_ok=True)
+        _compile(out, sources)
+        stamp_file.write_text(stamp)
+    return ctypes.CDLL(str(out)), {"path": str(out), "stamp": stamp, "built": built}
 
 
 def load_library() -> ctypes.CDLL:
@@ -96,15 +114,7 @@ def load_library() -> ctypes.CDLL:
     with _BUILD_LOCK:
         if _lib is not None:
             return _lib
-        out = _build_dir() / "libskydp.so"
-        stamp_file = _build_dir() / "libskydp.stamp"
-        stamp = build_stamp()
-        built = not out.exists() or not stamp_file.exists() or stamp_file.read_text() != stamp
-        if built:
-            out.parent.mkdir(parents=True, exist_ok=True)
-            _compile(out)
-            stamp_file.write_text(stamp)
-        lib = ctypes.CDLL(str(out))
+        lib, info = build_and_load("skydp", _SOURCES)
         u8p = ctypes.POINTER(ctypes.c_uint8)
         u32p = ctypes.POINTER(ctypes.c_uint32)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -127,6 +137,6 @@ def load_library() -> ctypes.CDLL:
             fn = getattr(lib, name)
             fn.restype = restype
             fn.argtypes = argtypes
-        _build_info.update(path=str(out), stamp=stamp, built=built)
+        _build_info.update(info)
         _lib = lib
         return _lib

@@ -70,14 +70,12 @@ def _content(seed: int) -> bytes:
     return b"".join(parts)
 
 
-@pytest.fixture(scope="module")
-def round_trip(tmp_path_factory):
+def _run_pair(tmp):
     """Served counters of both daemons after the chunks landed, and the
     profile-category spans entered: [(name, chunk id), ...]."""
     pytest.importorskip("zstandard")
     from tests.integration.harness import dispatch_file, make_pair, wait_complete
 
-    tmp = tmp_path_factory.mktemp("round")
     src_path, dst_path = tmp / "in.bin", tmp / "out.bin"
     data = _content(38)
     src_path.write_bytes(data)
@@ -100,12 +98,41 @@ def round_trip(tmp_path_factory):
             "src": (src.get("profile/compression", timeout=10).json(), src.get("profile/decode", timeout=10).json()["counters"]),
             "dst": (dst.get("profile/compression", timeout=10).json(), dst.get("profile/decode", timeout=10).json()["counters"]),
         }
+        wire = src.get("profile/socket/sender", timeout=10).json()["counters"]
     finally:
         src.stop()
         dst.stop()
         configure_tracer()
     assert dst_path.read_bytes() == data
-    return {"ids": ids, "served": served, "entered": list(entered)}
+    return {"ids": ids, "served": served, "wire": wire, "entered": list(entered)}
+
+
+@pytest.fixture(scope="module")
+def round_trip(tmp_path_factory):
+    return _run_pair(tmp_path_factory.mktemp("round"))
+
+
+@pytest.fixture(scope="module")
+def python_ssl_trip(tmp_path_factory):
+    """The same pair where the native TLS library cannot load."""
+    from skyplane_tpu.native import tlsstream
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(tlsstream, "load", lambda: None)
+        return _run_pair(tmp_path_factory.mktemp("round_python_ssl"))
+
+
+def test_every_tls_frame_went_over_the_native_stream(round_trip):
+    wire, (_, decode) = round_trip["wire"], round_trip["served"]["dst"]
+    assert wire["frames_sent"] >= N_CHUNKS and wire["tls_native_frames"] == wire["frames_sent"], wire
+    assert decode["decode_chunks"] == N_CHUNKS and decode["recv_native_frames"] == decode["decode_chunks"], decode
+
+
+def test_python_ssl_stream_where_the_library_cannot_load(python_ssl_trip):
+    wire, (_, decode) = python_ssl_trip["wire"], python_ssl_trip["served"]["dst"]
+    assert wire["frames_sent"] >= N_CHUNKS and wire["tls_native_frames"] == 0, wire
+    assert decode["decode_chunks"] == N_CHUNKS and decode["recv_native_frames"] == 0, decode
+    assert decode["recv_ns"] > 0 and python_ssl_trip["served"]["src"][0]["send_ns"] > 0
 
 
 @pytest.mark.parametrize("key", SOURCE_KEYS)
