@@ -77,29 +77,40 @@ class Observed:
     cdc: Tuple[int, int, int]
     wire_codec_id: int
     reference_rows: Dict[int, Tuple[np.ndarray, List[bytes]]] = field(default_factory=dict)
+    # the set-up rows landed one at a time, each in the source's index before
+    # the next was posted (not the set-up bursts' rows, which go together)
+    setup_rows: Tuple[int, ...] = (0,)
 
 
-def expected_refs(device_rows: Dict[int, Tuple[np.ndarray, List[bytes]]], order: List[int]) -> Tuple[int, int, int]:
+def expected_refs(
+    device_rows: Dict[int, Tuple[np.ndarray, List[bytes]]], order: List[int], setup_rows: Tuple[int, ...] = (0,)
+) -> Tuple[int, int, int]:
     """(segments, fewest REFs, most REFs) the recipes of these chunks hold if
-    dedup is exact: a segment is a REF when its fingerprint is in the set-up
-    chunk or earlier in its own chunk (fewest), or also in any chunk sent
-    before it (most; the two differ only if window chunks share content)."""
-    setup = set(device_rows[order[0]][1]) if order else set()
+    dedup is exact. Fewest: a segment is a REF when its fingerprint is earlier
+    in its own chunk, or in a set-up row that landed before its chunk was
+    posted: for set-up row k the set-up rows before k, for every other row all
+    of them. Most: also when it is in any chunk sent before it (the two differ
+    only if chunks other than the set-up rows share content). ``order`` is by
+    index, so the set-up rows come first."""
+    setup_all = set().union(*(device_rows[i][1] for i in setup_rows if i in device_rows))
+    setup_before: set = set()
     segments = fewest = most = 0
     seen_before: set = set()
-    for n, idx in enumerate(order):
+    for idx in order:
         fps = device_rows[idx][1]
         segments += len(fps)
+        known = setup_before if idx in setup_rows else setup_all
         own: set = set()
         for fp in fps:
-            in_setup = n > 0 and fp in setup
-            if in_setup or fp in own:
+            if fp in known or fp in own:
                 fewest += 1
                 most += 1
             elif fp in seen_before:
                 most += 1
             own.add(fp)
         seen_before |= own
+        if idx in setup_rows:
+            setup_before |= own
     return segments, fewest, most
 
 
@@ -124,7 +135,7 @@ def compare(obs: Observed) -> Dict[str, dict]:
     out["rows_ends_differ"] = ends_differ
     out["rows_fingerprints_differ"] = fps_differ
     order = [s.index for s in sorted(obs.sent, key=lambda s: s.index) if s.index in obs.device_rows]
-    segments, fewest, most = expected_refs(obs.device_rows, order)
+    segments, fewest, most = expected_refs(obs.device_rows, order, obs.setup_rows)
     out["segments_off"] = abs(int(obs.counters.get("segments", 0)) - segments)
     refs = int(obs.counters.get("ref_segments", 0))
     out["ref_segments_off"] = max(fewest - refs, refs - most, 0)

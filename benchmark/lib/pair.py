@@ -79,8 +79,14 @@ def start_gateway(program: dict, info: Dict[str, dict], gateway_id: str, chunk_d
     return gw
 
 
-def make_pair(tmp: Path, compress: str, dedup: bool, encrypt: bool, use_tls: bool, num_connections: int, cdc_params):
-    """Start (source, sink) wired source --send--> sink."""
+def make_pair(
+    tmp: Path, compress: str, dedup: bool, encrypt: bool, use_tls: bool, num_connections: int, cdc_params,
+    sink_segment_store_bytes: Optional[int] = None,
+):
+    """Start (source, sink) wired source --send--> sink. Where
+    ``sink_segment_store_bytes`` is given, the sink's segment store holds that
+    many bytes in memory and spills the rest; else it keeps the daemon's bound
+    (``SKYPLANE_TPU_SEGSTORE_MB``, else 4 GiB)."""
     from skyplane_tpu.gateway.crypto import generate_key
 
     key = generate_key() if encrypt else None
@@ -101,6 +107,10 @@ def make_pair(tmp: Path, compress: str, dedup: bool, encrypt: bool, use_tls: boo
         ]
     }
     sink = start_gateway(sink_program, {}, "gw_dst", str(tmp / "dst_chunks"), e2ee_key=key, use_tls=use_tls, cdc_params=cdc_params)
+    if sink_segment_store_bytes is not None:
+        # the store is still empty and the source does not exist yet: it sizes
+        # its dedup index from the capacity the sink advertises when it asks
+        sink.daemon.receiver.segment_store.set_bounds(max_bytes=sink_segment_store_bytes)
     info = {"gw_dst": {"public_ip": "127.0.0.1", "control_port": sink.daemon.api.port}}
     source_program = {
         "plan": [

@@ -52,6 +52,12 @@ LATE_S = 60.0  # how long past the close an answer is waited for
 STAGGER_S = 1.0  # between the posts that fill the pipeline
 POLL_S = 0.1  # small against a row's seconds; completion times are the sink's own stamps
 MARK = "bench:mark"
+# the sink's segment store and REF ladder, printed under run.sink_store: what
+# a cell whose store spills does there, for the whole run and after t0
+SINK_STORE_COUNTERS = (
+    "store_mem_evictions", "store_spill_reads", "store_promotions", "store_spill_bytes",
+    "store_ref_wait_ns", "store_ref_timeouts", "decode_nacks",
+)
 
 
 class GatewayFault(RuntimeError):
@@ -79,6 +85,10 @@ def load_module(path: Path):
     return module
 
 
+def whole_number(value, least: int) -> bool:
+    return type(value) is int and value >= least  # True, 1.5 and "3" are not
+
+
 def parse_args(argv):
     ap = argparse.ArgumentParser(description=__doc__, formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("--workload", required=True)
@@ -104,8 +114,18 @@ class Cell:
         self.generator_file = HERE / "generators" / f"{self.workload['generator']}.py"
         self.generator = load_module(self.generator_file).Generator
         self.burst = self.workload["traffic"].get("setup_burst_chunks")  # None: the set-up chunk alone, as ever
-        if self.burst is not None and (type(self.burst) is not int or self.burst < 2):
+        if self.burst is not None and not whole_number(self.burst, 2):
             raise SystemExit(f"workloads/{name}.json: setup_burst_chunks is {self.burst!r}; it is a whole number of 2 or more, or left out")
+        self.setup_chunks = self.workload["traffic"].get("setup_chunks", 1)  # rows landed one at a time before the fill
+        if not whole_number(self.setup_chunks, 1):
+            raise SystemExit(f"workloads/{name}.json: setup_chunks is {self.setup_chunks!r}; it is a whole number of 1 or more, or left out")
+        transfer = self.config["transfer"]
+        self.store_mb = transfer.get("sink_segment_store_mb")  # None: the daemon's own bound
+        if self.store_mb is not None and not (whole_number(self.store_mb, 1) and transfer["dedup"]):
+            raise SystemExit(
+                f"configs/{self.entry['config']}.json: sink_segment_store_mb is {self.store_mb!r}; "
+                "it is a whole number of 1 or more where dedup is on, or left out"
+            )
 
     def metrics(self, group: str):
         """The metrics of ``group`` this cell reports, each with its file."""
@@ -215,9 +235,11 @@ def tap_runner(runner, taps: dict) -> None:
     runner.submit = tapped
 
 
-def departures_from(cfg: dict, gateways) -> list:
+def departures_from(cfg: dict, gateways, store_bytes=None) -> list:
     """Where a daemon was built at another value than the configuration's
-    ``transfer`` states: a run that departs from it is no sound run."""
+    ``transfer`` states: a run that departs from it is no sound run.
+    ``store_bytes`` is the sink's segment-store bound the run asked for, where
+    the configuration states one; ``gateways`` is (source, sink)."""
     from skyplane_tpu.ops.cdc import CDCParams
 
     out = []
@@ -231,17 +253,24 @@ def departures_from(cfg: dict, gateways) -> list:
         ):
             if built != stated:
                 out.append(f"{d.gateway_id}.{what}: built {built}, stated {stated}")
+    if store_bytes is not None:  # stated only where dedup is on: the sink has a store
+        d = gateways[-1].daemon
+        built = d.receiver.segment_store._max_bytes
+        if built != store_bytes:
+            out.append(f"{d.gateway_id}.segment_store_bytes: built {built}, stated {store_bytes}")
     return out
 
 
 class ChunkSource(threading.Thread):
     """Makes chunk ``i`` of the cell from (seed, i), writes it where the source
-    gateway reads it and keeps its digest; stays ``ahead`` chunks ahead."""
+    gateway reads it and keeps its digest; stays ``ahead`` chunks ahead, from
+    chunk ``start`` on (the rows before it are the set-up's)."""
 
-    def __init__(self, generator, src_dir: Path, ahead: int):
+    def __init__(self, generator, src_dir: Path, ahead: int, start: int = 1):
         super().__init__(name="bench-generator", daemon=True)
         self.generator = generator
         self.src_dir = src_dir
+        self.start_index = start
         self.ready: queue.Queue = queue.Queue(maxsize=ahead)
         self.halt = threading.Event()
 
@@ -255,7 +284,7 @@ class ChunkSource(threading.Thread):
         return {"index": index, "path": path, "digest": bytes_digest(arr), "key": row_key(arr), "n_bytes": len(arr)}
 
     def run(self):
-        index = 1
+        index = self.start_index
         while not self.halt.is_set():
             made = self.make(index)
             while not self.halt.is_set():
@@ -343,11 +372,14 @@ def run_cell(args, cell: Cell, pool) -> int:
         dst_dir.mkdir()
         generator = cell.generator(cell.workload["content"], args.seed, max(args.rehearse_scale, 1))
         in_flight = int(traffic["in_flight_chunks"])
-        chunks = ChunkSource(generator, src_dir, ahead=in_flight)
+        n_setup = cell.setup_chunks
+        chunks = ChunkSource(generator, src_dir, ahead=in_flight, start=n_setup)
         setup_made = chunks.make(0)
         t = phase("setup_chunk_made_s", t)
 
         cdc = (cfg["cdc_min_bytes"], cfg["cdc_avg_bytes"], cfg["cdc_max_bytes"])
+        # a rehearsal cuts the store with the rows, so that it spills where the timed run does
+        store_bytes = None if cell.store_mb is None else (cell.store_mb << 20) // max(args.rehearse_scale, 1)
         source, sink = pair.make_pair(
             tmp,
             compress=cfg["compress"],
@@ -356,7 +388,10 @@ def run_cell(args, cell: Cell, pool) -> int:
             use_tls=cfg["encrypt_socket_tls"],
             num_connections=cfg["num_connections"],
             cdc_params=CDCParams(*cdc),
+            sink_segment_store_bytes=store_bytes,
         )
+        if store_bytes is not None:
+            log(f"sink segment store: memory tier bound {store_bytes} bytes, as the configuration states")
         runner = source.daemon.batch_runner
         taps: dict = {}
         if runner is not None:
@@ -399,18 +434,25 @@ def run_cell(args, cell: Cell, pool) -> int:
             return out
 
         pending: dict = {}
-        setup_rows: set = set()  # indices of the set-up chunk and the set-up bursts' chunks
+        setup_rows: set = set()  # indices of the set-up chunks and the set-up bursts' chunks
 
         def land(made_now: list) -> None:
             """Post these chunks at once and wait until the sink calls every
-            one complete: rows of the set-up, not of the window."""
+            one complete and the source too: the source logs a chunk complete
+            once it has taken the sink's ack and put the chunk's fingerprints
+            in its index, which the sink's own ``complete`` does not imply.
+            Rows of the set-up, not of the window."""
+            ids = []
             for made in made_now:
                 s = post(made)
                 pending[s.chunk_id] = s
                 setup_rows.add(s.index)
+                ids.append(s.chunk_id)
             while pending:
                 time.sleep(POLL_S)
                 poll(pending)
+            while len(pair.completions(source, ids)) < len(ids):
+                time.sleep(POLL_S)
 
         t0 = first = setup_seconds = None
         at_t0: dict = {}
@@ -421,6 +463,14 @@ def run_cell(args, cell: Cell, pool) -> int:
             # that loads both device programs at the timed shape
             land([setup_made])
             t = phase("setup_chunk_landed_s", t)
+            if n_setup > 1:
+                # ---- the further set-up rows, one at a time in index order:
+                # each is in the source's index before the next is posted, so
+                # what a post finds there is the same in every run
+                for index in range(1, n_setup):
+                    land([chunks.make(index)])
+                t = phase("setup_chunks_landed_s", t)
+                log(f"set-up: {n_setup} rows landed one at a time")
 
             chunks.start()
             if burst:
@@ -506,7 +556,7 @@ def run_cell(args, cell: Cell, pool) -> int:
             sent=sent, file_digests={}, device_rows={},
             row_bytes=lambda i: generator.chunk(i) if i else generator.setup_chunk(),
             counters={}, frames=[], gateway_errors=0, as_built_departures=[], cdc=cdc,
-            wire_codec_id=int(get_codec(cfg["compress"]).codec_id),
+            wire_codec_id=int(get_codec(cfg["compress"]).codec_id), setup_rows=tuple(range(n_setup)),
         )
         t_ref, closed_at = time.monotonic(), time.time()
         background = ThreadPoolExecutor(max_workers=1, thread_name_prefix="bench-reference")
@@ -537,7 +587,7 @@ def run_cell(args, cell: Cell, pool) -> int:
         obs.frames = frames_seen + decode["events"]
         obs.gateway_errors = len(pair.errors(source)) + len(pair.errors(sink))
         events = source.get("events", params={"since": 0})["events"]
-        obs.as_built_departures = departures_from(cfg, (source, sink))
+        obs.as_built_departures = departures_from(cfg, (source, sink), store_bytes)
         if args.trace and measured:
             record = trace_lib.extract(trace_lib.find_xplane(str(trace_dir)), rehearsal=rehearsal)  # no device plane: raises, no result
         reference = making_reference.result()
@@ -629,6 +679,10 @@ def run_cell(args, cell: Cell, pool) -> int:
             "reference_s": round(reference_s, 3), "drained_s": round(drained_s, 3), "compile_cache": cache_dir,
             "reference_rows": len(obs.reference_rows), "rows_sent": len(sent), "setup_rows": len(setup_rows), "reference": reference,
             "device_windows_after_t0": {k: facts.get(f"source_after_t0.batch_{k}") for k in ("rows", "windows", "padded_rows")},
+            "sink_store": {
+                "run": {k: decode["counters"].get(k) for k in SINK_STORE_COUNTERS},
+                "after_t0": {k: facts.get(f"sink_after_t0.{k}") for k in SINK_STORE_COUNTERS},
+            },
         }
         if control is not None:
             result["control"] = control

@@ -106,6 +106,90 @@ def test_refs_shared_between_window_chunks_widen_the_expected_count_not_the_faul
         assert "ref_segments_off" not in over(check.compare(obs))
 
 
+# ---- several set-up rows: what the source's index holds when each row is posted
+
+HAND_ROWS = {i: (None, [bytes([c]) for c in fps]) for i, fps in enumerate([b"ab", b"cda", b"ef", b"aceg", b"fgh"])}
+
+
+@pytest.mark.parametrize(
+    "setup_rows, fewest, most",
+    [
+        ((0, 1, 2), 5, 6),  # row 1 finds a in row 0; row 3 finds a, c, e in the set-up; row 4 f (g only in row 3)
+        ((0,), 2, 6),  # one set-up row: a in rows 1 and 3; the rest only among rows sent before
+    ],
+    ids=["three_setup_rows", "one_setup_row"],
+)
+def test_expected_refs_by_hand_for_set_up_rows(setup_rows, fewest, most):
+    assert check.expected_refs(HAND_ROWS, [0, 1, 2, 3, 4], setup_rows) == (14, fewest, most)
+
+
+def test_one_set_up_row_reads_what_the_rule_before_several_read():
+    """The rule before set-up rows of their own: the first row of ``order``
+    is the set-up, every later row finds it."""
+
+    def before(rows, order):
+        setup, segments, fewest, most, seen = set(rows[order[0]][1]), 0, 0, 0, set()
+        for n, idx in enumerate(order):
+            own = set()
+            for fp in rows[idx][1]:
+                segments += 1
+                if (n > 0 and fp in setup) or fp in own:
+                    fewest, most = fewest + 1, most + 1
+                elif fp in seen:
+                    most += 1
+                own.add(fp)
+            seen |= own
+        return segments, fewest, most
+
+    assert check.expected_refs(HAND_ROWS, [0, 1, 2, 3, 4]) == before(HAND_ROWS, [0, 1, 2, 3, 4])
+    # real rows, each content twice: rows 0 and 2 alike, rows 1 and 3 alike
+    rows = {i: reference.cdc_and_fingerprints(np.random.default_rng([3, i % 2]).integers(0, 256, 1 << 18, dtype=np.uint8), *CDC) for i in range(4)}
+    assert check.expected_refs(rows, [0, 1, 2, 3]) == before(rows, [0, 1, 2, 3])
+
+
+def regions(tmp_path, n_bytes=1 << 19):
+    """A sound run of three set-up rows, each a region of its own, and two
+    window rows that are set-up rows 1 and 2 with 4 KiB rewritten: the window
+    REFs rows 1 and 2 and never row 0."""
+    rng = np.random.default_rng(11)
+    bases = [rng.integers(0, 256, n_bytes, dtype=np.uint8) for _ in range(3)]
+    rows = {i: bases[i] for i in range(3)}
+    for i, r in ((3, 1), (4, 2)):
+        rows[i] = bases[r].copy()
+        rows[i][65536 : 65536 + 4096] = rng.integers(0, 256, 4096, dtype=np.uint8)
+    sent, digests, frames = [], {}, []
+    for i, row in rows.items():
+        path = tmp_path / f"chunk_{i}.bin"
+        path.write_bytes(row.tobytes())
+        s = check.Sent(index=i, chunk_id=f"c{i}", key=check.row_key(row), digest=check.bytes_digest(row), n_bytes=n_bytes,
+                       src_path=path, dst_path=path, posted_at=time.time(), completed_at=time.time())
+        sent.append(s)
+        digests[i] = check.file_digest(path)
+        frames.append({"chunk_id": s.chunk_id, "codec": 3, "raw_bytes": n_bytes, "wire_bytes": 1000})
+    device_rows = {i: reference.cdc_and_fingerprints(row, *CDC) for i, row in rows.items()}
+    segments, fewest, _ = check.expected_refs(device_rows, sorted(rows), (0, 1, 2))
+    obs = check.Observed(
+        sent=sent, file_digests=digests, device_rows=device_rows, row_bytes=rows.__getitem__,
+        counters={"batch_rows": len(rows), "stage_failures": 0, "segments": segments, "ref_segments": fewest},
+        frames=frames, gateway_errors=0, as_built_departures=[], cdc=CDC, wire_codec_id=3, setup_rows=(0, 1, 2),
+    )
+    check.compute_reference(obs)
+    return obs
+
+
+def test_a_sender_that_forgot_a_set_up_row_fails_the_new_fewest_and_passed_the_old(tmp_path):
+    obs = regions(tmp_path)
+    assert over(check.compare(obs)) == set()
+    row2 = set(obs.device_rows[2][1])
+    forgotten = sum(1 for i in (3, 4) for fp in obs.device_rows[i][1] if fp in row2)
+    assert forgotten > len(obs.device_rows[4][1]) // 2  # row 4 is row 2 but for 4 KiB: most of its segments
+    obs.counters["ref_segments"] -= forgotten  # those went as literals: the index had lost row 2
+    compared = check.compare(obs)
+    assert "ref_segments_off" in over(compared) and compared["ref_segments_off"]["value"] == forgotten
+    obs.setup_rows = (0,)  # the rule before: row 0 alone is the set-up
+    assert "ref_segments_off" not in over(check.compare(obs))
+
+
 # ---- a whole run on the CPU backend, at chunks 64 times smaller, with the
 # timed path broken underneath: the harness's look for a chip is skipped
 # (a rehearsal), everything else is the code a chip run drives

@@ -28,9 +28,11 @@ def test_data_file_loads_and_is_named_after_itself(path):
         assert (BENCH / "configs" / f"{data['config']}.json").is_file()
         assert (BENCH / "generators" / f"{data['generator']}.py").is_file()
         traffic = data["traffic"]
-        assert set(traffic) <= {"in_flight_chunks", "setup_burst_chunks"} and traffic["in_flight_chunks"] >= 1
+        assert set(traffic) <= {"in_flight_chunks", "setup_burst_chunks", "setup_chunks"} and traffic["in_flight_chunks"] >= 1
         burst = traffic.get("setup_burst_chunks", 2)  # optional: the set-up bursts of run.py
         assert type(burst) is int and burst >= 2
+        setup = traffic.get("setup_chunks", 1)  # optional: rows landed one at a time before the fill
+        assert type(setup) is int and setup >= 1
     if path.parent.name == "configs":
         assert 1 <= len(data["source"]) <= 200 and data["guarantees"] and isinstance(data["reduced"], dict)
 

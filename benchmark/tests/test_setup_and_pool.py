@@ -151,24 +151,32 @@ def test_pool_size_is_half_the_cores_and_at_most_eight(monkeypatch):
 # ---- whole rehearsal runs
 
 
-def with_cell(monkeypatch, traffic: dict, name: str = "test-cell.burst"):
+def with_cell(monkeypatch, traffic: dict, name: str = "test-cell.burst", transfer: dict = None, generator: str = None, content: dict = None):
     """Serve run.py a cell that is in no file: the first cell's configuration
-    and content under ``traffic``."""
+    (with ``transfer`` over its values) and content under ``traffic``, or
+    ``content`` made by ``generator``."""
     import run
 
     base = SPEC["workloads"][0]
+    config = f"{name}-config"
     real = run.load_json
 
     def load_json(path: Path) -> dict:
         if path.name == "BENCHMARK.json":
             spec = real(path)
-            spec["workloads"].append(dict(base, name=name))
+            spec["workloads"].append(dict(base, name=name, config=config))
             for m in spec["end_to_end"] + spec["per_layer"]:
                 if "workloads" in m:
                     m["workloads"].append(name)
             return spec
         if path.name == f"{name}.json":
-            return dict(real(path.with_name(f"{base['name']}.json")), name=name, traffic=traffic)
+            workload = dict(real(path.with_name(f"{base['name']}.json")), name=name, config=config, traffic=traffic)
+            if generator is not None:
+                workload.update(generator=generator, content=content)
+            return workload
+        if path.name == f"{config}.json":
+            cfg = real(path.with_name(f"{base['config']}.json"))
+            return dict(cfg, name=config, transfer=dict(cfg["transfer"], **(transfer or {})))
         return real(path)
 
     monkeypatch.setattr(run, "load_json", load_json)
@@ -238,12 +246,146 @@ def test_a_burst_of_fewer_than_two_chunks_is_refused_before_anything_starts(caps
         rehearse(capsys, cell)
 
 
-def test_a_workload_without_the_key_issues_the_posts_it_always_did_and_leaves_no_worker(capsys, monkeypatch):
+def watch_posts_and_completions(monkeypatch):
+    """(index, chunk id, when) of every post, and (gateway, chunk id) -> when
+    a poll first found it complete there."""
+    posts, done = [], {}
+    real_post, real_completions = pair.post_file, pair.completions
+
+    def post_file(source, src_path, dst_path, chunk_bytes):
+        ids = real_post(source, src_path, dst_path, chunk_bytes)
+        posts.append((int(src_path.stem.split("_")[1]), ids[0], time.monotonic()))
+        return ids
+
+    def completions(gw, chunk_ids):
+        out = real_completions(gw, chunk_ids)
+        for cid in out:
+            done.setdefault((gw.daemon.gateway_id, cid), time.monotonic())
+        return out
+
+    monkeypatch.setattr(pair, "post_file", post_file)
+    monkeypatch.setattr(pair, "completions", completions)
+    return posts, done
+
+
+def test_set_up_rows_land_one_at_a_time_before_t0_and_are_held_to_the_reference(capsys, monkeypatch):
+    cell = with_cell(monkeypatch, {"in_flight_chunks": 2, "setup_chunks": 3}, name="test-cell.setup")
+    posts, done = watch_posts_and_completions(monkeypatch)
+    rc, result, err = rehearse(capsys, cell)
+    assert rc == 1 and result["rehearsal"]["checks_passed"] is True, err[-3000:]
+    run = result["run"]
+    assert run["setup_rows"] == 3 and "setup_chunks_landed_s" in run["phases"]
+    assert [i for i, _, _ in posts[:5]] == [0, 1, 2, 3, 4]  # the fill numbers on from the set-up rows
+    for (_, before, _), (_, _, posted) in zip(posts[:3], posts[1:4]):
+        # the next row is posted once the sink has landed this one and the source has taken its ack
+        assert done[("gw_dst", before)] < posted and done[("gw_src", before)] < posted
+    assert run["reference_rows"] == run["rows_sent"] >= 3 + 1 + run["completions"]
+    assert len(run["gaps_s"]) == run["completions"]  # no set-up row in the window
+    assert line_no(err, "set-up: 3 rows landed one at a time") < line_no(err, "t0: first window chunk")
+    assert gone(pool_pids(err)[0], 1.0)
+
+
+@pytest.mark.parametrize("value", [0, 1.5, "3", True])
+def test_set_up_chunks_other_than_a_whole_number_are_refused_before_anything_starts(capsys, monkeypatch, value):
+    cell = with_cell(monkeypatch, {"in_flight_chunks": 2, "setup_chunks": value})
+    with pytest.raises(SystemExit, match="setup_chunks"):
+        rehearse(capsys, cell)
+
+
+@pytest.mark.parametrize("value", [0, 1.5, "256"])
+def test_a_store_bound_other_than_a_whole_number_is_refused_before_anything_starts(capsys, monkeypatch, value):
+    cell = with_cell(monkeypatch, {"in_flight_chunks": 2}, transfer={"sink_segment_store_mb": value})
+    with pytest.raises(SystemExit, match="sink_segment_store_mb"):
+        rehearse(capsys, cell)
+
+
+REGIONS = '''
+import numpy as np
+
+
+class Generator:
+    """Rows 0..regions-1 are regions of their own; row i past them is region
+    i % regions with one extent rewritten from (seed, i)."""
+
+    def __init__(self, params, seed, scale=1):
+        self.seed = int(seed)
+        self.regions = int(params["regions"])
+        self.chunk_bytes = int(params["region_bytes"]) // scale
+        self.extent_bytes = int(params["extent_bytes"]) // scale
+
+    def setup_chunk(self):
+        return self.chunk(0)
+
+    def chunk(self, i):
+        out = np.random.default_rng([self.seed, 0, i % self.regions]).integers(0, 256, self.chunk_bytes, dtype=np.uint8)
+        if i >= self.regions:
+            rng = np.random.default_rng([self.seed, 1, i])
+            at = int(rng.integers(0, self.chunk_bytes - self.extent_bytes))
+            out[at : at + self.extent_bytes] = rng.integers(0, 256, self.extent_bytes, dtype=np.uint8)
+        return out
+'''
+
+
+def test_a_store_smaller_than_the_set_up_spills_and_the_window_refs_resolve_from_disk(capsys, monkeypatch, tmp_path):
+    """Three set-up regions of 1 MiB (64 MiB at the timed size) against a
+    memory tier of 2 MiB (128 MB): window row i is region i % 3, so every
+    window row REFs a region the store has spilled, set-up rows 1 and 2 among
+    them; the union rule holds ``ref_segments`` to all three."""
+    (tmp_path / "regions.py").write_text(REGIONS)
+    cell = with_cell(
+        monkeypatch, {"in_flight_chunks": 2, "setup_chunks": 3}, name="test-cell.spill",
+        transfer={"sink_segment_store_mb": 128},
+        generator=str(tmp_path / "regions"),  # absolute: joined to generators/ it stays as it is
+        content={"regions": 3, "region_bytes": 67108864, "extent_bytes": 524288},
+    )
+    rc, result, err = rehearse(capsys, cell)
+    assert rc == 1 and result["rehearsal"]["checks_passed"] is True, err[-3000:]
+    assert "memory tier bound 2097152 bytes" in err and result["compared"]["as_built_departures"]["value"] == 0
+    store = result["run"]["sink_store"]
+    assert store["run"]["store_mem_evictions"] > 0 and store["after_t0"]["store_spill_reads"] > 0, store
+    assert store["run"]["decode_nacks"] == 0 and store["run"]["store_ref_timeouts"] == 0
+    assert result["run"]["completions"] >= 3  # window rows 3, 4, 5: regions 0, 1 and 2
+    assert gone(pool_pids(err)[0], 1.0)
+
+
+@pytest.mark.parametrize("asked, env, built", [(None, None, 4 << 30), (None, "8", 8 << 20), (3 << 20, "8", 3 << 20)])
+def test_the_pair_builds_the_sink_store_at_the_bound_asked_else_the_daemons_own(tmp_path, monkeypatch, asked, env, built):
+    import run
+    from skyplane_tpu.ops.cdc import CDCParams
+
+    if env is None:
+        monkeypatch.delenv("SKYPLANE_TPU_SEGSTORE_MB", raising=False)
+    else:
+        monkeypatch.setenv("SKYPLANE_TPU_SEGSTORE_MB", env)
+    source, sink = pair.make_pair(tmp_path, "tpu_zstd", True, False, False, 2, CDCParams(4096, 16384, 65536), sink_segment_store_bytes=asked)
+    try:
+        assert sink.daemon.receiver.segment_store._max_bytes == built
+        assert source.daemon.receiver.segment_store is None
+        cfg = {"encrypt_socket_tls": False, "encrypt_e2e": False, "cdc_min_bytes": 4096, "cdc_avg_bytes": 16384, "cdc_max_bytes": 65536, "batch_window": 8}
+        of_store = lambda stated: [d for d in run.departures_from(cfg, (source, sink), stated) if "segment_store" in d]  # noqa: E731
+        assert of_store(None) == [] and of_store(built) == []
+        assert of_store(built + 1) == [f"gw_dst.segment_store_bytes: built {built}, stated {built + 1}"]
+    finally:
+        source.stop()
+        sink.stop()
+
+
+@pytest.mark.parametrize(
+    "traffic, transfer",
+    [(None, None), ({"setup_chunks": 1}, None), (None, {"sink_segment_store_mb": 4096})],
+    ids=["neither_key", "setup_chunks_1", "store_at_its_default"],
+)
+def test_a_workload_without_the_key_issues_the_posts_it_always_did_and_leaves_no_worker(capsys, monkeypatch, traffic, transfer):
     """Recorded from the parent of the PR that brought the bursts: the set-up
     chunk alone, then ``in_flight_chunks`` posts one STAGGER_S apart, then one
-    post a completion, in the order of the generator's indices."""
+    post a completion, in the order of the generator's indices. One set-up
+    row stated, or the sink's store at the daemon's own 4 GiB, post the same."""
     import run
 
+    cell = SPEC["workloads"][0]["name"]
+    in_flight = json.loads((BENCH / "workloads" / f"{cell}.json").read_text())["traffic"]["in_flight_chunks"]
+    if traffic or transfer:
+        cell = with_cell(monkeypatch, dict({"in_flight_chunks": in_flight}, **(traffic or {})), name="test-cell.keys", transfer=transfer)
     events, main_thread = [], threading.current_thread()
     real_post, real_sleep = pair.post_file, time.sleep
 
@@ -258,8 +400,6 @@ def test_a_workload_without_the_key_issues_the_posts_it_always_did_and_leaves_no
 
     monkeypatch.setattr(pair, "post_file", post_file)
     monkeypatch.setattr(time, "sleep", sleep)
-    cell = SPEC["workloads"][0]["name"]
-    in_flight = json.loads((BENCH / "workloads" / f"{cell}.json").read_text())["traffic"]["in_flight_chunks"]
     rc, result, err = rehearse(capsys, cell)
     monkeypatch.undo()
     assert rc == 1 and result["rehearsal"]["checks_passed"] is True, err[-3000:]
@@ -268,6 +408,7 @@ def test_a_workload_without_the_key_issues_the_posts_it_always_did_and_leaves_no
     rest = events[1 + len(fill) :]
     assert rest == [("post", n) for n in range(in_flight + 1, in_flight + 1 + len(rest))] and rest
     assert result["run"]["setup_rows"] == 1 and "set-up burst" not in err and "setup_bursts_s" not in result["run"]["phases"]
+    assert "setup_chunks_landed_s" not in result["run"]["phases"] and "rows landed one at a time" not in err
     assert result["run"]["reference_rows"] == result["run"]["rows_sent"] == len(events) - (in_flight - 1)
     assert gone(pool_pids(err)[0], 1.0)
 
