@@ -3,18 +3,21 @@
 The benchmark's own copy of what it needs from ``tests/integration/harness.py``
 (``start_gateway``, ``make_pair``, ``build_chunk_requests``, status polling):
 later PRs may change that file and may not change the yardstick. The daemons
-are the program; everything here only starts them and talks to their control
-API over HTTP, as a client does.
+are the program; everything here starts them and talks to their control API
+over HTTP, as a client does, but for two reads made in this process: the
+status log from a cursor (``StatusReader``) and the sink's decode events
+(``decode_events``), each at a cost of what is new since the last read.
 """
 
 from __future__ import annotations
 
+import queue
 import threading
 import time
 import uuid
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Dict, Iterable, List, Optional
+from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 import requests
 
@@ -143,42 +146,101 @@ def make_pair(
     return source, sink
 
 
-def post_file(source: LocalGateway, src_path: Path, dst_path: Path, chunk_bytes: int) -> List[str]:
-    """Split a local file into chunk requests of ``chunk_bytes`` and POST them
-    to the source gateway; returns the chunk ids."""
+#: chunk requests in one ``POST /api/v1/chunk_requests``, as upstream's client
+#: sends them (``CopyJob.dispatch``: batches of 100)
+POST_BATCH = 100
+
+
+def post_files(source: LocalGateway, files: Sequence[Tuple[Path, Path, int]]) -> List[List[str]]:
+    """Split each local file of (src path, dst path, chunk bytes) into chunk
+    requests and POST them to the source gateway, in order, at most
+    ``POST_BATCH`` to a request; returns each file's chunk ids."""
     from skyplane_tpu.chunk import Chunk, ChunkRequest
 
-    size = src_path.stat().st_size
-    reqs = []
-    for offset in range(0, max(size, 1), chunk_bytes):
-        chunk = Chunk(
-            src_key=str(src_path),
-            dest_key=str(dst_path),
-            chunk_id=uuid.uuid4().hex,
-            chunk_length_bytes=min(chunk_bytes, size - offset),
-            file_offset_bytes=offset,
-        )
-        reqs.append(ChunkRequest(chunk=chunk, src_region="local:local", dst_region="local:local", src_type="local", dst_type="local"))
-    source.post("chunk_requests", [r.as_dict() for r in reqs])
-    return [r.chunk.chunk_id for r in reqs]
+    reqs, ids = [], []
+    for src_path, dst_path, chunk_bytes in files:
+        size = src_path.stat().st_size
+        ids.append([])
+        for offset in range(0, max(size, 1), chunk_bytes):
+            chunk = Chunk(
+                src_key=str(src_path),
+                dest_key=str(dst_path),
+                chunk_id=uuid.uuid4().hex,
+                chunk_length_bytes=min(chunk_bytes, size - offset),
+                file_offset_bytes=offset,
+            )
+            reqs.append(ChunkRequest(chunk=chunk, src_region="local:local", dst_region="local:local", src_type="local", dst_type="local"))
+            ids[-1].append(chunk.chunk_id)
+    for at in range(0, len(reqs), POST_BATCH):
+        source.post("chunk_requests", [r.as_dict() for r in reqs[at : at + POST_BATCH]])
+    return ids
 
 
-def completions(gw: LocalGateway, chunk_ids: Iterable[str]) -> Dict[str, float]:
-    """chunk id -> the wall-clock time (``time.time()``) at which this
-    gateway's LAST operator logged the chunk ``complete``, for those of
-    ``chunk_ids`` its status map calls complete. The time is the status log
-    record's own stamp, taken where the operator finished, not when we asked."""
-    ids = sorted(chunk_ids)
-    if not ids:
-        return {}
-    body = gw.get("chunk_status_log", params={"chunk_ids": ",".join(ids), "include_log": "1"})
-    done = {c for c, state in body["chunk_status"].items() if state == "complete"}
-    out: Dict[str, float] = {}
-    for rec in body["chunk_status_log"]:
-        cid = rec["chunk_id"]
-        if cid in done and rec["state"] == "complete":
-            out[cid] = max(out.get(cid, 0.0), float(rec["time"]))
-    return out
+class StatusLogLost(RuntimeError):
+    """A gateway dropped records of its status log before they were read: a
+    completion may be among them, and waiting for it could never end."""
+
+
+class StatusReader:
+    """A gateway's chunk status log, read in this process from where the last
+    read stopped: each read costs the records that are new since the one
+    before, not the whole log (``GET chunk_status_log?include_log=1`` copies
+    and encodes all of it under the API's lock, and lists the ids it asks for
+    in its URL). The cursor counts records ever logged, so the records the
+    log has dropped from its head (``_status_log_dropped``) are part of it."""
+
+    def __init__(self, gw: LocalGateway):
+        self.api = gw.daemon.api
+        self.name = gw.daemon.gateway_id
+        with self.api._lock:
+            self.cursor = self.api._status_log_dropped + len(self.api.chunk_status_log)
+        self.waiting: Dict[str, float] = {}  # chunk id -> largest ``complete`` stamp read so far
+        self.seen: Set[str] = set()  # waiting ids with a ``complete`` record whose chunk is not yet complete
+
+    def track(self, chunk_ids: Iterable[str]) -> None:
+        """Wait for these chunks too; records logged for them since the last
+        read are still ahead of the cursor."""
+        for cid in chunk_ids:
+            self.waiting[cid] = 0.0
+
+    def poll(self) -> Dict[str, float]:
+        """chunk id -> the wall-clock time (``time.time()``) at which this
+        gateway's LAST operator logged the chunk ``complete``, for the tracked
+        chunks its status map now calls complete; each is returned once. The
+        time is the largest stamp of the chunk's ``complete`` records, taken
+        where the operator finished, not when we asked."""
+        api = self.api
+        with api._lock:
+            at = self.cursor - api._status_log_dropped
+            if at < 0:
+                raise StatusLogLost(
+                    f"gateway {self.name} dropped {-at} records of its status log (bound {api.MAX_STATUS_LOG}) "
+                    f"before they were read; {len(self.waiting)} chunks were waited for"
+                )
+            new = api.chunk_status_log[at:]
+            self.cursor += len(new)
+            for rec in new:
+                cid = rec["chunk_id"]
+                if rec["state"] == "complete" and cid in self.waiting:
+                    self.waiting[cid] = max(self.waiting[cid], float(rec["time"]))
+                    self.seen.add(cid)
+            # the map and the records read agree: both are as of this lock
+            done = [cid for cid in self.seen if api.chunk_status.get(cid) == "complete"]
+        self.seen.difference_update(done)
+        return {cid: self.waiting.pop(cid) for cid in done}
+
+
+def decode_events(gw: LocalGateway) -> List[dict]:
+    """The sink's per-chunk decode events since the last drain, taken in this
+    process from the queue ``GET profile/decode`` drains: the queue holds
+    4,096 and drops the oldest, so a run of more chunks drains it as it goes."""
+    q = gw.daemon.receiver.decode_profile_events
+    out = []
+    while True:
+        try:
+            out.append(q.get_nowait())
+        except queue.Empty:
+            return out
 
 
 def errors(gw: LocalGateway) -> List[str]:

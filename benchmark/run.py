@@ -5,14 +5,15 @@
 
 One process holds the chip and runs a source and a sink ``GatewayDaemon`` as
 threads on loopback, built at the configuration's values. Chunks go in through
-``POST /api/v1/chunk_requests`` on the source and count when the sink's status
-log says ``complete``. A closed loop keeps the cell's ``in_flight_chunks`` in
-flight; the measured span runs from chunk completion to chunk completion
-(``lib/span.py``). What decides ``correct`` (``lib/check.py``) runs once the
-window has closed, its reference in worker processes (``lib/refpool.py``)
-that are started first of all and do nothing until then. The last line of
-stdout is the result as one JSON object, printed before the gateways are
-stopped and the data removed.
+``POST /api/v1/chunk_requests`` on the source, at most 100 to a request, and
+count when the sink's status log says ``complete``, read from where the last
+read stopped (``lib/pair.py``). A closed loop keeps the cell's
+``in_flight_chunks`` in flight; the measured span runs from chunk completion to
+chunk completion (``lib/span.py``). What decides ``correct`` (``lib/check.py``)
+runs once the window has closed, its reference in worker processes
+(``lib/refpool.py``) that are started first of all and do nothing until then.
+The last line of stdout is the result as one JSON object, printed before the
+gateways are stopped and the data removed.
 
 Everything that belongs to one cell, configuration, metric or kind of content
 is a file found by its name in BENCHMARK.json: ``workloads/<cell>.json``,
@@ -33,6 +34,7 @@ T_START = time.monotonic()
 import argparse  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import queue  # noqa: E402
 import shutil  # noqa: E402
@@ -49,8 +51,8 @@ sys.path[:0] = [str(HERE), str(ROOT)]
 DEADLINE_S = 345.0  # a run exits within 360 s
 TEARDOWN_S = 60.0  # stopping the gateways and removing the data, once the result is printed
 LATE_S = 60.0  # how long past the close an answer is waited for
-STAGGER_S = 1.0  # between the posts that fill the pipeline
-POLL_S = 0.1  # small against a row's seconds; completion times are the sink's own stamps
+STAGGER_S = 1.0  # between the posts that fill the pipeline, where a cell states no fill_stagger_s
+POLL_S = 0.1  # completion times are the sink's own stamps; a deep loop refills from its queue between polls
 MARK = "bench:mark"
 # the sink's segment store and REF ladder, printed under run.sink_store: what
 # a cell whose store spills does there, for the whole run and after t0
@@ -119,6 +121,9 @@ class Cell:
         self.setup_chunks = self.workload["traffic"].get("setup_chunks", 1)  # rows landed one at a time before the fill
         if not whole_number(self.setup_chunks, 1):
             raise SystemExit(f"workloads/{name}.json: setup_chunks is {self.setup_chunks!r}; it is a whole number of 1 or more, or left out")
+        self.fill_stagger_s = self.workload["traffic"].get("fill_stagger_s", STAGGER_S)  # 0: the whole fill at once
+        if not (type(self.fill_stagger_s) in (int, float) and math.isfinite(self.fill_stagger_s) and self.fill_stagger_s >= 0):
+            raise SystemExit(f"workloads/{name}.json: fill_stagger_s is {self.fill_stagger_s!r}; it is a number of seconds of 0 or more, or left out")
         transfer = self.config["transfer"]
         self.store_mb = transfer.get("sink_segment_store_mb")  # None: the daemon's own bound
         if self.store_mb is not None and not (whole_number(self.store_mb, 1) and transfer["dedup"]):
@@ -402,23 +407,36 @@ def run_cell(args, cell: Cell, pool) -> int:
 
         request_bytes = cfg["multipart_chunk_size_mb"] << 20
         sent: list = []
+        pending: dict = {}
+        landed = pair.StatusReader(sink)
+        frames_seen: list = []
 
-        def post(made: dict) -> check.Sent:
-            dst_path = dst_dir / made["path"].name
-            (chunk_id,) = pair.post_file(source, made["path"], dst_path, request_bytes)
-            s = check.Sent(
-                index=made["index"], chunk_id=chunk_id, key=made["key"], digest=made["digest"], n_bytes=made["n_bytes"],
-                src_path=made["path"], dst_path=dst_path, posted_at=time.time(),
-            )
-            sent.append(s)
-            return s
+        def post(made_now: list) -> list:
+            """Post these chunks in as few requests as ``pair.POST_BATCH``
+            allows; each is pending until the sink calls it complete."""
+            dst_paths = [dst_dir / made["path"].name for made in made_now]
+            ids = pair.post_files(source, [(made["path"], dst, request_bytes) for made, dst in zip(made_now, dst_paths)])
+            posted_at = time.time()
+            out = []
+            for made, dst_path, (chunk_id,) in zip(made_now, dst_paths, ids):
+                s = check.Sent(
+                    index=made["index"], chunk_id=chunk_id, key=made["key"], digest=made["digest"], n_bytes=made["n_bytes"],
+                    src_path=made["path"], dst_path=dst_path, posted_at=posted_at,
+                )
+                sent.append(s)
+                pending[chunk_id] = s
+                out.append(s)
+            landed.track(s.chunk_id for s in out)
+            return out
 
         polls = [0]
 
         def poll(pending: dict) -> list:
             """Chunks of ``pending`` the sink now calls complete, in order of
-            completion. A gateway that reports an error ends the run."""
-            done = pair.completions(sink, pending)
+            completion. A gateway that reports an error ends the run; a status
+            log that dropped records before they were read ends it unsound."""
+            done = landed.poll()
+            frames_seen.extend(pair.decode_events(sink))
             polls[0] += 1
             if polls[0] % 10 == 0:
                 for gw in (source, sink):
@@ -433,7 +451,6 @@ def run_cell(args, cell: Cell, pool) -> int:
                 out.append(s)
             return out
 
-        pending: dict = {}
         setup_rows: set = set()  # indices of the set-up chunks and the set-up bursts' chunks
 
         def land(made_now: list) -> None:
@@ -442,22 +459,20 @@ def run_cell(args, cell: Cell, pool) -> int:
             once it has taken the sink's ack and put the chunk's fingerprints
             in its index, which the sink's own ``complete`` does not imply.
             Rows of the set-up, not of the window."""
-            ids = []
-            for made in made_now:
-                s = post(made)
-                pending[s.chunk_id] = s
-                setup_rows.add(s.index)
-                ids.append(s.chunk_id)
+            acked = pair.StatusReader(source)
+            posted = post(made_now)
+            setup_rows.update(s.index for s in posted)
+            acked.track(s.chunk_id for s in posted)
             while pending:
                 time.sleep(POLL_S)
                 poll(pending)
-            while len(pair.completions(source, ids)) < len(ids):
+            while acked.waiting:
                 time.sleep(POLL_S)
+                acked.poll()
 
         t0 = first = setup_seconds = None
         at_t0: dict = {}
         feed_wait_s = 0.0
-        frames_seen: list = []
         try:
             # ---- set-up chunk: the base of the cell's content, and the row
             # that loads both device programs at the timed shape
@@ -501,11 +516,13 @@ def run_cell(args, cell: Cell, pool) -> int:
                 mark_wall_ns = time.time_ns()
                 with jax.profiler.TraceAnnotation(MARK):
                     time.sleep(0.001)
-            for n in range(in_flight):
-                if n:
-                    time.sleep(STAGGER_S)
-                s = post(chunks.ready.get())
-                pending[s.chunk_id] = s
+            if cell.fill_stagger_s:
+                for n in range(in_flight):
+                    if n:
+                        time.sleep(cell.fill_stagger_s)
+                    post([chunks.ready.get()])
+            else:  # the whole fill before the first poll
+                post([chunks.ready.get() for _ in range(in_flight)])
             starving_since = None
             while True:
                 time.sleep(POLL_S)
@@ -516,13 +533,15 @@ def run_cell(args, cell: Cell, pool) -> int:
                         decode = sink.get("profile/decode")  # the GET drains the events: keep them
                         frames_seen.extend(decode["events"])
                         at_t0 = {"source": source.get("profile/compression"), "sink": decode["counters"], "cpu": s.cpu_at_completion}
+                        thread_cpu_at_t0 = time.thread_time()
                         phase("first_window_chunk_s", t)
                         log(f"t0: first window chunk complete; set-up {setup_seconds:.2f}s, phases {phases}")
                 if t0 is not None and time.time() >= t0 + args.seconds:
                     break
-                while len(pending) < in_flight:
+                ready = []
+                while len(pending) + len(ready) < in_flight:
                     try:
-                        made = chunks.ready.get_nowait()
+                        ready.append(chunks.ready.get_nowait())
                     except queue.Empty:
                         if starving_since is None and t0 is not None:
                             starving_since = time.monotonic()
@@ -530,11 +549,16 @@ def run_cell(args, cell: Cell, pool) -> int:
                     if starving_since is not None:
                         feed_wait_s += time.monotonic() - starving_since
                         starving_since = None
-                    s = post(made)
-                    pending[s.chunk_id] = s
+                if ready:
+                    post(ready)
         except GatewayFault as err:
             log(f"FAIL: {err}")
         cpu_at_deadline = time.process_time()
+        harness_cpu_s = None
+        if t0 is not None:
+            # the harness's own thread against the whole process, t0 to the close: logged, no metric
+            harness_cpu_s = time.thread_time() - thread_cpu_at_t0
+            log(f"harness main thread: {harness_cpu_s:.3f}s of CPU, the process {cpu_at_deadline - at_t0['cpu']:.3f}s, t0 to the close")
         chunks.halt.set()
 
         # ---- the window has closed
@@ -568,6 +592,9 @@ def run_cell(args, cell: Cell, pool) -> int:
                 poll(pending)
             except GatewayFault as err:
                 log(f"FAIL: {err}")
+                break
+            except pair.StatusLogLost as err:  # the reference is under way: finish, then print no result
+                unsound = f"the run is not sound: {err}"
                 break
         drained_s = time.monotonic() - t_ref
 
@@ -678,7 +705,11 @@ def run_cell(args, cell: Cell, pool) -> int:
             "stalled": bool(measured and measured.stalled), "phases": phases,
             "reference_s": round(reference_s, 3), "drained_s": round(drained_s, 3), "compile_cache": cache_dir,
             "reference_rows": len(obs.reference_rows), "rows_sent": len(sent), "setup_rows": len(setup_rows), "reference": reference,
-            "device_windows_after_t0": {k: facts.get(f"source_after_t0.batch_{k}") for k in ("rows", "windows", "padded_rows")},
+            "device_windows_after_t0": dict(
+                {k: facts.get(f"source_after_t0.batch_{k}") for k in ("rows", "windows", "padded_rows")},
+                compiles=facts.get("source_after_t0.xla_compiles"),
+            ),
+            "harness_thread_cpu_s": harness_cpu_s, "sink_status_log_dropped": sink.daemon.api._status_log_dropped,
             "sink_store": {
                 "run": {k: decode["counters"].get(k) for k in SINK_STORE_COUNTERS},
                 "after_t0": {k: facts.get(f"sink_after_t0.{k}") for k in SINK_STORE_COUNTERS},
@@ -691,7 +722,9 @@ def run_cell(args, cell: Cell, pool) -> int:
         if control is not None:
             log(f"control {control['name']}: correct={control['correct']} " + " ".join(f"{k}={v['value']}" for k, v in control["compared"].items() if v["value"] > v["limit"]))
     except SetupUnsound as err:
-        unsound = str(err)
+        unsound = f"the set-up is not sound: {err}"
+    except pair.StatusLogLost as err:
+        unsound = f"the run is not sound: {err}"
     except BaseException:
         tear_down((source, sink), tmp, TEARDOWN_S)
         raise
@@ -700,7 +733,7 @@ def run_cell(args, cell: Cell, pool) -> int:
             jax.profiler.stop_trace()
     # ---- the result first, the teardown after it
     if unsound:
-        log(f"FAIL: the set-up is not sound: {unsound}: no result")
+        log(f"FAIL: {unsound}: no result")
         rc = 5
     elif not has_rate:
         log("FAIL: fewer than two completions after t0 inside the window: no rate")

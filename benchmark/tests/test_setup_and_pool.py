@@ -247,24 +247,24 @@ def test_a_burst_of_fewer_than_two_chunks_is_refused_before_anything_starts(caps
 
 
 def watch_posts_and_completions(monkeypatch):
-    """(index, chunk id, when) of every post, and (gateway, chunk id) -> when
-    a poll first found it complete there."""
+    """(index, chunk id, when) of every file posted, and (gateway, chunk id)
+    -> when a poll first found it complete there."""
     posts, done = [], {}
-    real_post, real_completions = pair.post_file, pair.completions
+    real_post, real_poll = pair.post_files, pair.StatusReader.poll
 
-    def post_file(source, src_path, dst_path, chunk_bytes):
-        ids = real_post(source, src_path, dst_path, chunk_bytes)
-        posts.append((int(src_path.stem.split("_")[1]), ids[0], time.monotonic()))
+    def post_files(source, files):
+        ids = real_post(source, files)
+        posts.extend((int(src_path.stem.split("_")[1]), file_ids[0], time.monotonic()) for (src_path, _, _), file_ids in zip(files, ids))
         return ids
 
-    def completions(gw, chunk_ids):
-        out = real_completions(gw, chunk_ids)
+    def poll(self):
+        out = real_poll(self)
         for cid in out:
-            done.setdefault((gw.daemon.gateway_id, cid), time.monotonic())
+            done.setdefault((self.name, cid), time.monotonic())
         return out
 
-    monkeypatch.setattr(pair, "post_file", post_file)
-    monkeypatch.setattr(pair, "completions", completions)
+    monkeypatch.setattr(pair, "post_files", post_files)
+    monkeypatch.setattr(pair.StatusReader, "poll", poll)
     return posts, done
 
 
@@ -372,44 +372,142 @@ def test_the_pair_builds_the_sink_store_at_the_bound_asked_else_the_daemons_own(
 
 @pytest.mark.parametrize(
     "traffic, transfer",
-    [(None, None), ({"setup_chunks": 1}, None), (None, {"sink_segment_store_mb": 4096})],
-    ids=["neither_key", "setup_chunks_1", "store_at_its_default"],
+    [(None, None), ({"setup_chunks": 1}, None), (None, {"sink_segment_store_mb": 4096}), ({"fill_stagger_s": 1.0}, None)],
+    ids=["neither_key", "setup_chunks_1", "store_at_its_default", "fill_stagger_s_1"],
 )
 def test_a_workload_without_the_key_issues_the_posts_it_always_did_and_leaves_no_worker(capsys, monkeypatch, traffic, transfer):
     """Recorded from the parent of the PR that brought the bursts: the set-up
     chunk alone, then ``in_flight_chunks`` posts one STAGGER_S apart, then one
     post a completion, in the order of the generator's indices. One set-up
-    row stated, or the sink's store at the daemon's own 4 GiB, post the same."""
+    row stated, the sink's store at the daemon's own 4 GiB, or a fill stagger
+    of STAGGER_S stated, post the same; the set-up chunk and each post of the
+    fill go in a request of their own."""
     import run
 
     cell = SPEC["workloads"][0]["name"]
     in_flight = json.loads((BENCH / "workloads" / f"{cell}.json").read_text())["traffic"]["in_flight_chunks"]
     if traffic or transfer:
         cell = with_cell(monkeypatch, dict({"in_flight_chunks": in_flight}, **(traffic or {})), name="test-cell.keys", transfer=transfer)
-    events, main_thread = [], threading.current_thread()
-    real_post, real_sleep = pair.post_file, time.sleep
+    events, requests, main_thread = [], [], threading.current_thread()
+    real_post, real_request, real_sleep = pair.post_files, pair.LocalGateway.post, time.sleep
 
-    def post_file(source, src_path, dst_path, chunk_bytes):
-        events.append(("post", int(src_path.stem.split("_")[1])))
-        return real_post(source, src_path, dst_path, chunk_bytes)
+    def post_files(source, files):
+        events.extend(("post", int(src_path.stem.split("_")[1])) for src_path, _, _ in files)
+        return real_post(source, files)
+
+    def post(self, route, body):
+        requests.append(len(body))
+        return real_request(self, route, body)
 
     def sleep(seconds):
         if threading.current_thread() is main_thread and seconds >= 0.5:  # not the polls, nor a retry's back-off
             events.append(("sleep", seconds))
         real_sleep(seconds)
 
-    monkeypatch.setattr(pair, "post_file", post_file)
+    monkeypatch.setattr(pair, "post_files", post_files)
+    monkeypatch.setattr(pair.LocalGateway, "post", post)
     monkeypatch.setattr(time, "sleep", sleep)
     rc, result, err = rehearse(capsys, cell)
     monkeypatch.undo()
     assert rc == 1 and result["rehearsal"]["checks_passed"] is True, err[-3000:]
     fill = [("post", 1)] + [e for n in range(2, in_flight + 1) for e in (("sleep", run.STAGGER_S), ("post", n))]
     assert events[: 1 + len(fill)] == [("post", 0)] + fill
+    assert requests[: 1 + in_flight] == [1] * (1 + in_flight) and sum(requests) == len(events) - (in_flight - 1)
     rest = events[1 + len(fill) :]
     assert rest == [("post", n) for n in range(in_flight + 1, in_flight + 1 + len(rest))] and rest
     assert result["run"]["setup_rows"] == 1 and "set-up burst" not in err and "setup_bursts_s" not in result["run"]["phases"]
     assert "setup_chunks_landed_s" not in result["run"]["phases"] and "rows landed one at a time" not in err
     assert result["run"]["reference_rows"] == result["run"]["rows_sent"] == len(events) - (in_flight - 1)
+    assert gone(pool_pids(err)[0], 1.0)
+
+
+SMALL = {"generator": "random_files", "content": {"file_bytes": 131072}}  # 2 KiB objects at 1/64
+
+
+def watch_requests_and_polls(monkeypatch):
+    """In order: ("post", chunk requests in the request) for every POST to
+    ``chunk_requests``, ("poll",) for every read of the sink's status log,
+    and ("sleep", s) for every sleep of half a second or more on this thread."""
+    events, main_thread = [], threading.current_thread()
+    real_post, real_poll, real_sleep = pair.LocalGateway.post, pair.StatusReader.poll, time.sleep
+
+    def post(self, route, body):
+        if route == "chunk_requests":
+            events.append(("post", len(body)))
+        return real_post(self, route, body)
+
+    def poll(self):
+        if self.name == "gw_dst":
+            events.append(("poll",))
+        return real_poll(self)
+
+    def sleep(seconds):
+        if threading.current_thread() is main_thread and seconds >= 0.5:
+            events.append(("sleep", seconds))
+        real_sleep(seconds)
+
+    monkeypatch.setattr(pair.LocalGateway, "post", post)
+    monkeypatch.setattr(pair.StatusReader, "poll", poll)
+    monkeypatch.setattr(time, "sleep", sleep)
+    return events
+
+
+def test_a_fill_stagger_of_0_posts_the_whole_fill_before_the_first_poll_in_batches_of_100(capsys, monkeypatch):
+    cell = with_cell(monkeypatch, {"in_flight_chunks": 250, "fill_stagger_s": 0}, name="test-cell.fill", **SMALL)
+    events = watch_requests_and_polls(monkeypatch)
+    rc, result, err = rehearse(capsys, cell, seconds="10")  # the first window of several rows compiles on the CPU
+    monkeypatch.undo()
+    assert rc == 1 and result["rehearsal"]["checks_passed"] is True, err[-3000:]
+    assert events[0] == ("post", 1)  # the set-up chunk, landed alone
+    fill_at = events.index(("post", 100))
+    assert all(e == ("poll",) for e in events[1:fill_at])  # the set-up chunk's polls
+    assert events[fill_at : fill_at + 4] == [("post", 100), ("post", 100), ("post", 50), ("poll",)]
+    assert not [e for e in events if e[0] == "sleep"]
+    refills = [e[1] for e in events[fill_at + 3 :] if e[0] == "post"]
+    assert refills and max(refills) <= 100 and 1 + 250 + sum(refills) == result["run"]["rows_sent"]
+    assert result["run"]["completions"] >= 2 and result["run"]["sink_status_log_dropped"] == 0
+    assert gone(pool_pids(err)[0], 1.0)
+
+
+@pytest.mark.parametrize("value", [-1, "0", True, None, float("nan")])
+def test_a_fill_stagger_other_than_a_number_of_0_or_more_is_refused_before_anything_starts(capsys, monkeypatch, value):
+    cell = with_cell(monkeypatch, {"in_flight_chunks": 2, "fill_stagger_s": value})
+    with pytest.raises(SystemExit, match="fill_stagger_s"):
+        rehearse(capsys, cell)
+
+
+def test_a_loop_deeper_than_a_url_can_list_completes_and_passes_every_check(capsys, monkeypatch):
+    """2,048 in flight: the read this harness had listed every pending id in
+    one GET's URL, 33 bytes an id, and ``http.server`` answers 414 past 65,536
+    bytes of request line. The reader sends no id anywhere."""
+    depth = 2048
+    assert len("chunk_ids=" + ",".join(["0" * 32] * depth)) > 65536
+    cell = with_cell(monkeypatch, {"in_flight_chunks": depth, "fill_stagger_s": 0}, name="test-cell.deep", **SMALL)
+    gets, real_get = [], pair.LocalGateway.get
+
+    def get(self, route, **kw):
+        gets.append(route)
+        return real_get(self, route, **kw)
+
+    monkeypatch.setattr(pair.LocalGateway, "get", get)
+    rc, result, err = rehearse(capsys, cell, seconds="10")  # the first window of several rows compiles on the CPU: a gap of 4 s
+    assert rc == 1 and result["rehearsal"]["checks_passed"] is True, err[-3000:]
+    run = result["run"]
+    assert run["rows_sent"] >= 1 + depth and run["completions"] >= 2 and run["sink_status_log_dropped"] == 0
+    assert result["compared"]["chunks_never_landed"]["value"] == 0 and result["compared"]["frames_missing"]["value"] == 0
+    assert "chunk_status_log" not in gets
+    assert gone(pool_pids(err)[0], 1.0)
+
+
+def test_a_status_log_that_drops_records_before_they_are_read_ends_the_run_unsound_at_once(capsys, monkeypatch):
+    from skyplane_tpu.gateway.gateway_daemon_api import GatewayDaemonAPI
+
+    monkeypatch.setattr(GatewayDaemonAPI, "MAX_STATUS_LOG", 1)  # every record but the newest is dropped at once
+    t = time.monotonic()
+    rc, result, err = rehearse(capsys, SPEC["workloads"][0]["name"])
+    assert rc == 5 and result is None
+    assert "FAIL: the run is not sound: gateway gw_" in err and "records of its status log" in err and "correct:" not in err
+    assert time.monotonic() - t < 60  # no wait for a completion the log no longer holds
     assert gone(pool_pids(err)[0], 1.0)
 
 
