@@ -132,6 +132,7 @@ def load_library() -> ctypes.CDLL:
                 [u8p, ctypes.c_uint64, u32p, ctypes.c_uint32, ctypes.c_uint64, ctypes.c_uint64, u32p, i64p, u32p, ctypes.c_uint64],
             ),
             ("skydp_blockpack_encode", ctypes.c_uint64, [u8p, ctypes.c_uint64, ctypes.c_uint64, u8p, u8p]),
+            ("skydp_blockpack_encode_gather", ctypes.c_uint64, [u8p, i64p, ctypes.c_uint64, ctypes.c_uint64, u8p, u8p]),
             ("skydp_blockpack_decode", ctypes.c_int, [u8p, ctypes.c_uint64, u8p, ctypes.c_uint64, ctypes.c_uint64, u8p]),
         ):
             fn = getattr(lib, name)

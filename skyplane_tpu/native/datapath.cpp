@@ -391,6 +391,39 @@ uint64_t skydp_cdc_fp(const uint8_t* data, uint64_t n, const uint32_t* table,
     return n_ends;
 }
 
+// Blockpack's classification of one block: 0 = all zero, 1 = constant
+// (one byte repeated), 2 = literal. Word-at-a-time constant check.
+static inline uint8_t blockpack_tag(const uint8_t* block, uint64_t block_bytes) {
+    const uint8_t first = block[0];
+    uint64_t pattern;
+    __builtin_memset(&pattern, first, 8);
+    uint64_t i = 0;
+    for (; i + 8 <= block_bytes; i += 8) {
+        uint64_t w;
+        __builtin_memcpy(&w, block + i, 8);
+        if (w != pattern) return 2;  // TAG_LITERAL
+    }
+    for (; i < block_bytes; i++) {
+        if (block[i] != first) return 2;
+    }
+    return first == 0 ? 0 : 1;  // TAG_ZERO : TAG_CONST
+}
+
+// Emit one classified block: the tag, and its literal bytes (1 for a
+// constant block, the whole block for a literal one) at lits_out + lit.
+static inline uint64_t blockpack_emit(const uint8_t* block, uint64_t block_bytes, uint8_t tag,
+                                      uint8_t* lits_out, uint64_t lit) {
+    if (tag == 1) {
+        lits_out[lit] = block[0];
+        return lit + 1;
+    }
+    if (tag == 2) {
+        __builtin_memcpy(lits_out + lit, block, block_bytes);
+        return lit + block_bytes;
+    }
+    return lit;
+}
+
 // Blockpack encode: per block_bytes block emit tag (0=zero, 1=const, 2=
 // literal) and the compacted literal stream (1 byte per const block, the
 // whole block for literals). data length must be a multiple of block_bytes
@@ -401,35 +434,64 @@ uint64_t skydp_blockpack_encode(const uint8_t* data, uint64_t n, uint64_t block_
     uint64_t lit = 0;
     for (uint64_t b = 0; b < nb; b++) {
         const uint8_t* block = data + b * block_bytes;
-        const uint8_t first = block[0];
-        bool is_const = true;
-        // word-at-a-time constant check
-        uint64_t pattern;
-        __builtin_memset(&pattern, first, 8);
-        uint64_t i = 0;
-        for (; i + 8 <= block_bytes; i += 8) {
-            uint64_t w;
-            __builtin_memcpy(&w, block + i, 8);
-            if (w != pattern) { is_const = false; break; }
-        }
-        if (is_const) {
-            for (; i < block_bytes; i++) {
-                if (block[i] != first) { is_const = false; break; }
-            }
-        }
-        if (is_const) {
-            if (first == 0) {
-                tags_out[b] = 0;  // TAG_ZERO
-            } else {
-                tags_out[b] = 1;  // TAG_CONST
-                lits_out[lit++] = first;
-            }
-        } else {
-            tags_out[b] = 2;  // TAG_LITERAL
-            __builtin_memcpy(lits_out + lit, block, block_bytes);
-            lit += block_bytes;
-        }
+        tags_out[b] = blockpack_tag(block, block_bytes);
+        lit = blockpack_emit(block, block_bytes, tags_out[b], lits_out, lit);
     }
+    return lit;
+}
+
+// Blockpack encode of a stream given as spans of one buffer: the bytes of
+// buf in [spans[2k], spans[2k+1]) for k = 0..n_spans-1, in order, read as
+// one stream padded with zeros to whole blocks. Blocks are classified as
+// skydp_blockpack_encode classifies them, so the output is what it gives for
+// the spans joined and padded, with the tags packed 4 to a byte (tag b in
+// bits 2*(b%4) of byte b/4; ceil(nb/4) bytes written) straight into
+// packed_tags_out. A block that lies inside one span is read in place; one
+// that straddles spans, or the stream's padded end, is assembled in a staging
+// block first. Returns the literal byte count, or UINT64_MAX where the
+// staging block cannot be allocated.
+uint64_t skydp_blockpack_encode_gather(const uint8_t* buf, const int64_t* spans, uint64_t n_spans,
+                                       uint64_t block_bytes, uint8_t* packed_tags_out, uint8_t* lits_out) {
+    uint8_t* stage = (uint8_t*)__builtin_malloc(block_bytes);
+    if (!stage) return ~(uint64_t)0;
+    uint64_t lit = 0, b = 0, k = 0;
+    uint64_t pos = n_spans ? (uint64_t)spans[0] : 0;
+    uint8_t packed = 0;
+    for (;;) {
+        while (k < n_spans && pos >= (uint64_t)spans[2 * k + 1]) {  // next non-empty span
+            if (++k < n_spans) pos = (uint64_t)spans[2 * k];
+        }
+        if (k == n_spans) break;
+        const uint8_t* block;
+        if ((uint64_t)spans[2 * k + 1] - pos >= block_bytes) {
+            block = buf + pos;
+            pos += block_bytes;
+        } else {
+            uint64_t filled = 0;
+            while (filled < block_bytes && k < n_spans) {
+                const uint64_t take_max = (uint64_t)spans[2 * k + 1] - pos;
+                const uint64_t take = take_max < block_bytes - filled ? take_max : block_bytes - filled;
+                __builtin_memcpy(stage + filled, buf + pos, take);
+                filled += take;
+                pos += take;
+                while (k < n_spans && pos >= (uint64_t)spans[2 * k + 1]) {
+                    if (++k < n_spans) pos = (uint64_t)spans[2 * k];
+                }
+            }
+            if (filled < block_bytes) __builtin_memset(stage + filled, 0, block_bytes - filled);
+            block = stage;
+        }
+        const uint8_t tag = blockpack_tag(block, block_bytes);
+        lit = blockpack_emit(block, block_bytes, tag, lits_out, lit);
+        packed |= (uint8_t)(tag << (2 * (b & 3)));
+        if ((b & 3) == 3) {
+            packed_tags_out[b >> 2] = packed;
+            packed = 0;
+        }
+        b++;
+    }
+    if (b & 3) packed_tags_out[b >> 2] = packed;
+    __builtin_free(stage);
     return lit;
 }
 

@@ -105,6 +105,32 @@ def blockpack_encode(data: np.ndarray, block_bytes: int):
     return tags, lits[:n_lit], int(n_lit)
 
 
+def blockpack_encode_gather(buf: np.ndarray, spans: np.ndarray, block_bytes: int, packed_tags: np.ndarray, lits: np.ndarray) -> int:
+    """The stream of ``buf``'s bytes in ``spans`` ([n, 2] int64 (start, end),
+    in order), padded with zeros to whole blocks -> its tags packed 4 to a
+    byte into ``packed_tags`` and its literals into ``lits`` (views the caller
+    sized: ceil(nb/4) and nb * block_bytes bytes at most), in one pass with
+    the interpreter lock released. Returns n_lit."""
+    buf = np.ascontiguousarray(buf, dtype=np.uint8)
+    spans = np.ascontiguousarray(spans, dtype=np.int64).reshape(-1, 2)
+    if len(spans) and (spans.min() < 0 or spans.max() > len(buf) or (spans[:, 1] < spans[:, 0]).any()):
+        raise ValueError(f"blockpack_encode_gather: spans outside the {len(buf)}-byte buffer")
+    n_blocks = -(-int((spans[:, 1] - spans[:, 0]).sum()) // block_bytes)
+    if len(packed_tags) < (n_blocks + 3) // 4 or len(lits) < n_blocks * block_bytes:
+        raise ValueError(f"blockpack_encode_gather: outputs too short for {n_blocks} blocks")
+    n_lit = load_library().skydp_blockpack_encode_gather(
+        _u8p(buf),
+        spans.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+        len(spans),
+        block_bytes,
+        _u8p(packed_tags),
+        _u8p(lits),
+    )
+    if n_lit == np.iinfo(np.uint64).max:
+        raise MemoryError("skydp_blockpack_encode_gather: no memory for the staging block")
+    return int(n_lit)
+
+
 def blockpack_decode(tags: np.ndarray, literals: np.ndarray, block_bytes: int, out=None) -> np.ndarray:
     """(tags [NB], literals, block_bytes) -> [NB*block_bytes] uint8, written into
     the head of ``out`` (a C-contiguous uint8 array at least that long) where the

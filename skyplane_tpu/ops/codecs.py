@@ -13,6 +13,11 @@ in the wire header (chunk.py Codec), and include the TPU block-suppress path:
   tpu_zstd   — blockpack, then zstd over the compacted container (device does
                suppression; CPU entropy-codes only surviving literals)
   native_lz  — C++ LZ codec from skyplane_tpu/native (registered lazily)
+
+The sender hands a codec its literal stream as spans of the chunk
+(:func:`timed_encoder`): the blockpack codecs lay the spans down themselves,
+in one native pass into a pooled container that zstd reads in place; the
+others get the spans joined.
 """
 
 from __future__ import annotations
@@ -20,11 +25,12 @@ from __future__ import annotations
 import os
 import threading
 import time
-from typing import Callable, Dict, NamedTuple, Optional, Tuple
+from typing import Callable, Dict, NamedTuple, Optional, Sequence, Tuple
 
 from skyplane_tpu.chunk import Codec
 from skyplane_tpu.exceptions import CodecException
 from skyplane_tpu.obs import NOOP_SPAN
+from skyplane_tpu.ops.bufpool import BufferPool, bucket_size
 
 
 class CodecSpec(NamedTuple):
@@ -36,14 +42,20 @@ class CodecSpec(NamedTuple):
 
     ``encode_steps`` names the steps ``encode`` is made of, in order, for the
     codecs whose steps have a counter (``blockpack``, ``zstd``): running them
-    one after the other gives ``encode``'s bytes (see :func:`timed_encoder`)."""
+    one after the other gives ``encode``'s bytes (see :func:`timed_encoder`).
+    Where ``gather_bound`` is set the first step takes the stream as ``(buf,
+    spans, out)`` in place of its bytes and lays it down itself into ``out``,
+    an array of at least ``gather_bound(n)`` bytes for a stream of ``n``,
+    returning a view of what it wrote and whether the native pass ran (see
+    :func:`blockpack.encode_spans`)."""
 
     name: str
     codec_id: Codec
     encode: Callable[[bytes], bytes]
     decode: Callable[..., object]
     decode_out_len: Optional[Callable[[int], int]] = None
-    encode_steps: Tuple[Tuple[str, Callable[[bytes], bytes]], ...] = ()
+    encode_steps: Tuple[Tuple[str, Callable], ...] = ()
+    gather_bound: Optional[Callable[[int], int]] = None
 
 
 def _zstd():
@@ -138,6 +150,18 @@ def _tpu_out_len(n: int) -> int:
     return blockpack.padded_len(n)
 
 
+def _encode_tpu_spans(buf, spans: Sequence[Tuple[int, int]], out):
+    from skyplane_tpu.ops import blockpack
+
+    return blockpack.encode_spans(buf, spans, out)
+
+
+def _tpu_container_bound(n: int) -> int:
+    from skyplane_tpu.ops import blockpack
+
+    return blockpack.container_bound(n)
+
+
 def _encode_tpu_zstd(data: bytes) -> bytes:
     return _encode_zstd(_encode_tpu(data))
 
@@ -179,10 +203,12 @@ def _decode_lz4(buf: bytes) -> bytes:
 _REGISTRY: Dict[str, CodecSpec] = {
     "none": CodecSpec("none", Codec.NONE, lambda b: b, lambda b: b),
     "zstd": CodecSpec("zstd", Codec.ZSTD, _encode_zstd, _decode_zstd, None, (("zstd", _encode_zstd),)),
-    "tpu": CodecSpec("tpu", Codec.TPU_BLOCK, _encode_tpu, _decode_tpu, _tpu_out_len, (("blockpack", _encode_tpu),)),
+    "tpu": CodecSpec(
+        "tpu", Codec.TPU_BLOCK, _encode_tpu, _decode_tpu, _tpu_out_len, (("blockpack", _encode_tpu_spans),), _tpu_container_bound
+    ),
     "tpu_zstd": CodecSpec(
         "tpu_zstd", Codec.TPU_BLOCK_ZSTD, _encode_tpu_zstd, _decode_tpu_zstd, _tpu_out_len,
-        (("blockpack", _encode_tpu), ("zstd", _encode_zstd)),
+        (("blockpack", _encode_tpu_spans), ("zstd", _encode_zstd)), _tpu_container_bound,
     ),
     "native_lz": CodecSpec("native_lz", Codec.NATIVE_LZ, _encode_native, _decode_native),
     # the reference's wire codec (gateway_operator.py:358-361), bound to the
@@ -194,21 +220,58 @@ _REGISTRY: Dict[str, CodecSpec] = {
 _BY_ID: Dict[int, CodecSpec] = {int(spec.codec_id): spec for spec in _REGISTRY.values()}
 
 
-def timed_encoder(spec: CodecSpec, timings: dict, span=lambda name: NOOP_SPAN) -> Callable[[bytes], bytes]:
-    """``spec.encode`` taken step by step: the callable returned gives the
-    same bytes and leaves ``<step>_ns`` in ``timings`` for each of
-    ``spec.encode_steps``, each run under ``span("codec.<step>")``. A codec
-    that names no steps is returned as it is."""
-    if not spec.encode_steps:
-        return spec.encode
+def _joined(buf, spans: Sequence[Tuple[int, int]]):
+    """The bytes of ``buf`` in ``spans``, in order: ``buf`` itself where one span covers it."""
+    if len(spans) == 1 and tuple(spans[0]) == (0, len(buf)):
+        return buf
+    view = memoryview(buf)
+    return b"".join([view[start:end] for start, end in spans])
 
-    def encode(data: bytes) -> bytes:
-        for step, fn in spec.encode_steps:
-            t = time.perf_counter_ns()
-            with span(f"codec.{step}"):
-                data = fn(data)
-            timings[f"{step}_ns"] = time.perf_counter_ns() - t
-        return data
+
+def timed_encoder(
+    spec: CodecSpec, timings: dict, span=lambda name: NOOP_SPAN, pool: Optional[BufferPool] = None
+) -> Callable[[object, Sequence[Tuple[int, int]]], bytes]:
+    """``spec.encode`` over a stream given as spans: the callable returned
+    takes ``(buf, spans)``, the bytes of ``buf`` in its ``(start, end)`` spans
+    in order, and gives ``spec.encode`` of those bytes joined. It leaves
+    ``<step>_ns`` in ``timings`` for each of ``spec.encode_steps``, each run
+    under ``span("codec.<step>")``. A codec with a ``gather_bound`` lays the
+    stream down in its first step, one pass from ``buf`` into a buffer drawn
+    from ``pool`` (the bucket of the stream's worst case; a pool of its own
+    where none is given); the next step reads it in place, and it goes back to
+    the pool once the codec has returned. Any other codec gets the spans
+    joined first, outside its steps. A stream of at least one byte also leaves
+    ``literal_gathers`` 1 where the native pass laid it down, else
+    ``literal_joins`` 1 (joined here, or laid down by the numpy fallback)."""
+    pool = pool if pool is not None else BufferPool()
+
+    def timed(step: str, fn, *args):
+        t = time.perf_counter_ns()
+        with span(f"codec.{step}"):
+            out = fn(*args)
+        timings[f"{step}_ns"] = time.perf_counter_ns() - t
+        return out
+
+    def encode(buf, spans: Sequence[Tuple[int, int]]) -> bytes:
+        n_raw = sum(end - start for start, end in spans)
+        if spec.gather_bound is None:
+            data = _joined(buf, spans)
+            if n_raw:
+                timings["literal_joins"] = 1
+            for step, fn in spec.encode_steps:
+                data = timed(step, fn, data)
+            return data if spec.encode_steps else spec.encode(data)
+        (first, gather), rest = spec.encode_steps[0], spec.encode_steps[1:]
+        out = pool.acquire(spec.gather_bound(bucket_size(n_raw)))
+        try:
+            data, native = timed(first, gather, buf, spans, out)
+            if n_raw:
+                timings["literal_gathers" if native else "literal_joins"] = 1
+            for step, fn in rest:
+                data = timed(step, fn, data)
+            return data if rest else bytes(data)
+        finally:
+            pool.release(out)
 
     return encode
 

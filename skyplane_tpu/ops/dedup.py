@@ -873,6 +873,7 @@ def build_recipe(
     index: SenderDedupIndex,
     encode_blob,
     timings: Optional[dict] = None,
+    chunk=None,
 ) -> Tuple[bytes, int, int, List[bytes], List[bytes]]:
     """Assemble a recipe for one chunk.
 
@@ -887,6 +888,13 @@ def build_recipe(
     Repeats *within* this chunk are still deduped (they travel in the same
     frame, so in-order resolution is guaranteed).
 
+    ``chunk``, where the caller has it, is the buffer ``segments`` tile in
+    order (each segment the next ``len(seg)`` bytes of it): the literals are
+    then never joined here, and ``encode_blob(chunk, spans)`` gets them as the
+    ``(start, end)`` spans of ``chunk`` they fill, adjacent literals in one
+    span (:func:`codecs.timed_encoder`). Without it ``encode_blob`` gets the
+    literals joined.
+
     ``timings``, where given, receives ``recipe_encode_ns``: the literal join
     and ``encode_blob``. What the call takes beyond that is index lookups and
     recipe assembly. It also receives ``literal_blob_bytes``, the length of
@@ -895,25 +903,36 @@ def build_recipe(
     """
     entries = bytearray()
     lit_parts: List[bytes] = []
+    lit_spans: List[Tuple[int, int]] = []
+    lit_bytes = 0
+    offset = 0
     emitted_here: set = set()
     new_fps: List[bytes] = []
     ref_fps: List[bytes] = []
     for fp, seg in segments:
+        n = len(seg)
         if fp in index or fp in emitted_here:
-            entries += _ENTRY.pack(KIND_REF, fp, len(seg))
+            entries += _ENTRY.pack(KIND_REF, fp, n)
             ref_fps.append(fp)
         else:
-            entries += _ENTRY.pack(KIND_LIT, fp, len(seg))
-            lit_parts.append(seg)
+            entries += _ENTRY.pack(KIND_LIT, fp, n)
+            if chunk is None:
+                lit_parts.append(seg)
+            elif lit_spans and lit_spans[-1][1] == offset:
+                lit_spans[-1] = (lit_spans[-1][0], offset + n)
+            else:
+                lit_spans.append((offset, offset + n))
+            lit_bytes += n
             emitted_here.add(fp)
-            new_fps.append((fp, len(seg)))
+            new_fps.append((fp, n))
+        offset += n
     t0 = time.perf_counter_ns()
-    lit_blob = encode_blob(b"".join(lit_parts))
+    lit_blob = encode_blob(b"".join(lit_parts)) if chunk is None else encode_blob(chunk, lit_spans)
     if timings is not None:
         timings["recipe_encode_ns"] = time.perf_counter_ns() - t0
         timings["literal_blob_bytes"] = len(lit_blob)
     head = MAGIC + struct.pack("<BI", VERSION, len(segments))
-    return head + bytes(entries) + lit_blob, len(ref_fps), sum(len(p) for p in lit_parts), new_fps, ref_fps
+    return head + bytes(entries) + lit_blob, len(ref_fps), lit_bytes, new_fps, ref_fps
 
 
 class PooledChunk:

@@ -85,6 +85,8 @@ class DataPathStats(StageCounters):
         "blockpack_ns",
         "zstd_ns",
         "seal_ns",
+        "literal_gathers",
+        "literal_joins",
     )
     EXTERNAL_ZERO = {
         "pool_hits": 0,
@@ -123,7 +125,11 @@ class DataPathStats(StageCounters):
         (``build_recipe`` whole: dedup-index lookups, literal join, codec;
         counted by the ``recipe.build`` stage), and inside that
         ``blockpack_ns`` and ``zstd_ns``, the steps of the
-        codec that ran (a step it does not have stays 0). ``literal_bytes``:
+        codec that ran (a step it does not have stays 0; ``blockpack_ns``
+        holds the pass that lays the literals down from the chunk, where the
+        join sat outside it), and ``literal_gathers`` / ``literal_joins``: 1
+        for a chunk that holds a literal, as the native pass laid its literals
+        down or Python joined them (``codecs.timed_encoder``). ``literal_bytes``:
         raw bytes of the segments that went as literals, so what dedup left
         (0 with dedup off: no recipe, no literal); ``literal_blob_bytes``:
         what the codec made of them. Added together with ``chunks``, their
@@ -137,7 +143,7 @@ class DataPathStats(StageCounters):
         d["literal_bytes"] += p.literal_bytes
         d["literal_blob_bytes"] += p.literal_blob_bytes
         d["device_path_ns"] += device_path_ns
-        for k in ("recipe_encode_ns", "blockpack_ns", "zstd_ns"):
+        for k in ("recipe_encode_ns", "blockpack_ns", "zstd_ns", "literal_gathers", "literal_joins"):
             d[k] += (timings or {}).get(k, 0)
 
     def observe_device_wait(self, ns: int) -> None:
@@ -359,8 +365,8 @@ class DataPathProcessor:
             # final once ends land, so they're cut while the fingerprint
             # readback of this worker's batch is still in flight
             ends_l = np.asarray(phased.ends).tolist()
-            # memoryview slices: REF segments never need their bytes copied
-            # (only literals are materialized, inside build_recipe's join)
+            # memoryview slices: no segment's bytes are copied here (the
+            # codec reads the literals from ``data`` by their spans)
             mv = memoryview(data)
             spans = []
             start = 0
@@ -373,9 +379,11 @@ class DataPathProcessor:
             self.stats.observe_device_wait(phased.wait_ns)
             segments = list(zip(seg_fps, spans))
             tracer = get_tracer()
-            encode = timed_encoder(self.codec, timings, lambda name: tracer.span(name, trace_id=trace_id, cat="sender"))
+            encode = timed_encoder(
+                self.codec, timings, lambda name: tracer.span(name, trace_id=trace_id, cat="sender"), pool=self.bufpool
+            )
             with self._t_recipe(trace_id):
-                wire, n_ref, lit_bytes, new_fps, ref_fps = build_recipe(segments, index, encode, timings)
+                wire, n_ref, lit_bytes, new_fps, ref_fps = build_recipe(segments, index, encode, timings, chunk=data)
             payload = ProcessedPayload(
                 wire_bytes=wire,
                 codec=self.codec.codec_id,
@@ -391,7 +399,7 @@ class DataPathProcessor:
                 ref_fingerprints=ref_fps,
             )
         else:
-            wire = self.codec.encode(data)
+            wire = timed_encoder(self.codec, {}, pool=self.bufpool)(data, [(0, raw_len)])
             if len(wire) >= raw_len and self.codec.codec_id != Codec.NONE:
                 # incompressible chunk: ship raw (receiver dispatches on header codec)
                 wire, codec_id = data, Codec.NONE

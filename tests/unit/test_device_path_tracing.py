@@ -437,10 +437,11 @@ def test_encode_counters_and_spans_name_the_steps_of_the_codec_that_ran(codec_na
         assert p.literal_blob_bytes < p.literal_bytes  # the content compresses under every step
     else:
         assert p.literal_blob_bytes == p.literal_bytes
-    # taken step by step the codec gives the bytes it gives in one piece
+    # taken step by step, over the stream as one span, the codec gives the bytes it gives in one piece
     spec, timings = get_codec(codec_name), {}
-    assert bytes(timed_encoder(spec, timings)(data)) == bytes(spec.encode(data))
-    assert sorted(timings) == sorted(f"{k}_ns" for k in steps)
+    assert bytes(timed_encoder(spec, timings)(data, [(0, len(data))])) == bytes(spec.encode(data))
+    assert sorted(k for k in timings if k.endswith("_ns")) == sorted(f"{k}_ns" for k in steps)
+    assert d["literal_gathers"] + d["literal_joins"] == 1  # one chunk, and it holds a literal
 
     header = WireProtocolHeader(
         chunk_id=trace_id, data_len=len(p.wire_bytes), raw_data_len=p.raw_len, codec=int(p.codec),
@@ -503,6 +504,9 @@ REF_PATH_KEYS = (
     ("profile/compression", "zstd_ns"),
     ("profile/compression", "literal_blob_bytes"),
     ("profile/decode", "blob_decode_ns"),
+    # how the sender's literals reached the codec
+    ("profile/compression", "literal_gathers"),
+    ("profile/compression", "literal_joins"),
 )
 
 
